@@ -174,11 +174,9 @@ class GangTrialRunner:
         self.fault_spec_for = fault_spec_for
         self.spool_dir = os.path.join(fleet_workdir, "spool")
         self.flight_dir = os.path.join(fleet_workdir, "flight")
-        self.compile_cache_dir = os.path.join(fleet_workdir, "compile_cache")
 
     def __call__(self, slot: TrialSlot, target_iter: int,
                  timeout_s: float) -> float:
-        from ..common import compile_cache
         from ..parallel.supervisor import GangSupervisor
 
         extra = {
@@ -192,9 +190,8 @@ class GangTrialRunner:
             # prefixes keep identities apart, the merged scrape shows all
             aggregate.ENV_DIR: self.spool_dir,
             flight.ENV_DIR: self.flight_dir,
-            # one executable cache for the sweep: trials share model shape,
-            # so later trials restore what the first one compiled
-            compile_cache.ENV_DIR: self.compile_cache_dir,
+            # the sweep shares one executable cache without help: every
+            # trial resolves the same directory (common.compile_cache)
         }
         if self.fault_spec_for is not None:
             spec = self.fault_spec_for(slot)

@@ -1,27 +1,26 @@
-"""Persistent compiled-executable cache (ISSUE 12 tentpole layer 2).
+"""Persistent compiled-executable cache: one rule for where it lives.
 
-Wires JAX's on-disk compilation cache behind one env contract:
+- ``JAX_COMPILATION_CACHE_DIR`` set → jax reads it itself at import; this
+  module never writes ``jax_compilation_cache_dir``, and child processes
+  inherit the variable. Whoever launches the program places the cache.
+- not set → ``<checkout>/.jax_cache`` (derived from the package path, in
+  ``.gitignore``): the same directory for a parent and every child it
+  spawns, on every run. The directory is part of jax's cache key, so a
+  path that moves (a temp dir, a pid, a timestamp) never hits.
 
-- ``TDL_COMPILE_CACHE_DIR`` — directory holding serialized XLA executables.
-  Set by :class:`~deeplearning4j_tpu.parallel.supervisor.GangSupervisor`
-  (stable ``workdir/compile_cache``, same pattern as ``TDL_FLIGHT_DIR`` /
-  ``TDL_HISTORY_DIR``) and by the serving builder
-  (``JsonModelServer.Builder.compile_cache_dir``); any process may also
-  export it directly.
-
-A respawned gang rank or a warming serving replica then *restores* its
-step/forward executables from disk instead of re-paying full XLA
-compilation: on a cache hit jax returns the deserialized executable before
-``backend_compile`` ever runs, so ``tdl_xla_compiles_total{fn}`` stays flat
-across the restart — exactly the "compiles flat after warmup, even across a
-restart" contract (pinned by tests/test_compile_cache.py).
-
-``enable()`` is idempotent and cheap to call from every entry point that is
-about to build an executable (fit loops, executors, trainers); the first
-call also installs the hit/miss metrics listener
+``enable()`` is idempotent and cheap; every entry point that is about to
+build an executable (fit loops, executors, trainers, servers) calls it. The
+first call installs the hit/miss metrics listener
 (``monitoring.compilecache``), so ``tdl_compile_cache_{hits,misses}_total``
-are attributed per-fn through the same ``note_signature`` thread
-announcements the recompile watchdog uses.
+are attributed per-fn through the same ``note_signature`` announcements the
+recompile watchdog uses. On a cache hit jax returns the deserialized
+executable before ``backend_compile`` runs, so ``tdl_xla_compiles_total``
+stays flat across a restart (pinned by tests/test_compile_cache.py).
+
+Off switches are jax's own: ``JAX_ENABLE_COMPILATION_CACHE=false`` (the test
+suite runs with it so no state outside git steers a test), and
+:func:`disable`, which flips the same flag. Multi-process CPU (gloo) gangs
+are always excluded — see :func:`_unsafe_multiprocess_cpu`.
 """
 
 from __future__ import annotations
@@ -33,27 +32,51 @@ from typing import Optional
 
 log = logging.getLogger(__name__)
 
-ENV_DIR = "TDL_COMPILE_CACHE_DIR"
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
 
 _lock = threading.Lock()
 _enabled_dir: Optional[str] = None
-_env_checked = False
+_gang_skip_logged = False
 
 
-def enable(directory: str) -> str:
-    """Point jax's persistent compilation cache at ``directory`` (created
-    if missing) and install the cache metrics listener. Idempotent; a
-    second call with a DIFFERENT directory re-points the cache (jax reads
-    the config per compile) and logs the switch."""
-    global _enabled_dir
-    directory = os.path.abspath(directory)
+def default_dir() -> str:
+    """``<checkout>/.jax_cache`` — beside the package, wherever it sits."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(pkg), ".jax_cache")
+
+
+def enable() -> Optional[str]:
+    """Turn the persistent cache on — where ``JAX_COMPILATION_CACHE_DIR``
+    says, else at :func:`default_dir` — and install the metrics listener.
+    Returns the directory in use, or None when the cache is off for this
+    process (jax's own switch, or a multi-process CPU gang). Re-probed on
+    every call: the first net/executor can be built before
+    ``jax.distributed`` initializes, and an early enable must be revoked
+    once the process turns out to be a CPU gang rank."""
+    global _enabled_dir, _gang_skip_logged
+    import jax
+
+    if not jax.config.jax_enable_compilation_cache:
+        _enabled_dir = None
+        return None
+    if _unsafe_multiprocess_cpu():
+        if not _gang_skip_logged:
+            log.info("compile cache: off on a multi-process CPU gang "
+                     "(reloaded XLA:CPU collective executables are not "
+                     "crash-safe); TPU gangs and single-process runs use it")
+            _gang_skip_logged = True
+        disable()
+        return None
     with _lock:
-        if _enabled_dir == directory:
+        directory = jax.config.jax_compilation_cache_dir
+        if _enabled_dir is not None and _enabled_dir == directory:
             return directory
+        if directory is None:
+            # only reachable with JAX_COMPILATION_CACHE_DIR unset: jax seeds
+            # this config from the variable at import
+            directory = default_dir()
+            jax.config.update("jax_compilation_cache_dir", directory)
         os.makedirs(directory, exist_ok=True)
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", directory)
         # cache EVERY executable: the default thresholds (1s compile time,
         # non-zero entry size) would silently skip exactly the small steady
         # executables whose recompile-on-restart churn this kills
@@ -65,9 +88,6 @@ def enable(directory: str) -> str:
         from jax.experimental.compilation_cache import compilation_cache
 
         compilation_cache.reset_cache()
-        if _enabled_dir is not None:
-            log.info("compile cache re-pointed %s -> %s",
-                     _enabled_dir, directory)
         _enabled_dir = directory
     from ..monitoring import compilecache
 
@@ -97,54 +117,19 @@ def _unsafe_multiprocess_cpu() -> bool:
         return False
 
 
-def maybe_enable_from_env() -> Optional[str]:
-    """Enable the cache iff ``TDL_COMPILE_CACHE_DIR`` is set (and this
-    process can safely use it — see :func:`_unsafe_multiprocess_cpu`).
-    Called from the executable-building entry points; one env lookup when
-    unset."""
-    global _env_checked
-    directory = os.environ.get(ENV_DIR)
-    if not directory:
-        return _enabled_dir
-    if _unsafe_multiprocess_cpu():
-        # re-probed on EVERY entry point, not just the first enable: the
-        # first net/executor can be built before jax.distributed
-        # initializes (the probe still answers safe), so an early env
-        # enable must be revoked once the process turns out to be a
-        # multi-process CPU gang — respawning into reloaded XLA:CPU
-        # collective executables segfaults
-        if not _env_checked:
-            log.info("compile cache: skipping %s on a multi-process CPU "
-                     "gang (reloaded XLA:CPU collective executables are "
-                     "not crash-safe); TPU gangs and single-process runs "
-                     "use it normally", directory)
-        _env_checked = True
-        if _enabled_dir == os.path.abspath(directory):
-            disable()
-        return None
-    _env_checked = True
-    if _enabled_dir is not None:
-        # an explicit enable() (serving builder compile_cache_dir, test
-        # fixture) WINS over the env contract: re-pointing here would strand
-        # the already-persisted executables in a directory the operator
-        # never asked for — the next entry point silently moving the cache
-        # is exactly the kind of spooky action this module exists to kill
-        return _enabled_dir
-    return enable(directory)
-
-
 def disable() -> None:
-    """Stop persisting executables (tests: an enabled cache is process-wide
-    jax config — a test pointing it at tmp_path must reset it so later
-    tests don't write into a deleted directory)."""
+    """Stop reading and writing the persistent cache in this process, by
+    jax's own switch — the directory setting is never touched. Stays off
+    until ``jax_enable_compilation_cache`` is set again (tests do; nothing
+    in the package does)."""
     global _enabled_dir
-    with _lock:
-        if _enabled_dir is None:
-            return
-        import jax
-        from jax.experimental.compilation_cache import compilation_cache
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-        jax.config.update("jax_compilation_cache_dir", None)
+    with _lock:
+        if not jax.config.jax_enable_compilation_cache:
+            return
+        jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         _enabled_dir = None
     from ..monitoring import watchdogs

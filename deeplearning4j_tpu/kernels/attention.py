@@ -30,8 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ..common import jax_compat
+from jax.sharding import PartitionSpec as P
 
 _NEG_INF = -1e30
 
@@ -550,7 +549,7 @@ def ring_attention(q, k, v, *, axis_name: str, causal: bool = False, scale: Opti
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    n = jax_compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     B, H, Tl, D = q.shape
 
@@ -607,7 +606,7 @@ def ulysses_attention(q, k, v, *, axis_name: str, causal: bool = False,
     ppermute pipeline; the ring wins at very long T where even T×T/P tiles
     blow HBM, Ulysses wins on latency for moderate T.
     """
-    n = jax_compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     H = q.shape[1]
     if H % n:
         raise ValueError(f"ulysses needs heads ({H}) divisible by axis size ({n})")
@@ -625,6 +624,41 @@ def ulysses_attention(q, k, v, *, axis_name: str, causal: bool = False,
     return jax.lax.all_to_all(out, axis_name, split_axis=2, concat_axis=1, tiled=True)
 
 
+#: mesh axis names this repo gives the batch dim (``parallel.mesh`` /
+#: ``models.transformer``) and the head dim, in order of preference
+_BATCH_AXES = ("data", "dp")
+_HEAD_AXES = ("tp", "model")
+
+
+def _flash_per_shard(q, k, v, mask, *, causal, scale):
+    """``flash_attention`` under whatever mesh is ambient.
+
+    A Mosaic call is opaque to GSPMD ("Mosaic kernels cannot be
+    automatically partitioned"), so inside a ``jit`` traced under
+    ``jax.sharding.set_mesh`` the kernel runs in a ``shard_map`` over the
+    mesh's batch and head axes — attention is independent per (batch, head),
+    so no collective is needed. Axes that divide neither dim, and a mask's
+    non-batch dims, stay replicated. With no ambient mesh, one device, or
+    every axis already manual (the caller is itself inside a ``shard_map``)
+    this is a plain call."""
+    fn = functools.partial(flash_attention, causal=causal, scale=scale)
+    mesh = jax.sharding.get_abstract_mesh()
+    free = [a for a in mesh.axis_names
+            if a not in mesh.manual_axes and mesh.shape[a] > 1]
+    if not free:
+        return fn(q, k, v, mask)
+
+    def pick(names, dim):
+        return next((a for a in names
+                     if a in free and dim % mesh.shape[a] == 0), None)
+
+    b_ax, h_ax = pick(_BATCH_AXES, q.shape[0]), pick(_HEAD_AXES, q.shape[1])
+    spec = P(b_ax, h_ax, None, None)
+    mspec = None if mask is None else P(b_ax, *([None] * (mask.ndim - 1)))
+    return jax.shard_map(fn, in_specs=(spec, spec, spec, mspec),
+                         out_specs=spec, check_vma=False)(q, k, v, mask)
+
+
 def dot_product_attention(q, k, v, mask=None, *, causal=False, scale=None, impl: str = "auto"):
     """Front door used by nn layers / the transformer. impl: auto|xla|flash.
 
@@ -632,12 +666,12 @@ def dot_product_attention(q, k, v, mask=None, *, causal=False, scale=None, impl:
     sequence reaches one 128-block (the pad shim handles non-multiples
     above that; below it, padding tiny T up to 128² blocks would cost more
     than the dense softmax it replaces). Only a full per-query
-    [B,1,Tq,Tk] score mask falls back to the dense XLA path.
+    [B,1,Tq,Tk] score mask falls back to the dense XLA path. Under an
+    ambient mesh the kernel runs per shard (:func:`_flash_per_shard`).
     """
-    if impl == "flash":
-        return flash_attention(q, k, v, mask, causal=causal, scale=scale)
-    if (impl == "auto" and jax.default_backend() == "tpu"
+    if impl == "flash" or (
+            impl == "auto" and jax.default_backend() == "tpu"
             and min(q.shape[-2], k.shape[-2]) >= 128
             and (mask is None or _as_key_mask(mask) is not None)):
-        return flash_attention(q, k, v, mask, causal=causal, scale=scale)
+        return _flash_per_shard(q, k, v, mask, causal=causal, scale=scale)
     return mha_reference(q, k, v, mask, causal=causal, scale=scale)

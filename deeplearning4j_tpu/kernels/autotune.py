@@ -1,8 +1,8 @@
 """Persistent Pallas block-size autotuner (ISSUE 12 tentpole layer 3).
 
 The flash-attention kernel's block sizes were a two-entry hand-measured
-table (128² default, (512, 1024) at T ≥ 4096 — BASELINE.md r5, 3.6× at
-T=8192). CUDA-L1 (PAPERS.md 2507.14111) and the GPU↔CPU transpilation work
+table (128² default, (512, 1024) at T ≥ 4096 — timed by hand on a v5e
+before PR 1; ROADMAP S7 re-measures it). CUDA-L1 (PAPERS.md 2507.14111) and the GPU↔CPU transpilation work
 (2207.00257) both land on the same lesson: kernel parameters must be
 *measured per (op, shape, dtype)*, not assumed — and the measurements must
 persist, or every process pays the search again.
@@ -15,10 +15,11 @@ Three pieces:
   (:func:`static_flash_blocks`) answers. Shape buckets reuse
   ``common.bucketing`` so nearby shapes share one entry, exactly like they
   share one XLA executable.
-- :class:`AutotuneTable` — the JSON table persisting winners next to the
-  executable cache (``$TDL_COMPILE_CACHE_DIR/autotune/`` by default,
-  ``TDL_AUTOTUNE_DIR`` to re-point), keyed per backend so a TPU table never
-  leaks onto GPU.
+- :class:`AutotuneTable` — the JSON table persisting winners under
+  ``TDL_AUTOTUNE_DIR``, keyed per backend so a TPU table never leaks onto
+  GPU. With the variable unset there is no default table: block sizes come
+  from the static table in this file, so no state outside git steers the
+  kernel.
 - :func:`autotune_flash_attention` — the measured search: timed best-of-N
   per candidate with warmup discard, fwd+bwd (training is the workload that
   matters), and a regression guard — a "winner" that measures slower than
@@ -50,7 +51,7 @@ ENV_DIR = "TDL_AUTOTUNE_DIR"
 
 #: candidate (block_q, block_k) search grid — multiples of the 128-lane MXU
 #: tile (see /opt guide tiling constraints); the hand-measured winners at
-#: both ends of the BASELINE.md grid are members, so exact-match against
+#: both ends of that hand-timed grid are members, so exact-match against
 #: the static table is always reachable.
 FLASH_CANDIDATES: Tuple[Tuple[int, int], ...] = (
     (128, 128), (128, 256), (256, 256), (256, 512),          # block-ok: candidate grid
@@ -63,11 +64,11 @@ _VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
 
 def static_flash_blocks(Tq: int, Tk: int) -> Tuple[int, int]:
-    """The hand-measured fallback table (BASELINE.md r5 long-context grid):
-    coarse tiles win at long T because the Pallas grid runs sequentially
-    per core — (512, 1024) measured 3.6× faster than 128² at T=8192."""
+    """The hand-timed fallback table: coarse tiles win at long T because
+    the Pallas grid runs sequentially per core. Timed on a v5e before PR 1;
+    not re-measured on today's code (ROADMAP S7)."""
     if min(Tq, Tk) >= 4096:
-        return 512, 1024  # block-ok: hand-measured long-T entry (r5: 3.6x at T=8192)
+        return 512, 1024  # block-ok: hand-timed long-T entry
     return 128, 128  # block-ok: hand-measured default entry
 
 
@@ -193,19 +194,13 @@ _TABLE_LOCK = threading.Lock()
 
 
 def default_table_path() -> Optional[str]:
-    """``TDL_AUTOTUNE_DIR`` wins; else the table lives next to the
-    executable cache (``$TDL_COMPILE_CACHE_DIR/autotune/``) so a gang
-    respawn restores executables AND the block sizes they were built for
-    from the same workdir; None when neither is configured."""
+    """``$TDL_AUTOTUNE_DIR/autotune_<backend>.json``, or None when the
+    variable is unset. The table deliberately does NOT live beside the
+    executable cache: a table left in a shared cache directory by one run
+    would change the next run's block sizes."""
     import jax
 
-    from ..common import compile_cache
-
     d = os.environ.get(ENV_DIR)
-    if not d:
-        compile_cache.maybe_enable_from_env()
-        base = compile_cache.cache_dir()
-        d = os.path.join(base, "autotune") if base else None
     if not d:
         return None
     return os.path.join(d, f"autotune_{jax.default_backend()}.json")
@@ -323,7 +318,7 @@ def autotune_flash_attention(B: int, H: int, T: int, D: int,
     if interpret:
         # deterministic fallback: the Pallas interpreter's wall time says
         # nothing about Mosaic tiles, so "measuring" would persist noise.
-        # The static table IS the measured answer at every BASELINE.md grid
+        # The static table is the answer at every hand-timed grid
         # point; record it unmeasured so lookups stay stable and tests can
         # assert exact-match with the hand-picked table.
         entry = {"block_q": static_bq, "block_k": static_bk,
@@ -356,24 +351,24 @@ def autotune_flash_attention(B: int, H: int, T: int, D: int,
 
     _, _, trials_counter = _metrics()
     timings: Dict[Tuple[int, int], float] = {}
+    last_error: Optional[Exception] = None
     for bq, bk in cands:
         try:
             timings[(bq, bk)] = _time_best_of(run_for(bq, bk), q, k, v,
                                               trials=trials)
             trials_counter.labels("flash_attention").inc(trials)
         except Exception as e:  # a candidate the hardware rejects is skipped
-            log.info("autotune: candidate (%d, %d) failed at T=%d D=%d: %s",
-                     bq, bk, T, D, e)
+            log.warning("autotune: candidate (%d, %d) failed at T=%d D=%d: "
+                        "%s", bq, bk, T, D, e)
+            last_error = e
     if not timings:
-        # every candidate failed (transient OOM etc.): nothing was measured
-        # — fall back to the static blocks but record that honestly, so
-        # the entry reads as a fallback (retried next search), never as a
-        # measured table winner with junk best_us
-        entry = {"block_q": static_bq, "block_k": static_bk,
-                 "measured": False, "source": "all-candidates-failed",
-                 "trials": 0}
-        t.record(key, entry, persist=persist)
-        return entry
+        # the compiled kernel ran for NO candidate, the static choice
+        # included: the kernel is broken on this device, and recording a
+        # fallback entry would hide that behind a table row
+        raise RuntimeError(
+            f"autotune: every flash-attention candidate {cands} failed on "
+            f"{jax.default_backend()} at B={B} H={H} T={T} D={D}"
+        ) from last_error
     static_s = timings.get((static_bq, static_bk), float("inf"))
     best = min(timings, key=timings.get)
     if timings[best] > static_s:

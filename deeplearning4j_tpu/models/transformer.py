@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..common import jax_compat
 from ..kernels.attention import dot_product_attention, ring_attention, ulysses_attention
 
 
@@ -174,19 +173,19 @@ def _attention(cfg: TransformerConfig, q, k, v, pad_mask):
         # ring = ppermute pipeline (longest T); ulysses = 2 all-to-alls
         # swapping seq↔head sharding (lower latency at moderate T).
         kernel = ring_attention if cfg.attn_impl == "ring" else ulysses_attention
-        mesh = jax_compat.get_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         tp = "tp" if "tp" in mesh.axis_names else None
         dp = "dp" if "dp" in mesh.axis_names else None
         spec = P(dp, tp, cfg.sequence_axis, None)
         if pad_mask is not None:
             mspec = P(dp, cfg.sequence_axis)
-            f = jax_compat.shard_map(
+            f = jax.shard_map(
                 lambda a, b, c, m: kernel(
                     a, b, c, axis_name=cfg.sequence_axis, causal=cfg.causal, key_mask=m),
                 mesh=mesh, in_specs=(spec, spec, spec, mspec), out_specs=spec,
             )
             return f(q, k, v, pad_mask)
-        f = jax_compat.shard_map(
+        f = jax.shard_map(
             functools.partial(kernel, axis_name=cfg.sequence_axis, causal=cfg.causal),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         )

@@ -1,8 +1,8 @@
 """Compile-cache hit/miss metrics, attributed per jitted function (ISSUE 12).
 
 jax's persistent compilation cache (enabled by
-``common.compile_cache.enable`` / the ``TDL_COMPILE_CACHE_DIR`` env
-contract) emits plain monitoring events:
+``common.compile_cache.enable``, placed by ``JAX_COMPILATION_CACHE_DIR`` or
+at ``<checkout>/.jax_cache``) emits plain monitoring events:
 
 - ``/jax/compilation_cache/cache_hits`` — an executable was restored from
   disk (``backend_compile`` never ran; the monitor also marks the thread so

@@ -108,7 +108,7 @@ class DeviceMemoryWatchdog:
             if limit:
                 self._limit.labels(label).set(int(limit))
         if not saw_stats:
-            # CPU (and some tunnel) backends expose no per-device stats;
+            # the CPU backend exposes no per-device stats;
             # host RSS is the best available proxy for the smoke tier
             rss = host_rss_bytes()
             out["host"] = rss
@@ -197,11 +197,13 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # production serving replica wants cache counters without churn tracking).
 # One module-level store, thread-keyed like the per-watchdog tables.
 #
-# Event ordering with the persistent cache ON (measured against jax 0.4.37,
-# pinned by tests/test_compile_cache.py): the backend_compile duration event
-# wraps jax's WHOLE compile_or_get_cached — it fires on cache HITS too (a
-# few ms of deserialization), and the cache hit/miss events fire INSIDE the
-# timed block, i.e. BEFORE the duration event. So:
+# Event ordering with the persistent cache ON (jax 0.9.0: read in
+# jax/_src/compiler.py + interpreters/pxla.py, pinned on CPU by
+# tests/test_compile_cache.py, and checked on a TPU v5e by chip_smoke.py's
+# warm run — hits > 0 with xla_compiles staying 0): the backend_compile
+# duration event wraps jax's WHOLE compile_or_get_cached — it fires on cache
+# HITS too (a few ms of deserialization), and the cache hit/miss events fire
+# INSIDE the timed block, i.e. BEFORE the duration event. So:
 #   - cache_misses → peek the pending announcement (the duration event that
 #     follows will claim it for the compile counters);
 #   - cache_hits → consume the pending announcement (nothing compiled) and

@@ -6,40 +6,35 @@ NativeOps C ABI (SURVEY §2.1 N13 / §2.2 J5). ctypes is the binding layer
 codecs run truly parallel to the training loop's Python thread.
 
 The library lazily builds from source on first use (g++ is baked into the
-image) and caches next to this file; set ``TDL_NATIVE_DISABLE=1`` to force
-the numpy fallbacks in ``parallel.compression`` / ``data.records``.
+image) and caches next to this file under a name keyed to the source's
+content hash (``_build``), so a stale binary is never loaded; set
+``TDL_NATIVE_DISABLE=1`` to force the numpy fallbacks in
+``parallel.compression`` / ``data.records``.
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from typing import Optional, Tuple
 
 import numpy as np
 
+from . import _build
+
 _LIB: Optional[ctypes.CDLL] = None
 _LOCK = threading.Lock()
 _BUILD_FAILED = False
 
-_SRC_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))), "native")
-_SO_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "libtnd.so")
+_SOURCES = ("tnd.cpp", "tnd.h")
 
 
-def _build() -> Optional[str]:
-    src = os.path.join(_SRC_DIR, "tnd.cpp")
-    if not os.path.exists(src):
-        return None
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           "-I", _SRC_DIR, src, "-o", _SO_PATH]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return _SO_PATH
-    except (subprocess.SubprocessError, FileNotFoundError):
-        return None
+def _lib_path() -> Optional[str]:
+    return _build.build_or_reuse("libtnd", _SOURCES, lambda out: [
+        "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
+        "-I", _build.SRC_DIR, os.path.join(_build.SRC_DIR, "tnd.cpp"),
+        "-o", out], timeout=120)
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -52,7 +47,7 @@ def get_lib() -> Optional[ctypes.CDLL]:
     with _LOCK:
         if _LIB is not None:
             return _LIB
-        path = _SO_PATH if os.path.exists(_SO_PATH) else _build()
+        path = _lib_path()
         if path is None:
             _BUILD_FAILED = True
             return None

@@ -20,19 +20,16 @@ from __future__ import annotations
 import ctypes
 import glob
 import os
-import subprocess
 import threading
 from typing import Optional
 
 import numpy as np
 
+from . import _build
+
 _LIB: Optional[ctypes.CDLL] = None
 _LOCK = threading.Lock()
 _BUILD_FAILED = False
-
-_SRC_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))), "native")
-_SO_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "libtnd_pjrt.so")
 
 
 def _tf_include_dir() -> Optional[str]:
@@ -68,18 +65,18 @@ def default_plugin_path() -> Optional[str]:
     return None
 
 
-def _build() -> Optional[str]:
-    src = os.path.join(_SRC_DIR, "tnd_pjrt.cpp")
-    inc = _tf_include_dir()
-    if not os.path.exists(src) or inc is None:
-        return None
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-I", inc,
-           src, "-o", _SO_PATH, "-ldl"]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=180)
-        return _SO_PATH
-    except (subprocess.SubprocessError, FileNotFoundError):
-        return None
+def _lib_path() -> Optional[str]:
+    """The smoke surface built from the current ``tnd_pjrt.cpp`` (name keyed
+    to its content hash — see ``_build``), compiling on first use."""
+    def command(out):
+        inc = _tf_include_dir()
+        if inc is None:
+            return None
+        return ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-I", inc,
+                os.path.join(_build.SRC_DIR, "tnd_pjrt.cpp"), "-o", out,
+                "-ldl"]
+
+    return _build.build_or_reuse("libtnd_pjrt", ("tnd_pjrt.cpp",), command)
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -91,7 +88,7 @@ def get_lib() -> Optional[ctypes.CDLL]:
     with _LOCK:
         if _LIB is not None:
             return _LIB
-        path = _SO_PATH if os.path.exists(_SO_PATH) else _build()
+        path = _lib_path()
         if path is None:
             _BUILD_FAILED = True
             return None

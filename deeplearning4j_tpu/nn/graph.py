@@ -29,10 +29,10 @@ from .multilayer import _grad_normalize, _mask_frozen, _LazyScoreMixin
 
 class ComputationGraph(_LazyScoreMixin):
     def __init__(self, conf: ComputationGraphConfiguration):
-        # ISSUE 12: honor TDL_COMPILE_CACHE_DIR before the first jit builds
+        # persistent executable cache on before the first jit builds
         from ..common import compile_cache
 
-        compile_cache.maybe_enable_from_env()
+        compile_cache.enable()
         self.conf = conf
         self.params_: Dict[str, Any] = {}
         self.bn_state: Dict[str, Any] = {}

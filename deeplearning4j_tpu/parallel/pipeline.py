@@ -42,7 +42,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..common import jax_compat
 from ..monitoring import aggregate, flight
 from .mesh import AXIS_DATA, AXIS_PIPE
 from .trainer import ParallelTrainer
@@ -63,7 +62,7 @@ def _pipeline_body(stage_fn, params_local, xs, aux, axis: str):
     (t - s), so each stage indexes its own aux slice. Returns ys [M, mb, ...]
     (pipe-replicated — the last stage's results psum-broadcast over the axis).
     """
-    n_stages = jax.lax.psum(1, axis)
+    n_stages = jax.lax.axis_size(axis)
     stage = jax.lax.axis_index(axis)
     my_params = _squeeze_leading(params_local)
     M = xs.shape[0]
@@ -117,7 +116,7 @@ def _pipeline_body_1f1b_bwd(stage_fn, params_local, xs, aux, dys, axis: str,
     (leading dim restored to 1 for the pipe out_spec) and the input
     cotangents (written by stage 0, psum-broadcast like the forward outputs).
     """
-    n_stages = jax.lax.psum(1, axis)
+    n_stages = jax.lax.axis_size(axis)
     stage = jax.lax.axis_index(axis)
     my_params = _squeeze_leading(params_local)
     M = xs.shape[0]
@@ -189,12 +188,12 @@ def _spmd_pipeline_1f1b(stage_fn, stacked_params, xs, mesh, *, pipe_axis,
     xspec = P(None, dp, *([None] * (xs.ndim - 2)))
     aspec = (None if aux is None
              else jax.tree.map(lambda a: P(None, dp, *([None] * (a.ndim - 2))), aux))
-    fwd_f = jax_compat.shard_map(
+    fwd_f = jax.shard_map(
         functools.partial(_pipeline_body, stage_fn, axis=pipe_axis),
         mesh=mesh, in_specs=(pspec, xspec, aspec), out_specs=xspec,
         check_vma=False,
     )
-    bwd_f = jax_compat.shard_map(
+    bwd_f = jax.shard_map(
         functools.partial(_pipeline_body_1f1b_bwd, stage_fn, axis=pipe_axis,
                           data_axis=dp),
         mesh=mesh, in_specs=(pspec, xspec, aspec, xspec),
@@ -265,7 +264,7 @@ def spmd_pipeline(stage_fn: Callable[..., Any], stacked_params, xs, mesh: Mesh,
     xspec = P(None, dp, *([None] * (xs.ndim - 2)))
     aspec = (None if aux is None
              else jax.tree.map(lambda a: P(None, dp, *([None] * (a.ndim - 2))), aux))
-    f = jax_compat.shard_map(
+    f = jax.shard_map(
         functools.partial(_pipeline_body, stage_fn, axis=pipe_axis),
         mesh=mesh, in_specs=(pspec, xspec, aspec), out_specs=xspec,
         check_vma=False,

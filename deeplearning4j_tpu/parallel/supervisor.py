@@ -67,7 +67,6 @@ import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from ..common import compile_cache
 from ..monitoring import aggregate, flight, history
 from ..monitoring.flight import FlightRecorder
 from ..monitoring.heartbeat import ENV_DIR, ENV_INTERVAL, read_heartbeat
@@ -228,6 +227,7 @@ class GangSupervisor:
         proc_prefix: Optional[str] = None,
         registry: Optional[MetricsRegistry] = None,
     ):
+        launcher.check_platform(platform, n_processes)
         self.target = target
         self.n_processes = n_processes
         self.n_local_devices = n_local_devices
@@ -296,10 +296,6 @@ class GangSupervisor:
         self.spool_dir = os.path.join(self.workdir, "spool")
         #: stable per-proc history-ring dir (ISSUE 11): windowed /history
         self.history_dir = os.path.join(self.workdir, "history")
-        #: stable persistent-executable-cache dir (ISSUE 12): a respawned
-        #: incarnation restores its XLA executables from here instead of
-        #: recompiling — compiles stay flat across the restart
-        self.compile_cache_dir = os.path.join(self.workdir, "compile_cache")
 
         self.events: List[GangEvent] = []
         self.restarts = 0           # budgeted restarts performed (total)
@@ -427,16 +423,14 @@ class GangSupervisor:
         # metrics spool: windowed alert/SLO views spanning a restart are the
         # point — read_rings dedupes incarnations by newest ring per proc
         env.setdefault(history.ENV_DIR, os.path.join(self.workdir, "history"))
-        # persistent executable cache (ISSUE 12): STABLE across attempts by
-        # construction — the whole point is that incarnation N+1 restores
-        # the executables incarnation N compiled, so a respawn-from-
-        # checkpoint pays deserialization, not XLA compilation
-        env.setdefault(compile_cache.ENV_DIR,
-                       os.path.join(self.workdir, "compile_cache"))
+        # the persistent executable cache needs nothing here: a child
+        # inherits JAX_COMPILATION_CACHE_DIR when it is set and resolves the
+        # same <checkout>/.jax_cache as this process when it is not
+        # (common.compile_cache), so incarnation N+1 restores the
+        # executables incarnation N compiled
         self.flight_dir = env[flight.ENV_DIR]
         self.spool_dir = env[aggregate.ENV_DIR]
         self.history_dir = env[history.ENV_DIR]
-        self.compile_cache_dir = env[compile_cache.ENV_DIR]
         return env
 
     def _spawn(self, attempt: int):

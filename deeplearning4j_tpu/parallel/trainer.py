@@ -72,13 +72,12 @@ class ParallelTrainer:
 
     def __init__(self, net, mesh: Optional[Mesh] = None, data_axis: str = AXIS_DATA,
                  sharding_rules=None, mesh_layout=None, bucketing=None):
-        # persistent executable cache (ISSUE 12): a respawned gang rank
-        # constructs its trainer before its first compile — honoring the
-        # supervisor's TDL_COMPILE_CACHE_DIR here restores executables from
-        # the stable workdir/compile_cache instead of recompiling
+        # persistent executable cache: a respawned gang rank constructs its
+        # trainer before its first compile, so enabling here restores its
+        # executables from disk instead of recompiling
         from ..common import compile_cache
 
-        compile_cache.maybe_enable_from_env()
+        compile_cache.enable()
         self.net = net
         if bucketing is not None:
             # ISSUE 12: pad-to-bucket on the fit paths; the mesh-divisibility

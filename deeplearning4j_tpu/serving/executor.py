@@ -217,11 +217,11 @@ class BatchingInferenceExecutor:
     # -- lifecycle ---------------------------------------------------------
 
     def start(self) -> "BatchingInferenceExecutor":
-        # ISSUE 12: honor TDL_COMPILE_CACHE_DIR before the warmup compiles —
-        # a warming replica then restores its bucket executables from disk
+        # executable cache on before the warmup compiles — a warming
+        # replica then restores its bucket executables from disk
         from ..common import compile_cache
 
-        compile_cache.maybe_enable_from_env()
+        compile_cache.enable()
         with self._cv:
             if self._thread is not None:
                 return self

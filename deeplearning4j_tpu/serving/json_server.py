@@ -95,18 +95,13 @@ class JsonModelServer:
                  default_deadline_ms: float = DEFAULT_DEADLINE_MS,
                  max_body_bytes: int = DEFAULT_MAX_BODY_BYTES,
                  warmup_input=None, registry=None, span_sample_n: int = 1,
-                 compile_cache_dir: Optional[str] = None,
                  warmup_all_buckets: Optional[bool] = None,
                  generative_session=None, default_max_new_tokens: int = 32):
-        # ISSUE 12: an explicit cache dir wins; else the TDL_COMPILE_CACHE_DIR
-        # env contract — enabled before any warmup compile so a warming
+        # executable cache on before any warmup compile, so a warming
         # replica restores executables from disk
         from ..common import compile_cache
 
-        if compile_cache_dir:
-            compile_cache.enable(compile_cache_dir)
-        else:
-            compile_cache.maybe_enable_from_env()
+        compile_cache.enable()
         self.warmup_all_buckets = warmup_all_buckets
         self.model = model
         #: ISSUE 13: a decode slot pool (``models.transformer.DecodeSlotPool``
@@ -213,14 +208,6 @@ class JsonModelServer:
         def max_new_tokens(self, n: int):
             """Default per-request generation budget (generative mode)."""
             self._kw["default_max_new_tokens"] = n
-            return self
-
-        def compile_cache_dir(self, path: str):
-            """Persist compiled executables under ``path`` (ISSUE 12): a
-            restarted replica restores them from disk instead of re-paying
-            XLA compilation at warmup. Same contract as exporting
-            ``TDL_COMPILE_CACHE_DIR``."""
-            self._kw["compile_cache_dir"] = path
             return self
 
         def warmup_all_buckets(self, flag: bool = True):

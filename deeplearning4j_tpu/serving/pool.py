@@ -15,10 +15,10 @@ Three cooperating parts:
 - **replica processes** — each runs a replica target (``module:function`` or
   ``/path/file.py:fn`` returning a ``JsonModelServer``), publishes its bound
   port through a port file, beats a per-replica heartbeat, spools metrics
-  with a RESTART-STABLE ``proc=replica{N}`` identity, and — because
-  ``TDL_COMPILE_CACHE_DIR`` points at one stable pool-wide dir — warms from
-  the persistent executable cache (ISSUE 12), so a respawn pays
-  deserialization, not XLA compilation;
+  with a RESTART-STABLE ``proc=replica{N}`` identity, and — because every
+  replica resolves the same executable-cache directory
+  (``common.compile_cache``) — warms from the persistent cache, so a
+  respawn pays deserialization, not XLA compilation;
 - **the front router** — one HTTP door with least-loaded dispatch over the
   READY replicas, per-replica circuit breakers (consecutive connection/5xx
   failures open a replica for a cooldown), transparent failover on
@@ -108,6 +108,23 @@ def _load_target(target: str):
     return getattr(mod, fn_name)
 
 
+def _initialized_accelerator() -> Optional[str]:
+    """Name of a non-CPU jax backend THIS process has already initialised
+    (it then holds that chip), else None. Never initialises one itself."""
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    backends = getattr(bridge, "_backends", None) or {}
+    return next((name for name in backends if name != "cpu"), None)
+
+
+def local_tpu_chips() -> int:
+    """TPU chips on this host, counted from their device files — without
+    opening one (asking jax would take the chip for this process)."""
+    import glob
+
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
 def _replica_main(argv: Sequence[str]) -> None:
     """Replica process entry: build the target's ``JsonModelServer``, start
     it, publish the bound port, then beat/spool until SIGTERM asks for a
@@ -115,9 +132,9 @@ def _replica_main(argv: Sequence[str]) -> None:
     target = argv[0]
     replica_id = int(os.environ.get(ENV_REPLICA_ID, "0"))
     port_file = os.environ[ENV_PORT_FILE]
-    # honor the pool's stable executable cache BEFORE the target builds a
-    # model: warmup then restores executables instead of recompiling
-    compile_cache.maybe_enable_from_env()
+    # executable cache on BEFORE the target builds a model: warmup then
+    # restores what an earlier replica compiled instead of recompiling
+    compile_cache.enable()
     server = _load_target(target)()
     if server is None:
         raise RuntimeError(f"replica target {target!r} returned None — it "
@@ -248,7 +265,6 @@ class ServingPool:
         #: GangSupervisor (spool merge dedupes by newest per proc identity)
         self.spool_dir = os.path.join(self.workdir, "spool")
         self.history_dir = os.path.join(self.workdir, "history")
-        self.compile_cache_dir = os.path.join(self.workdir, "compile_cache")
         self.hb_dir = os.path.join(self.workdir, "hb")
         self.flight_dir = os.path.join(self.workdir, "flight")
         #: run identity (ISSUE 16): replicas inherit it via TDL_RUN_ID, so
@@ -277,9 +293,37 @@ class ServingPool:
 
     # -- lifecycle ---------------------------------------------------------
 
+    def _check_chip_available(self) -> None:
+        """Fail NOW, with the reason, when the replicas could never reach an
+        accelerator — instead of letting ``wait_ready`` run out. A chip
+        belongs to one process at a time, each replica is its own process on
+        the backend its environment names, and nothing pins a replica to one
+        chip of a host: a replica opens every local chip."""
+        env = {**os.environ, **self.extra_env}
+        first = env.get("JAX_PLATFORMS", "").split(",")[0].strip().lower()
+        if first == "cpu":
+            return  # replicas never open a chip
+        held = _initialized_accelerator()
+        if held is not None:
+            raise RuntimeError(
+                f"ServingPool.start(): this process has initialised the "
+                f"{held!r} backend and so holds the chip; replica processes "
+                f"on the default backend could never come up. Start the "
+                f"pool from a process that has not touched jax, or run the "
+                f"replicas on CPU (extra_env={{'JAX_PLATFORMS': 'cpu'}}).")
+        chips = local_tpu_chips()
+        if chips and self.desired > 1:
+            raise RuntimeError(
+                f"ServingPool.start(): {self.desired} replicas on a host "
+                f"with {chips} TPU chip(s) — each replica process opens "
+                f"every local chip (there is no per-replica chip pinning), "
+                f"so only one can start. Use replicas=1 here, or run the "
+                f"replicas on CPU (extra_env={{'JAX_PLATFORMS': 'cpu'}}).")
+
     def start(self) -> "ServingPool":
         if self._monitor_thread is not None:
             return self
+        self._check_chip_available()
         self._stop_evt.clear()
         from concurrent.futures import ThreadPoolExecutor
 
@@ -701,7 +745,7 @@ class ServingPool:
     def _child_env(self, handle: ReplicaHandle) -> Dict[str, str]:
         """One replica's env contract (the GangSupervisor contracts, minus
         the gang): caller ``extra_env`` wins for the SHARED data contracts
-        (spool/history/flight/compile-cache dirs); per-replica IDENTITY
+        (spool/history/flight dirs); per-replica IDENTITY
         keys (replica id, port file, proc name, heartbeat dir/interval) are
         pool-owned and hard-assigned — inheriting a parent's values (e.g. a
         pool launched inside a supervised rank) would merge every replica's
@@ -727,10 +771,11 @@ class ServingPool:
         env.setdefault(history.ENV_DIR, self.history_dir)
         env.setdefault(flight.ENV_DIR, self.flight_dir)
         env.setdefault(flight.ENV_RUN_ID, self.run_id)
-        # stable executable cache: replica N+1's warmup (and a respawn of
-        # replica N) restores what the first warmup compiled — the ISSUE 12
-        # cache is what makes elastic scale-out cheap
-        env.setdefault(compile_cache.ENV_DIR, self.compile_cache_dir)
+        # the executable cache needs nothing here: a replica inherits
+        # JAX_COMPILATION_CACHE_DIR when it is set and resolves the same
+        # <checkout>/.jax_cache as this process when it is not, so replica
+        # N+1's warmup (and a respawn of replica N) restores what the first
+        # warmup compiled
         return env
 
     def _spawn_replica(self, handle: Optional[ReplicaHandle] = None,

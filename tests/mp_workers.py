@@ -655,7 +655,6 @@ def tp_step_losses(mesh, steps=3):
     deterministic dp×tp transformer training losses on the given mesh."""
     import jax
 
-    from deeplearning4j_tpu.common import jax_compat
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
     from jax.tree_util import tree_map
@@ -699,7 +698,7 @@ def tp_step_losses(mesh, steps=3):
 
     rng = jax.random.wrap_key_data(_rep_arr(jax.random.key_data(jax.random.key(9))))
     losses = []
-    with jax_compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         for i in range(steps):
             it = _rep_arr(np.asarray(i, np.int32))
             params, opt, loss = step(params, opt, batch, it, rng)

@@ -115,33 +115,26 @@ def test_regression_guard_keeps_static_winner(monkeypatch):
     assert (e["block_q"], e["block_k"]) == (256, 256)
 
 
-def test_all_failed_candidates_record_unmeasured_fallback(tmp_path,
-                                                          monkeypatch):
-    """When every timed candidate fails (transient OOM, missing backend)
-    the static fallback is recorded with measured:false — never as a
-    'measured' table winner carrying best_us 0.0 that future lookups
-    would report as a real measurement."""
+def test_all_failed_candidates_raise(tmp_path, monkeypatch):
+    """A compiled search in which EVERY candidate fails — the static choice
+    included — means the kernel does not run on this device. That must
+    raise with the device's error attached, never record a fallback row
+    that later lookups would serve as if nothing happened."""
     import deeplearning4j_tpu.kernels.autotune as mod
-    from deeplearning4j_tpu.kernels.autotune import static_flash_blocks
 
     def boom(fn, *args, trials, warmup=1):
-        raise RuntimeError("RESOURCE_EXHAUSTED: transient OOM")
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
 
     monkeypatch.setattr(mod, "_time_best_of", boom)
     table = AutotuneTable(str(tmp_path / "t.json"))
-    e = autotune_flash_attention(
-        1, 2, 256, 64, np.float32, table=table, interpret=False,
-        candidates=[(128, 256)], trials=1, include_backward=False)
-    assert e["measured"] is False
-    assert e["source"] == "all-candidates-failed"
-    assert (e["block_q"], e["block_k"]) == static_flash_blocks(256, 256)
-    assert "best_us" not in e
-    # persisted form keeps the honesty flag
-    reloaded = AutotuneTable(str(tmp_path / "t.json"))
-    assert len(reloaded) == 1
-    key = mod.shape_key("flash_attention", B=1, H=2, Tq=256, Tk=256, D=64,
-                        dtype="float32")
-    assert reloaded.lookup(key)["measured"] is False
+    with pytest.raises(RuntimeError, match="every flash-attention candidate"
+                       ) as ei:
+        autotune_flash_attention(
+            1, 2, 256, 64, np.float32, table=table, interpret=False,
+            candidates=[(128, 256)], trials=1, include_backward=False)
+    assert "Mosaic failed" in str(ei.value.__cause__)
+    assert len(table) == 0
+    assert not os.path.exists(str(tmp_path / "t.json"))
 
 
 def test_candidate_validity_filters():
@@ -221,18 +214,23 @@ def test_table_roundtrip_and_corruption_tolerance(tmp_path):
                          backend="cpu").lookup("k1") is None
 
 
-def test_default_table_lives_next_to_compile_cache(tmp_path, monkeypatch):
-    from deeplearning4j_tpu.common import compile_cache
-
+def test_no_default_table_without_explicit_dir(tmp_path, monkeypatch):
+    """Block sizes come from the static table in the source unless
+    TDL_AUTOTUNE_DIR is set explicitly: the table never lives beside the
+    executable cache, where one run's leftovers would steer the next."""
     monkeypatch.delenv(autotune.ENV_DIR, raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
     autotune.reset_table()
     try:
-        compile_cache.enable(str(tmp_path / "cc"))
-        path = autotune.default_table_path()
-        assert path is not None
-        assert os.path.join(str(tmp_path), "cc", "autotune") in path
+        assert autotune.default_table_path() is None
+        assert autotune.get_table().path is None
+        assert autotune.resolve_blocks(
+            "flash_attention", B=1, H=12, Tq=8192, Tk=8192, D=64,
+            dtype="bfloat16") == autotune.static_flash_blocks(8192, 8192)
+        monkeypatch.setenv(autotune.ENV_DIR, str(tmp_path / "at"))
+        assert autotune.default_table_path().startswith(
+            str(tmp_path / "at"))
     finally:
-        compile_cache.disable()
         autotune.reset_table()
 
 

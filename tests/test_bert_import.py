@@ -11,7 +11,6 @@ import pytest
 torch = pytest.importorskip("torch")
 transformers = pytest.importorskip("transformers")
 
-from deeplearning4j_tpu.common import jax_compat
 from deeplearning4j_tpu.models.bert_import import (
     config_from_hf,
     import_hf_bert,
@@ -94,7 +93,7 @@ def test_imported_model_fine_tunes_under_dp():
         "weights": jnp.asarray((rs.rand(B, T) < 0.15).astype(np.float32)),
     }
     batch = {k: jax.device_put(v, NamedSharding(mesh, bspec[k])) for k, v in batch.items()}
-    with jax_compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         losses = []
         for i in range(4):
             params, opt, loss = step(params, opt, batch,

@@ -1,18 +1,24 @@
-"""Persistent compiled-executable cache (ISSUE 12 tentpole layer 2).
+"""Persistent compiled-executable cache (``common.compile_cache``).
 
 Acceptance pins:
-- the restart contract: two PROCESSES sharing one TDL_COMPILE_CACHE_DIR —
-  the second pays ZERO per-fn compiles after warmup and shows cache-hit
+- the restart contract: two PROCESSES sharing one JAX_COMPILATION_CACHE_DIR
+  — the second pays ZERO per-fn compiles after warmup and shows cache-hit
   counters as evidence;
 - per-fn hit/miss attribution through the note_signature thread
   announcements;
 - executables restored from disk are NOT counted as compiles (the
   backend_compile duration event wraps jax's cache retrieval too — pinned
   here so a jax upgrade changing that ordering fails loudly);
-- env contract plumbing: GangSupervisor hands every incarnation a STABLE
-  ``workdir/compile_cache``; the serving builder takes an explicit dir;
+- the placement contract: with JAX_COMPILATION_CACHE_DIR set nothing in the
+  package moves ``jax.config.jax_compilation_cache_dir`` or hands a child
+  another directory; unset, parent and children resolve
+  ``<checkout>/.jax_cache``;
 - warmup completeness satellite: with the cache present the executor warms
   EVERY ParallelInference bucket, not just the smallest.
+
+The suite runs with jax's own switch off (conftest:
+JAX_ENABLE_COMPILATION_CACHE=false); the fixture below turns the cache on at
+a tmp dir the way an operator would — by configuring jax, not the package.
 """
 
 import json
@@ -31,16 +37,27 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
-def enabled_cache(tmp_path):
-    """Enable the persistent cache at a tmp dir for one test, restoring the
-    disabled state after (the cache is process-wide jax config — leaking it
-    would slow and dirty every later test)."""
+def cache_placed(tmp_path):
+    """jax configured as if launched with JAX_COMPILATION_CACHE_DIR=<tmp>
+    and the cache switched on; everything restored after (the cache is
+    process-wide jax config — leaking it would slow and dirty every later
+    test)."""
+    import jax
+
     d = str(tmp_path / "compile_cache")
-    compile_cache.enable(d)
+    jax.config.update("jax_compilation_cache_dir", d)
+    jax.config.update("jax_enable_compilation_cache", True)
     try:
         yield d
     finally:
         compile_cache.disable()
+        jax.config.update("jax_compilation_cache_dir", None)
+
+
+@pytest.fixture
+def enabled_cache(cache_placed):
+    assert compile_cache.enable() == cache_placed
+    return cache_placed
 
 
 def _tiny_net():
@@ -145,16 +162,18 @@ def test_cache_bytes_gauge_tracks_directory(enabled_cache):
     assert n == compile_cache.cache_size_bytes(enabled_cache) > 0
 
 
-def test_enable_is_idempotent_and_disable_resets(tmp_path):
-    d = str(tmp_path / "cc")
-    assert compile_cache.enable(d) == compile_cache.enable(d)
-    assert compile_cache.enabled() and compile_cache.cache_dir() == \
-        os.path.abspath(d)
-    compile_cache.disable()
-    assert not compile_cache.enabled()
+def test_enable_is_idempotent_and_disable_never_moves_the_dir(cache_placed):
     import jax
 
-    assert jax.config.jax_compilation_cache_dir is None
+    assert compile_cache.enable() == compile_cache.enable() == cache_placed
+    assert compile_cache.enabled()
+    assert compile_cache.cache_dir() == cache_placed
+    compile_cache.disable()
+    assert not compile_cache.enabled()
+    # off by jax's own switch; the placement is not the package's to touch
+    assert jax.config.jax_enable_compilation_cache is False
+    assert jax.config.jax_compilation_cache_dir == cache_placed
+    assert compile_cache.enable() is None  # stays off until jax says on
 
 
 # ------------------------------------------------- the restart acceptance
@@ -162,8 +181,7 @@ def test_enable_is_idempotent_and_disable_resets(tmp_path):
 
 _RESTART_WORKER = textwrap.dedent("""
     import json, os, sys
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    os.environ["TDL_COMPILE_CACHE_DIR"] = sys.argv[1]
+    import jax
     import numpy as np
     from deeplearning4j_tpu.monitoring import RecompileWatchdog, compilecache
     from deeplearning4j_tpu.nn import MultiLayerNetwork, NeuralNetConfiguration
@@ -187,23 +205,34 @@ _RESTART_WORKER = textwrap.dedent("""
     print(json.dumps({
         "per_fn_compiles": wd.stats()["per_fn_compiles"],
         "hits": stats["hits"], "misses": stats["misses"],
-        "bytes": stats["bytes"],
+        "bytes": stats["bytes"], "dir": stats["dir"],
+        "config_dir": jax.config.jax_compilation_cache_dir,
     }))
 """)
 
 
+def _child_env(cache_dir=None):
+    """A child launched the way an operator would: the cache on, and placed
+    by the environment alone (or not placed at all)."""
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
+               JAX_ENABLE_COMPILATION_CACHE="true")
+    env.pop(compile_cache.ENV_DIR, None)
+    if cache_dir is not None:
+        env[compile_cache.ENV_DIR] = cache_dir
+    return env
+
+
 def _run_restart_worker(cache_dir):
-    env = dict(os.environ, PYTHONPATH=REPO)
     out = subprocess.run(
-        [sys.executable, "-c", _RESTART_WORKER, cache_dir],
-        capture_output=True, text=True, timeout=240, env=env, cwd=REPO)
+        [sys.executable, "-c", _RESTART_WORKER], capture_output=True,
+        text=True, timeout=240, env=_child_env(cache_dir), cwd=REPO)
     assert out.returncode == 0, out.stderr[-2000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 def test_compiles_flat_across_process_restart(tmp_path):
-    """ISSUE 12 acceptance: same TDL_COMPILE_CACHE_DIR across two processes
-    ⇒ the second process records ZERO compiles per fn (every executable —
+    """Same JAX_COMPILATION_CACHE_DIR across two processes ⇒ the second
+    process records ZERO compiles per fn (every executable —
     the announced train step AND the helper jits — restores from disk),
     with cache-hit counters as the evidence."""
     cache_dir = str(tmp_path / "compile_cache")
@@ -211,6 +240,8 @@ def test_compiles_flat_across_process_restart(tmp_path):
     assert run1["per_fn_compiles"].get("MultiLayerNetwork.train_step") == 1
     assert run1["misses"].get("MultiLayerNetwork.train_step") == 1
     assert run1["bytes"] > 0
+    # the program used the directory it was given and never moved it
+    assert run1["dir"] == run1["config_dir"] == cache_dir
 
     run2 = _run_restart_worker(cache_dir)
     assert run2["per_fn_compiles"] == {}, (
@@ -220,103 +251,121 @@ def test_compiles_flat_across_process_restart(tmp_path):
     assert run2["misses"] == {}
 
 
-# ------------------------------------------------------- env contract
+# ------------------------------------------------- the placement contract
 
 
-def test_supervisor_child_env_carries_stable_compile_cache_dir(tmp_path):
+_CONTRACT_WORKER = textwrap.dedent("""
+    import json, os, sys
+    import jax
+    from deeplearning4j_tpu.common import compile_cache
+    from deeplearning4j_tpu.nn import MultiLayerNetwork, NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.conf import DenseLayer, OutputLayer
     from deeplearning4j_tpu.parallel.supervisor import GangSupervisor
+    from deeplearning4j_tpu.serving import JsonModelServer
+    from deeplearning4j_tpu.serving.pool import ReplicaHandle, ServingPool
 
+    work = sys.argv[1]
+    seen = {"import": jax.config.jax_compilation_cache_dir}
+    out = {"enable": compile_cache.enable()}
+    seen["enable"] = jax.config.jax_compilation_cache_dir
+    conf = (NeuralNetConfiguration.Builder().seed(0).list()
+            .layer(DenseLayer(n_in=4, n_out=4, activation="relu"))
+            .layer(OutputLayer(n_out=2, activation="softmax", loss="mcxent"))
+            .build())
+    JsonModelServer.Builder(MultiLayerNetwork(conf).init()).build()
+    seen["server_builder"] = jax.config.jax_compilation_cache_dir
     sup = GangSupervisor("tests.mp_workers:dp_train", n_processes=1,
-                         workdir=str(tmp_path))
-    env1 = sup._child_env(0, str(tmp_path / "hb_0"))
-    env2 = sup._child_env(1, str(tmp_path / "hb_1"))
-    expected = os.path.join(str(tmp_path), "compile_cache")
-    # STABLE across attempts: incarnation N+1 must find incarnation N's
-    # executables (flight dirs, by contrast, are per-attempt)
-    assert env1[compile_cache.ENV_DIR] == expected
-    assert env2[compile_cache.ENV_DIR] == expected
-    assert sup.compile_cache_dir == expected
-    # an operator override through extra_env wins
-    sup2 = GangSupervisor("tests.mp_workers:dp_train", n_processes=1,
-                          workdir=str(tmp_path / "w2"),
-                          extra_env={compile_cache.ENV_DIR: "/elsewhere"})
-    assert sup2._child_env(0, str(tmp_path / "hb"))[
-        compile_cache.ENV_DIR] == "/elsewhere"
-    assert sup2.compile_cache_dir == "/elsewhere"
+                         workdir=os.path.join(work, "gang"))
+    gang_env = sup._child_env(0, os.path.join(work, "hb"))
+    pool = ServingPool("tests/pool_workers.py:stub_server", replicas=1,
+                       workdir=os.path.join(work, "pool"))
+    pool_env = pool._child_env(ReplicaHandle(id=0))
+    seen["children"] = jax.config.jax_compilation_cache_dir
+    print(json.dumps({
+        **out, "seen": seen,
+        "gang_child": gang_env.get(compile_cache.ENV_DIR),
+        "pool_child": pool_env.get(compile_cache.ENV_DIR),
+        "builder_has_dir_option": hasattr(JsonModelServer.Builder,
+                                          "compile_cache_dir"),
+    }))
+""")
 
 
-def test_multiprocess_cpu_gang_skips_cache(tmp_path, monkeypatch):
+def test_cache_placement_contract(tmp_path):
+    """The two halves of the rule, one child process each (run side by side:
+    each is mostly interpreter start-up)."""
+    placed = str(tmp_path / "placed")
+    procs = {
+        key: subprocess.Popen(
+            [sys.executable, "-c", _CONTRACT_WORKER, str(tmp_path / key)],
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+            env=_child_env(cache_dir), cwd=REPO)
+        for key, cache_dir in (("placed", placed), ("unplaced", None))}
+    res = {}
+    for key, proc in procs.items():
+        out, err = proc.communicate(timeout=240)
+        assert proc.returncode == 0, err[-2000:]
+        res[key] = json.loads(out.strip().splitlines()[-1])
+
+    # JAX_COMPILATION_CACHE_DIR set: jax reads it itself, and enable(), the
+    # serving builder, the gang supervisor and the replica pool neither
+    # touch jax.config.jax_compilation_cache_dir nor hand children another
+    # directory — children inherit the variable
+    r = res["placed"]
+    assert r["enable"] == placed
+    assert set(r["seen"].values()) == {placed}, r["seen"]
+    # the pool builds a replica's whole environment, the supervisor only
+    # the extras the launcher lays over os.environ: either way the child
+    # sees the variable as this process does
+    assert r["pool_child"] == placed
+    assert r["gang_child"] is None
+    assert not r["builder_has_dir_option"]
+
+    # unset: parent and children all resolve <checkout>/.jax_cache — derived
+    # from the package path, never a temp name, pid or time — and no child
+    # is handed anything else (it derives the same path from the package)
+    expected = os.path.join(REPO, ".jax_cache")
+    assert compile_cache.default_dir() == expected
+    r = res["unplaced"]
+    assert r["seen"]["import"] is None
+    assert r["enable"] == expected
+    assert r["seen"]["children"] == expected
+    assert r["gang_child"] is None and r["pool_child"] is None
+
+
+def test_multiprocess_cpu_gang_skips_cache(cache_placed, monkeypatch):
     """Reloaded XLA:CPU executables carrying gloo collectives segfault
     (observed: respawned CPU gangs died -11/-6 on their first restored
-    step), so the env contract is deliberately ignored on multi-process
-    CPU — TPU gangs and single-process runs use the cache normally."""
+    step), so the cache is deliberately off on multi-process CPU — TPU gangs
+    and single-process runs use it normally."""
     from jax._src import distributed
 
-    monkeypatch.setenv(compile_cache.ENV_DIR, str(tmp_path / "cc"))
     monkeypatch.setattr(distributed.global_state, "client", object(),
                         raising=False)
-    assert compile_cache.maybe_enable_from_env() is None
+    assert compile_cache.enable() is None
     assert not compile_cache.enabled()
-    # same process, distributed torn down (single-process again): enabled
-    monkeypatch.setattr(distributed.global_state, "client", None,
-                        raising=False)
-    try:
-        assert compile_cache.maybe_enable_from_env() is not None
-        assert compile_cache.enabled()
-    finally:
-        compile_cache.disable()
 
 
-def test_env_enable_revoked_when_gang_turns_multiprocess(tmp_path,
-                                                         monkeypatch):
+def test_enable_revoked_when_gang_turns_multiprocess(cache_placed,
+                                                     monkeypatch):
     """The first net/executor can be built BEFORE jax.distributed
-    initializes — the safety probe still answers 'safe' and the env var
-    enables the cache. The next entry point after distributed init must
-    REVOKE it: a respawned gang restoring XLA:CPU collective executables
-    from that early enable segfaults (-11/-6 at the first restored step)."""
+    initializes — the safety probe still answers 'safe' and the cache comes
+    on. The next entry point after distributed init must REVOKE it: a
+    respawned gang restoring XLA:CPU collective executables from that early
+    enable segfaults (-11/-6 at the first restored step)."""
+    import jax
     from jax._src import distributed
 
-    monkeypatch.setenv(compile_cache.ENV_DIR, str(tmp_path / "cc"))
     monkeypatch.setattr(distributed.global_state, "client", None,
                         raising=False)
-    try:
-        assert compile_cache.maybe_enable_from_env() is not None  # pre-init
-        assert compile_cache.enabled()
-        monkeypatch.setattr(distributed.global_state, "client", object(),
-                            raising=False)
-        assert compile_cache.maybe_enable_from_env() is None
-        assert not compile_cache.enabled()
-    finally:
-        compile_cache.disable()
-
-
-def test_explicit_enable_wins_over_env(tmp_path, monkeypatch):
-    """An entry point's maybe_enable_from_env must NOT re-point a cache the
-    serving builder (or operator) explicitly enabled — executables would be
-    silently stranded in a directory a restarted replica never reads."""
-    explicit = str(tmp_path / "explicit")
-    monkeypatch.setenv(compile_cache.ENV_DIR, str(tmp_path / "env"))
-    try:
-        compile_cache.enable(explicit)
-        assert compile_cache.maybe_enable_from_env() == \
-            os.path.abspath(explicit)
-        assert compile_cache.cache_dir() == os.path.abspath(explicit)
-    finally:
-        compile_cache.disable()
-
-
-def test_server_builder_enables_explicit_cache_dir(tmp_path):
-    from deeplearning4j_tpu.serving import JsonModelServer
-
-    d = str(tmp_path / "serving_cache")
-    try:
-        server = (JsonModelServer.Builder(_tiny_net())
-                  .compile_cache_dir(d).build())
-        assert compile_cache.cache_dir() == os.path.abspath(d)
-        assert os.path.isdir(d)
-        assert server.warmup_all_buckets is None  # auto: cache on → ladder
-    finally:
-        compile_cache.disable()
+    assert compile_cache.enable() == cache_placed  # pre-init
+    assert compile_cache.enabled()
+    monkeypatch.setattr(distributed.global_state, "client", object(),
+                        raising=False)
+    assert compile_cache.enable() is None
+    assert not compile_cache.enabled()
+    assert jax.config.jax_enable_compilation_cache is False
+    assert jax.config.jax_compilation_cache_dir == cache_placed
 
 
 # ------------------------------------------- warmup completeness satellite

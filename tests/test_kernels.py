@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from deeplearning4j_tpu.common import jax_compat
 from deeplearning4j_tpu.kernels import flash_attention, mha_reference, ring_attention
 
 
@@ -38,7 +37,7 @@ def test_ring_attention_matches_reference(causal):
     q, k, v = _qkv()
     ref = mha_reference(q, k, v, causal=causal)
     mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))
-    f = jax_compat.shard_map(
+    f = jax.shard_map(
         lambda a, b, c: ring_attention(a, b, c, axis_name="sp", causal=causal),
         mesh=mesh,
         in_specs=(P(None, None, "sp", None),) * 3,
@@ -223,7 +222,7 @@ def test_ulysses_attention_matches_reference(causal):
     q, k, v = _qkv((2, 4, 256, 32))
     ref = mha_reference(q, k, v, causal=causal)
     mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))
-    f = jax_compat.shard_map(
+    f = jax.shard_map(
         lambda a, b, c: ulysses_attention(a, b, c, axis_name="sp", causal=causal),
         mesh=mesh,
         in_specs=(P(None, None, "sp", None),) * 3,
@@ -241,7 +240,7 @@ def test_ulysses_attention_respects_key_mask():
     mask = jnp.asarray((rs.rand(2, 64) > 0.3).astype(np.float32))
     ref = mha_reference(q, k, v, mask)
     mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))
-    f = jax_compat.shard_map(
+    f = jax.shard_map(
         lambda a, b, c, m: ulysses_attention(a, b, c, axis_name="sp", key_mask=m),
         mesh=mesh,
         in_specs=(P(None, None, "sp", None),) * 3 + (P(None, "sp"),),
@@ -256,7 +255,7 @@ def test_ulysses_heads_divisibility_error():
 
     q, k, v = _qkv((1, 3, 64, 16))  # 3 heads, 4 devices
     mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))
-    f = jax_compat.shard_map(
+    f = jax.shard_map(
         lambda a, b, c: ulysses_attention(a, b, c, axis_name="sp"),
         mesh=mesh, in_specs=(P(None, None, "sp", None),) * 3,
         out_specs=P(None, None, "sp", None),
@@ -273,3 +272,42 @@ def test_flash_long_t_auto_blocks_match_reference():
     out = flash_attention(q, k, v, mask, interpret=True)
     ref = mha_reference(q, k, v, mask)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+def test_flash_front_door_runs_per_shard_under_an_ambient_mesh():
+    """A Mosaic call cannot be partitioned by GSPMD (lowering for TPU raises
+    "Mosaic kernels cannot be automatically partitioned"), so under
+    ``jax.sharding.set_mesh`` the front door runs the kernel in a shard_map
+    over the mesh's batch and head axes. Pinned here: the jaxpr contains the
+    shard_map, values and gradients match the unsharded dense reference, and
+    a mesh whose axes divide neither dim still answers (replicated)."""
+    from jax.sharding import NamedSharding
+
+    from deeplearning4j_tpu.kernels import dot_product_attention
+
+    q, k, v = _qkv((4, 4, 128, 32))
+    rs = np.random.RandomState(3)
+    mask = jnp.asarray((rs.rand(4, 128) > 0.2).astype(np.float32))
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(jnp.sin(attn(q, k, v)))
+
+    ref = jax.value_and_grad(loss(lambda q, k, v: mha_reference(q, k, v, mask)),
+                             argnums=(0, 1, 2))(q, k, v)
+    flash = loss(lambda q, k, v: dot_product_attention(q, k, v, mask,
+                                                       impl="flash"))
+    for shape in ((2, 1, 2), (1, 3, 1)):  # 3 divides neither B=4 nor H=4
+        n = int(np.prod(shape))
+        mesh = Mesh(np.array(jax.devices()[:n]).reshape(shape),
+                    ("data", "fsdp", "tp"))
+        qs, ks, vs = (jax.device_put(t, NamedSharding(mesh, P("data", "tp")))
+                      for t in (q, k, v))
+        with jax.sharding.set_mesh(mesh):
+            assert "shard_map" in str(jax.make_jaxpr(flash)(qs, ks, vs))
+            got = jax.jit(jax.value_and_grad(flash, argnums=(0, 1, 2)))(
+                qs, ks, vs)
+        np.testing.assert_allclose(float(got[0]), float(ref[0]), rtol=1e-5)
+        for g, r in zip(got[1], ref[1]):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-5)
+    # no ambient mesh: a plain call, no shard_map
+    assert "shard_map" not in str(jax.make_jaxpr(flash)(q, k, v))

@@ -8,7 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu.common import jax_compat
 from deeplearning4j_tpu.data.dataset import DataSet
 from deeplearning4j_tpu.models import (
     LeNet,
@@ -85,7 +84,7 @@ def test_transformer_dp_tp_train_step():
     batch = {k: jax.device_put(v, NamedSharding(mesh, P("dp", None)))
              for k, v in batch.items()}
     step = jax.jit(make_train_step(cfg, upd), donate_argnums=(0, 1))
-    with jax_compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         params, opt, loss = step(params, opt, batch, jnp.asarray(0, jnp.int32),
                                  jax.random.key(1))
     assert np.isfinite(float(loss))
@@ -108,7 +107,7 @@ def test_transformer_ring_loss_matches_xla():
     params_s = jax.device_put(params, pshard)
     batch_s = {k: jax.device_put(v, NamedSharding(mesh, P("dp", "sp")))
                for k, v in batch.items()}
-    with jax_compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         l_ring = float(jax.jit(lambda p, b: transformer_loss(p, b, cfg_r, None, False))(
             params_s, batch_s))
     assert abs(l_ref - l_ring) < 1e-3
