@@ -1,0 +1,7 @@
+"""Share of chip 0's busy time spent in Mosaic (flash prefill) calls."""
+
+from benchmark import reduce
+
+
+def read(obs):
+    return reduce.mosaic_time_share(obs)
