@@ -171,6 +171,7 @@ def _flash_forward(q, k, v, qseg, kseg, causal, scale, block_q, block_k, interpr
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",  # what a device trace calls the kernel
     )(*args)
     return out.reshape(B, H, Tq, D), lse.reshape(B, H, Tq, 1)
 
@@ -345,6 +346,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, q_offset, res, do):
             pltpu.VMEM((bk, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*args)
 
     dq_in_specs = [
@@ -370,6 +372,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, q_offset, res, do):
         out_shape=[jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype)],
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*args)
 
     return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..monitoring.trace import span
 from .transformer import (
     TransformerConfig,
     KvCacheLostError,
@@ -295,6 +296,9 @@ class PagedDecodeSlotPool:
         self._nblocks = np.zeros(slots, np.int32)   # logical blocks owned
         self._cow_reserve = np.zeros(slots, np.int32)
         self._joined: Dict[int, Dict[int, int]] = {}  # slot -> {logical: phys}
+        #: seconds the last ``step()`` blocked reading its result back (the
+        #: ``kv.step.fetch`` span): the step's time less this is the host's
+        self.last_fetch_s = 0.0
         # cumulative speculative counters (0 forever on a plain pool)
         self.spec_proposed = 0
         self.spec_accepted = 0
@@ -515,7 +519,7 @@ class PagedDecodeSlotPool:
         ``KvCacheLostError`` (donated prefill failed; pool already reset)."""
         toks = np.asarray(prompt, np.int32).reshape(-1)
         n = toks.shape[0]
-        span, nblocks, shared_full, tail, new_needed, reserve = \
+        n_span, nblocks, shared_full, tail, new_needed, reserve = \
             self._plan(toks, max_new_tokens)
         free = np.flatnonzero(~self._active)
         if free.size == 0:
@@ -561,14 +565,19 @@ class PagedDecodeSlotPool:
             if j < nblocks and row[j] not in shared_set:
                 dest[j] = row[j]
         try:
-            if self.draft_cfg is not None:
-                self._kc, self._vc, self._dkc, self._dvc, first = \
-                    self._prefill_fn(self.params, self.draft_params,
-                                     self._kc, self._vc, self._dkc, self._dvc,
-                                     dest, padded, np.int32(n))
-            else:
-                self._kc, self._vc, first = self._prefill_fn(
-                    self.params, self._kc, self._vc, dest, padded, np.int32(n))
+            with span("kv.prefill", bucket=bucket,
+                      shared_blocks=len(shared_set), new_blocks=len(new_blocks)):
+                if self.draft_cfg is not None:
+                    self._kc, self._vc, self._dkc, self._dvc, first = \
+                        self._prefill_fn(self.params, self.draft_params,
+                                         self._kc, self._vc, self._dkc,
+                                         self._dvc, dest, padded, np.int32(n))
+                else:
+                    self._kc, self._vc, first = self._prefill_fn(
+                        self.params, self._kc, self._vc, dest, padded,
+                        np.int32(n))
+                with span("kv.prefill.fetch"):
+                    first = int(first)  # the host waits for the prefill here
         except Exception as e:
             self._reset_after_failure()
             raise KvCacheLostError(
@@ -587,13 +596,13 @@ class PagedDecodeSlotPool:
         self._tables[slot] = row
         self._active[slot] = True
         self._positions[slot] = n
-        self._tokens[slot] = int(first)
+        self._tokens[slot] = first
         self._budget[slot] = max_new_tokens
         self._emitted[slot] = 1
-        self._span[slot] = span
+        self._span[slot] = n_span
         self._nblocks[slot] = nblocks
         self._joined[slot] = joined
-        return slot, int(first)
+        return slot, first
 
     def _cow_before_write(self, slot: int, p_lo: int, p_hi: int) -> None:
         """Copy any JOINED shared block this step will write into (positions
@@ -649,27 +658,37 @@ class PagedDecodeSlotPool:
             s = int(s)
             self._cow_before_write(s, int(self._positions[s]),
                                    int(self._positions[s]) + window - 1)
-        tables = jnp.asarray(self._tables)
-        toks = jnp.asarray(self._tokens)
-        pos = jnp.asarray(self._positions)
+        with span("kv.step.upload"):
+            tables = jnp.asarray(self._tables)
+            toks = jnp.asarray(self._tokens)
+            pos = jnp.asarray(self._positions)
         out: Dict[int, List[int]] = {}
         try:
-            if self.draft_cfg is not None:
-                (self._kc, self._vc, self._dkc, self._dvc, ver, n_acc) = \
-                    self._decode_fn(self.params, self.draft_params,
-                                    self._kc, self._vc, self._dkc, self._dvc,
-                                    tables, toks, pos)
-            else:
-                self._kc, self._vc, nxt = self._decode_fn(
-                    self.params, self._kc, self._vc, tables, toks, pos)
+            with span("kv.step.dispatch"):
+                if self.draft_cfg is not None:
+                    (self._kc, self._vc, self._dkc, self._dvc, ver, n_acc) = \
+                        self._decode_fn(self.params, self.draft_params,
+                                        self._kc, self._vc, self._dkc,
+                                        self._dvc, tables, toks, pos)
+                else:
+                    self._kc, self._vc, nxt = self._decode_fn(
+                        self.params, self._kc, self._vc, tables, toks, pos)
+            # the one host round trip a step (S4): the device runs the step
+            # while the host waits here
+            with span("kv.step.fetch") as fetch:
+                if self.draft_cfg is None:
+                    nxt = np.asarray(nxt)
+                else:
+                    ver = np.asarray(ver)
+                    n_acc = np.asarray(n_acc)
         except Exception as e:
             self._reset_after_failure()
             raise KvCacheLostError(
                 f"decode step failed after its KV buffers were donated "
                 f"({type(e).__name__}: {e}); cache reset, in-flight "
                 f"sequences lost") from e
+        self.last_fetch_s = fetch.duration_s
         if self.draft_cfg is None:
-            nxt = np.asarray(nxt)
             for slot in live:
                 slot = int(slot)
                 out[slot] = [int(nxt[slot])]
@@ -677,8 +696,6 @@ class PagedDecodeSlotPool:
                 self._tokens[slot] = nxt[slot]
                 self._emitted[slot] += 1
             return out
-        ver = np.asarray(ver)
-        n_acc = np.asarray(n_acc)
         for slot in live:
             slot = int(slot)
             na = int(n_acc[slot])

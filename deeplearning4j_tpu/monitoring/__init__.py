@@ -45,7 +45,7 @@ from .partition import partition_metrics
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
                        get_registry)
 from .serving import serving_metrics
-from .trace import (Span, StepPhaseRecorder, current_span_path,
+from .trace import (SERVING_SPANS, Span, StepPhaseRecorder, current_span_path,
                     set_trace_profiler, span, step_phase_histogram, step_span)
 from .watchdogs import (DeviceMemoryWatchdog, RecompileWatchdog, active,
                         host_rss_bytes, note_signature, note_step,
@@ -83,6 +83,7 @@ __all__ = [
     "HeartbeatWriter",
     "maybe_beat",
     "read_heartbeat",
+    "SERVING_SPANS",
     "Span",
     "StepPhaseRecorder",
     "step_phase_histogram",

@@ -6,7 +6,9 @@ were previously uncorrelated. A :func:`span` does three things at once:
 
 - wraps ``jax.profiler.TraceAnnotation`` (or ``StepTraceAnnotation`` when a
   ``step_num`` is given) so the span shows up on the device trace whenever an
-  XProf capture is active — host spans and HLO timelines line up by name;
+  XProf capture is active — host spans and HLO timelines line up by name, on
+  the profiler's own clock, and the span's keywords (``request_id``,
+  ``step``, ``live``, ``bucket``) ride the trace event as its stats;
 - records a chrome-trace complete event into an :class:`~..ops.profiler.
   OpProfiler` (the one attached via :func:`set_trace_profiler`, or an
   explicit ``profiler=``), so ONE ``to_chrome_trace`` file carries both op
@@ -14,7 +16,9 @@ were previously uncorrelated. A :func:`span` does three things at once:
 - optionally observes the span duration into a registry histogram.
 
 Spans nest: names are qualified with the enclosing span path
-(``fit/step/h2d``), per thread.
+(``fit/step/h2d``), per thread. It is the ONE way to open a span on a hot
+path; the names used on the served path are declared in
+:data:`SERVING_SPANS`.
 """
 
 from __future__ import annotations
@@ -52,36 +56,77 @@ def current_span_path() -> str:
     return "/".join(_stack())
 
 
+#: THE span vocabulary of the served path (door -> sched -> kv), as
+#: ``flight.EVENT_KINDS`` is for flight events. Every ``span("...")`` literal
+#: under ``serving/`` and ``models/paged_decode.py`` must be declared here
+#: (tests/test_serving_spans.py AST lint) and tabled in
+#: docs/OBSERVABILITY.md ("Served-path spans"): a reader of a device trace
+#: finds the host's side of a gap by these names.
+SERVING_SPANS = (
+    # one handler thread per request (serving/json_server.py)
+    "door.request", "door.read", "door.parse", "door.wait",
+    "door.serialize", "door.write",
+    # the executor's loop thread (serving/executor.py)
+    "sched.idle", "sched.admit", "sched.decode_step", "sched.retire",
+    # the slot pool, on the loop thread (models/paged_decode.py)
+    "kv.prefill", "kv.prefill.fetch",
+    "kv.step.upload", "kv.step.dispatch", "kv.step.fetch",
+)
+
+_annotation_types = None  # (TraceAnnotation, StepTraceAnnotation), on first use
+
+
+def _annotations():
+    # jax stays out of this module's import (supervisors and stub replicas
+    # import monitoring and must not pay for jax) and out of __enter__
+    global _annotation_types
+    if _annotation_types is None:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+        _annotation_types = (TraceAnnotation, StepTraceAnnotation)
+    return _annotation_types
+
+
 class Span:
+    """One host span: ``name``, ``start_ns`` / ``duration_s`` (perf_counter),
+    its parent (``qualified_name``, nesting per thread) and ``stats``, the
+    request or step it belongs to. The profiler's trace event carries the
+    stats too, so a device trace is joined to a request by number."""
+
+    __slots__ = ("name", "stats", "_profiler", "_histogram", "_step_num",
+                 "_annotation", "qualified_name", "start_ns", "duration_s")
+
     def __init__(self, name: str, profiler=None, histogram=None,
-                 step_num: Optional[int] = None):
+                 step_num: Optional[int] = None, stats: Optional[dict] = None):
         self.name = name
+        self.stats = stats or {}
         self._profiler = profiler
         self._histogram = histogram
         self._step_num = step_num
         self._annotation = None
         self.qualified_name: Optional[str] = None
+        self.start_ns: Optional[int] = None
         self.duration_s: Optional[float] = None
 
     def __enter__(self):
-        import jax
-
+        plain, stepped = _annotations()
         stack = _stack()
         stack.append(self.name)
         self.qualified_name = "/".join(stack)
         # StepTraceAnnotation marks step boundaries for XProf's step-time
-        # analysis; TraceAnnotation is a plain named region
+        # analysis; TraceAnnotation is a plain named region. Outside a
+        # profiler session either costs one flag check
         if self._step_num is not None:
-            self._annotation = jax.profiler.StepTraceAnnotation(
-                self.name, step_num=self._step_num)
+            self._annotation = stepped(self.name, step_num=self._step_num,
+                                       **self.stats)
         else:
-            self._annotation = jax.profiler.TraceAnnotation(self.name)
+            self._annotation = plain(self.name, **self.stats)
         self._annotation.__enter__()
-        self._t0 = time.perf_counter_ns()
+        self.start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        dur_ns = time.perf_counter_ns() - self._t0
+        dur_ns = time.perf_counter_ns() - self.start_ns
         self._annotation.__exit__(*exc)
         _stack().pop()
         self.duration_s = dur_ns / 1e9
@@ -93,9 +138,10 @@ class Span:
         return False
 
 
-def span(name: str, profiler=None, histogram=None) -> Span:
-    """Open a nestable host span: ``with span("h2d"): ...``"""
-    return Span(name, profiler=profiler, histogram=histogram)
+def span(name: str, profiler=None, histogram=None, **stats) -> Span:
+    """Open a nestable host span: ``with span("h2d"): ...``. Keywords become
+    the trace event's stats: ``span("sched.decode_step", step=7, live=3)``."""
+    return Span(name, profiler=profiler, histogram=histogram, stats=stats)
 
 
 def step_span(step_num: int, name: str = "train",

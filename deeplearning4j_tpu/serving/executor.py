@@ -29,7 +29,9 @@ What production hardening adds on top of the DL4J shape:
   ``tdl_inference_*`` families (``monitoring.serving``); SAMPLED requests
   (deterministic by request-id hash, ``span_sample_n``) leave
   ``request_span`` flight events carrying the per-phase
-  queue→batch-form→infer timeline keyed by request id (ISSUE 11) — shed
+  queue→batch-form→infer timeline keyed by request id (ISSUE 11; the door
+  adds its own phases and records the event once the response is written,
+  so the phases tile the request's whole life, ISSUE 28) — shed
   requests (queue-full, expired-in-queue, abandoned-mid-batch) leave one
   under the same sampling decision, so a sampled 429/504's life is as
   reconstructable as a sampled 200's.
@@ -48,6 +50,7 @@ import numpy as np
 from ..common.faults import fault_point
 from ..monitoring import aggregate, flight
 from ..monitoring.serving import serving_metrics
+from ..monitoring.trace import span
 
 log = logging.getLogger(__name__)
 
@@ -55,7 +58,8 @@ log = logging.getLogger(__name__)
 #: recorder (the executor's abandoned paths, the HTTP layer's
 #: ``_record_span``) must split on this ONE set — a new extra added to
 #: only one site would land in ``phases={}`` as fake per-phase seconds.
-SPAN_EXTRA_KEYS = ("batch_rows", "steps", "step_ms", "step_tokens")
+SPAN_EXTRA_KEYS = ("batch_rows", "steps", "step_ms", "step_tokens",
+                   "step_host_ms", "first_step", "last_step")
 
 
 def span_sampled(request_id: Optional[str], sample_n: int) -> bool:
@@ -505,7 +509,7 @@ class GenerationFuture(InferenceFuture):
     ``result`` the generated token ids (np.int32, EOS inclusive). The
     executor appends into ``tokens`` as decode steps land."""
 
-    __slots__ = ("max_new_tokens", "tokens", "steps")
+    __slots__ = ("max_new_tokens", "tokens", "steps", "slot_mark")
 
     def __init__(self, x: np.ndarray, deadline: Optional[float],
                  max_new_tokens: int, request_id: Optional[str] = None,
@@ -515,6 +519,10 @@ class GenerationFuture(InferenceFuture):
         self.max_new_tokens = max_new_tokens
         self.tokens: List[int] = []
         self.steps = 0
+        #: a sampled request's slot account, opened when its prefill ends:
+        #: (instant, the executor's prefill-seconds and step-seconds totals,
+        #: the number of its first decode step) — closed by ``_close_account``
+        self.slot_mark: Optional[Tuple[float, float, float, int]] = None
 
 
 #: per-request decode-step timeline entries kept on a sampled span — enough
@@ -574,6 +582,12 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
         # python-side aggregates for stats()/bench (registry counters are
         # process-global; these are THIS executor's)
         self._steps = 0
+        # seconds this loop thread has spent in session.admit / session.step:
+        # sampled when a request takes its slot and when it leaves, the two
+        # differences are its `interleave` (others' prefills while it stood
+        # still) and its `decode` (every step of its slot life is its own)
+        self._prefill_s = 0.0
+        self._step_s = 0.0
         self._occupancy_sum = 0
         self._tokens_out = 0
         self._admitted = 0
@@ -705,7 +719,8 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
         while True:
             with self._cv:
                 while not self._q and not active and not self._stopping:
-                    self._cv.wait()
+                    with span("sched.idle"):
+                        self._cv.wait()
                 stopping, drain = self._stopping, self._drain_on_stop
                 if stopping and not drain:
                     # queued requests were already cancelled by stop();
@@ -749,11 +764,16 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
                     # cannot hold — but a duck-typed session could get here)
                     self._cv.wait(0.01)
             for fut in candidates:
-                self._admit_into_slot(fut, active)
+                with span("sched.admit", request_id=fut.request_id,
+                          prompt_len=int(fut.x.shape[0])):
+                    self._admit_into_slot(fut, active)
             if not active:
                 continue
-            self._decode_step(active)
-            aggregate.maybe_spool()  # replica's aggregated-/metrics spool
+            # `step` is the number first_step/last_step of a request_span
+            # name: a request joins its steps in a device trace by number
+            with span("sched.decode_step", step=self._steps + 1,
+                      live=len(active)):
+                self._decode_step(active)
 
     def _admit_into_slot(self, fut: GenerationFuture,
                          active: Dict[int, GenerationFuture]) -> None:
@@ -802,12 +822,12 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
                 for rider in active.values():
                     self._md.evicted.labels(reason="cache_lost").inc()
                     self._evicted += 1
-                    rider._resolve(error=e)
-                    self._record_abandoned_span(rider)
+                    self._finish(rider, error=e)
                 active.clear()
                 self._md.slot_occupancy.set(0)
             return
         prefill_s = time.monotonic() - now
+        self._prefill_s += prefill_s  # before the mark: its own prefill is not its interleave
         self._md.admitted.inc()
         self._admitted += 1
         fut.tokens.append(int(first))
@@ -816,7 +836,10 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
         if fut.sampled:
             fut.span = {"queue": now - fut.enqueued_at,
                         "prefill": prefill_s, "decode": 0.0,
+                        "interleave": 0.0, "loop": 0.0,
                         "steps": 0, "step_ms": [], "step_tokens": []}
+            fut.slot_mark = (now + prefill_s, self._prefill_s, self._step_s,
+                             self._steps + 1)
         if (fut.max_new_tokens == 1
                 or (self.eos_id is not None and first == self.eos_id)):
             self.session.release(slot)  # done at prefill: slot never held
@@ -843,15 +866,27 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
                     log.debug("slot %d release failed after step error", slot)
                 self._md.evicted.labels(reason=reason).inc()
                 self._evicted += 1
-                fut._resolve(error=e)
-                self._record_abandoned_span(fut)
+                self._finish(fut, error=e)
             active.clear()
             self._md.slot_occupancy.set(0)
             return
         dt = time.monotonic() - t0
-        self._md.steps.inc()
-        self._md.slot_occupancy.set(len(active))
+        self._step_s += dt
         self._steps += 1
+        with span("sched.retire"):
+            # the paged pool says how long it blocked on the step's result;
+            # the rest of the step was the host's (S4's cost a step)
+            fetch_s = getattr(self.session, "last_fetch_s", None)
+            self._retire(active, out, dt,
+                         None if fetch_s is None else dt - fetch_s)
+            self._sync_session_metrics()
+            aggregate.maybe_spool()  # replica's aggregated-/metrics spool
+
+    def _retire(self, active: Dict[int, GenerationFuture], out: dict,
+                dt: float, host_s: Optional[float]) -> None:
+        """Hand one step's tokens to their requests; finish or evict the
+        ones that are done."""
+        self._md.steps.inc()
         self._occupancy_sum += len(active)
         now = time.monotonic()
         emitted_total = 0
@@ -875,13 +910,13 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
                     hit_eos = True
                     break
             emitted_total += chunk
-            if fut.sampled and fut.span is not None:
-                fut.span["decode"] += dt
-                fut.span["steps"] = fut.steps
-                if len(fut.span["step_ms"]) < _SPAN_STEP_CAP:
-                    fut.span["step_ms"].append(round(dt * 1e3, 3))
-                if len(fut.span["step_tokens"]) < _SPAN_STEP_CAP:
-                    fut.span["step_tokens"].append(chunk)
+            if fut.sampled and fut.span is not None \
+                    and len(fut.span["step_ms"]) < _SPAN_STEP_CAP:
+                fut.span["step_ms"].append(round(dt * 1e3, 3))
+                fut.span["step_tokens"].append(chunk)
+                if host_s is not None:
+                    fut.span.setdefault("step_host_ms", []).append(
+                        round(host_s * 1e3, 3))
             done = (hit_eos or len(fut.tokens) >= fut.max_new_tokens)
             if done:
                 self.session.release(slot)
@@ -895,6 +930,7 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
                 del active[slot]
                 self._md.evicted.labels(reason="deadline").inc()
                 self._evicted += 1
+                self._close_account(fut, now)
                 owns = fut._expire(DeadlineExceededError(
                     f"deadline expired mid-decode after {fut.steps} steps "
                     f"({len(fut.tokens)}/{fut.max_new_tokens} tokens)"))
@@ -911,10 +947,33 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
         self._md.tokens.inc(emitted_total)
         self._tokens_out += emitted_total
         self._md.slot_occupancy.set(len(active))
-        self._sync_session_metrics()
 
-    def _finish(self, fut: GenerationFuture) -> None:
-        fut._resolve(result=np.asarray(fut.tokens, np.int32))
+    def _close_account(self, fut: GenerationFuture, now: float) -> None:
+        """Split a sampled request's slot life, prefill's end to ``now``,
+        into ``decode`` (the steps run meanwhile: each was its own),
+        ``interleave`` (the prefills run meanwhile: every one was another
+        request's, and this one stood still) and ``loop`` (the rest: retire,
+        metrics, lock waits). Two differences of running totals — nothing
+        was kept per step."""
+        if fut.span is None or fut.slot_mark is None:
+            return
+        t_slot, prefill0, step0, first_step = fut.slot_mark
+        fut.slot_mark = None
+        decode = self._step_s - step0
+        interleave = self._prefill_s - prefill0
+        fut.span.update(decode=decode, interleave=interleave,
+                        loop=max(0.0, now - t_slot - decode - interleave),
+                        steps=fut.steps)
+        if fut.steps:
+            fut.span.update(first_step=first_step, last_step=self._steps)
+
+    def _finish(self, fut: GenerationFuture,
+                error: Optional[BaseException] = None) -> None:
+        self._close_account(fut, time.monotonic())
+        if error is None:
+            fut._resolve(result=np.asarray(fut.tokens, np.int32))
+        else:
+            fut._resolve(error=error)
         self._record_abandoned_span(fut)
 
     @staticmethod

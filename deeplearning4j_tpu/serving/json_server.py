@@ -42,9 +42,10 @@ import numpy as np
 
 from ..monitoring import flight
 from ..monitoring.serving import client_metrics, serving_metrics
+from ..monitoring.trace import span
 from .executor import (SPAN_EXTRA_KEYS, BatchingInferenceExecutor,
                        DeadlineExceededError, ExecutorClosedError,
-                       QueueFullError)
+                       InferenceFuture, QueueFullError)
 
 log = logging.getLogger(__name__)
 
@@ -83,6 +84,23 @@ def _trace_id(header_value: Optional[str], rid: str) -> str:
     if tr and len(tr) <= _REQUEST_ID_MAX and tr.isprintable():
         return tr
     return rid
+
+
+class _DoorAccount:
+    """The instants of one POST on flight's clock (``time.monotonic``), set
+    as its handler thread passes them; ``JsonModelServer._record_span`` turns
+    them into the phases of the request's span."""
+
+    __slots__ = ("t_start", "t_read", "t_awake", "t_serialized", "fut",
+                 "outcome")
+
+    def __init__(self):
+        self.t_start = time.monotonic()
+        self.t_read = self.t_awake = self.t_serialized = None
+        self.fut: Optional[InferenceFuture] = None
+        #: (outcome, code) of a request the door itself records; None for
+        #: the refusals and shed paths, recorded where they happen
+        self.outcome: Optional[Tuple[str, int]] = None
 
 
 class JsonModelServer:
@@ -275,14 +293,11 @@ class JsonModelServer:
         except OSError:
             log.debug("client stalled while its oversized body was drained")
 
-    def _handle_predict(self, handler, rid: Optional[str] = None,
-                        trace_id: Optional[str] = None,
-                        ) -> Tuple[int, dict, Optional[int]]:
-        """Returns (status, json body, Retry-After seconds or None)."""
-        rid = rid if rid is not None else _request_id(
-            handler.headers.get("X-Request-Id"))
-        trace_id = trace_id if trace_id is not None else _trace_id(
-            handler.headers.get("X-Trace-Id"), rid)
+    def _handle_predict(self, handler, rid: str, trace_id: str,
+                        acct: _DoorAccount):
+        """Returns (status, json body, Retry-After seconds or None). ``acct``
+        takes the instants the account of the request is made from
+        (``_record_span``)."""
         content_length = handler.headers.get("Content-Length")
         try:
             length = int(content_length)
@@ -304,12 +319,61 @@ class JsonModelServer:
             return 413, {"error": f"request body {length}B exceeds "
                                   f"{self.max_body_bytes}B limit"}, None
         try:
-            body = handler.rfile.read(length)
+            with span("door.read"):
+                body = handler.rfile.read(length)
         except OSError:
             # socket read timed out (slowloris: Content-Length promised more
             # bytes than the client ever sends) — the handler thread must not
             # wedge holding an _inflight slot
             return 408, {"error": "timed out reading request body"}, None
+        acct.t_read = time.monotonic()
+        with span("door.parse"):
+            parsed = self._parse_and_submit(handler, body, executor, rid,
+                                            trace_id)
+        if not isinstance(parsed, InferenceFuture):
+            return parsed
+        fut = acct.fut = parsed
+        remaining = (None if fut.deadline is None
+                     else fut.deadline - time.monotonic())
+        with span("door.wait"):
+            resolved = fut.wait(remaining)
+        acct.t_awake = time.monotonic()
+        if not resolved and fut.abandon():
+            # the executor is still busy; the client's budget is spent —
+            # answer 504 now rather than hang the connection. abandon()
+            # claims the shed accounting so the executor won't also count
+            # this request when it later pops it expired
+            self._m.shed.labels(reason="deadline").inc()
+            log.warning("request %s: deadline exceeded while inference "
+                        "still pending", rid)
+            return 504, {"error": "deadline exceeded before inference "
+                                  "completed"}, None
+        if fut.error is not None:
+            e = fut.error
+            if isinstance(e, DeadlineExceededError):
+                # the executor recorded the shed_deadline span when it
+                # popped the expired request — don't double-record
+                return 504, {"error": str(e)}, None
+            if isinstance(e, ExecutorClosedError):
+                return 503, {"error": str(e)}, RETRY_AFTER_S
+            acct.outcome = ("error", 500)
+            return 500, {"error": f"{type(e).__name__}: {e}"}, None
+        try:
+            with span("door.serialize"):
+                body = {"output": self.serializer(fut.result)}
+        except Exception as e:
+            acct.outcome = ("error", 500)
+            return 500, {"error": f"serializer failed: "
+                                  f"{type(e).__name__}: {e}"}, None
+        finally:
+            acct.t_serialized = time.monotonic()
+        acct.outcome = ("ok", 200)
+        return 200, body, None
+
+    def _parse_and_submit(self, handler, body: bytes, executor, rid: str,
+                          trace_id: str):
+        """Headers and payload to an admitted future, or the (status,
+        object, Retry-After) of the refusal."""
         deadline_ms: Optional[float] = None
         header = handler.headers.get("X-Deadline-Ms")
         if header is not None:
@@ -346,61 +410,44 @@ class JsonModelServer:
             return 503, {"error": str(e)}, RETRY_AFTER_S
         except (ValueError, TypeError) as e:
             return 400, {"error": f"{type(e).__name__}: {e}"}, None
-        remaining = (None if fut.deadline is None
-                     else fut.deadline - time.monotonic())
-        if not fut.wait(remaining) and fut.abandon():
-            # the executor is still busy; the client's budget is spent —
-            # answer 504 now rather than hang the connection. abandon()
-            # claims the shed accounting so the executor won't also count
-            # this request when it later pops it expired
-            self._m.shed.labels(reason="deadline").inc()
-            log.warning("request %s: deadline exceeded while inference "
-                        "still pending", rid)
-            return 504, {"error": "deadline exceeded before inference "
-                                  "completed"}, None
-        if fut.error is not None:
-            e = fut.error
-            if isinstance(e, DeadlineExceededError):
-                # the executor recorded the shed_deadline span when it
-                # popped the expired request — don't double-record
-                return 504, {"error": str(e)}, None
-            if isinstance(e, ExecutorClosedError):
-                return 503, {"error": str(e)}, RETRY_AFTER_S
-            self._record_span(fut, rid, "error", 500)
-            return 500, {"error": f"{type(e).__name__}: {e}"}, None
-        t_ser = time.monotonic()
-        try:
-            body = {"output": self.serializer(fut.result)}
-        except Exception as e:
-            self._record_span(fut, rid, "error", 500,
-                              serialize=time.monotonic() - t_ser)
-            return 500, {"error": f"serializer failed: "
-                                  f"{type(e).__name__}: {e}"}, None
-        self._record_span(fut, rid, "ok", 200,
-                          serialize=time.monotonic() - t_ser)
-        return 200, body, None
+        return fut
 
     @staticmethod
-    def _record_span(fut, rid: str, outcome: str, code: int,
-                     serialize: Optional[float] = None) -> None:
-        """Complete a sampled request's ``request_span`` flight event
-        (ISSUE 11): the executor filled queue/batch_form/infer, the HTTP
-        layer owns serialize and the outcome. One event per request, keyed
-        by the same ``X-Request-Id`` that rides every response — a
-        timeline reconstructs with one grep."""
-        if not fut.sampled:
+    def _record_span(acct: _DoorAccount, rid: str) -> None:
+        """Close a sampled request's account as ONE ``request_span`` flight
+        event, after its response is written: phases that tile ``[t_start,
+        t_end]`` in order — ``read``, ``parse`` (to the future's
+        ``enqueued_at``), the executor's own (queue/batch_form/infer, or
+        queue/prefill/decode/interleave/loop), ``handoff`` (what is left of
+        the handler's wait: the executor's last instant to this thread
+        awake), ``serialize`` (the serializer alone, as before), ``write``
+        (JSON encoding and the socket). Keyed by the ``X-Request-Id`` that
+        rides every response — a timeline reconstructs with one grep. Shed
+        requests are recorded where they are shed."""
+        t_end = time.monotonic()
+        fut = acct.fut
+        if fut is None or acct.outcome is None or not fut.sampled:
             return
-        phases = dict(fut.span or {})
+        executor = dict(fut.span or {})
         # non-phase span payload: micro-batch rows, and (generative mode,
-        # ISSUE 13) the per-step decode timeline + step count
-        extra = {k: phases.pop(k) for k in SPAN_EXTRA_KEYS if k in phases}
-        if serialize is not None:
-            phases["serialize"] = serialize
+        # ISSUE 13) the per-step decode timeline + step numbers
+        extra = {k: executor.pop(k) for k in SPAN_EXTRA_KEYS if k in executor}
+        phases = {"read": acct.t_read - acct.t_start,
+                  "parse": fut.enqueued_at - acct.t_read, **executor}
+        phases["handoff"] = (acct.t_awake - fut.enqueued_at
+                             - sum(executor.values()))
+        t_written_from = acct.t_awake
+        if acct.t_serialized is not None:
+            phases["serialize"] = acct.t_serialized - acct.t_awake
+            t_written_from = acct.t_serialized
+        phases["write"] = t_end - t_written_from
         trace_id = getattr(fut, "trace_id", None)
         if trace_id is not None:
             extra["trace_id"] = trace_id
+        outcome, code = acct.outcome
         flight.record("request_span", request_id=rid, outcome=outcome,
-                      code=code, phases=phases, **extra)
+                      code=code, t_start=acct.t_start, t_end=t_end,
+                      phases=phases, **extra)
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -463,19 +510,22 @@ class JsonModelServer:
                 with server._inflight_cv:
                     server._inflight += 1
                 try:
-                    t0 = time.perf_counter()
+                    acct = _DoorAccount()
                     # the correlation id rides every response — header AND
                     # body (incl. 429/504/413 error JSON), so a client-
                     # reported slow request is greppable in server telemetry
                     rid = _request_id(self.headers.get("X-Request-Id"))
                     tid = _trace_id(self.headers.get("X-Trace-Id"), rid)
-                    code, obj, retry_after = server._handle_predict(
-                        self, rid, trace_id=tid)
-                    obj.setdefault("request_id", rid)
-                    self._json(obj, code, retry_after, request_id=rid,
-                               trace_id=tid)
+                    with span("door.request", request_id=rid):
+                        code, obj, retry_after = server._handle_predict(
+                            self, rid, tid, acct)
+                        obj.setdefault("request_id", rid)
+                        with span("door.write"):
+                            self._json(obj, code, retry_after, request_id=rid,
+                                       trace_id=tid)
+                    server._record_span(acct, rid)
                     server._m.requests.labels(code=str(code)).inc()
-                    server._m.latency.observe(time.perf_counter() - t0)
+                    server._m.latency.observe(time.monotonic() - acct.t_start)
                 finally:
                     with server._inflight_cv:
                         server._inflight -= 1

@@ -42,11 +42,36 @@ def _seeded_rng():
 _SHM_DIR = "/dev/shm"
 
 
+def _descends_from_me(pid: int) -> bool:
+    """Whether ``pid`` is this process or one it started (walks /proc). A
+    creator that is gone is nobody else's: count it here."""
+    me = os.getpid()
+    while pid > 0:
+        if pid == me:
+            return True
+        try:
+            with open(f"/proc/{pid}/stat") as f:
+                pid = int(f.read().rsplit(")", 1)[1].split()[1])  # ppid
+        except (OSError, ValueError, IndexError):
+            return True
+    return False
+
+
 def _tdl_shm_segments():
+    """This process's ``tdl_*`` segments. /dev/shm is shared by the xdist
+    workers, and a segment carries its creator's pid (``tdl_etl_<pid>_<id>``):
+    one that a LIVE process outside this worker's tree made is another
+    worker's test in flight, not this test's leak."""
     try:
-        return {n for n in os.listdir(_SHM_DIR) if n.startswith("tdl_")}
+        names = {n for n in os.listdir(_SHM_DIR) if n.startswith("tdl_")}
     except OSError:  # non-Linux: no visible shm namespace to audit
         return set()
+    mine = set()
+    for n in names:
+        pid = n.split("_")[2] if n.count("_") >= 3 else ""
+        if not pid.isdigit() or _descends_from_me(int(pid)):
+            mine.add(n)
+    return mine
 
 
 @pytest.fixture(autouse=True)

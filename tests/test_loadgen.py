@@ -216,8 +216,9 @@ def test_replay_acceptance_burst_fires_and_clears_windowed_alerts():
         assert ok_rows, "no sampled 200 with a span event"
         ok_span = spans[ok_rows[0]["request_id"]]
         assert ok_span["outcome"] == "ok"
-        assert set(ok_span["phases"]) == {"queue", "batch_form", "infer",
-                                          "serialize"}
+        assert set(ok_span["phases"]) == {"read", "parse", "queue",
+                                          "batch_form", "infer", "handoff",
+                                          "serialize", "write"}
         shed_rows = [r for r in report["requests"] if r["outcome"] == "504"
                      and r["request_id"] in spans]
         assert shed_rows, "no shed 504 with a span event"

@@ -637,13 +637,22 @@ def _clear_recorder():
 
 
 def _spans(rec, rid=None):
-    return [e for e in rec.events() if e["kind"] == "request_span"
-            and (rid is None or e.get("request_id") == rid)]
+    """The request_span events of ``rid``. A completion's span is recorded
+    AFTER its response is written, so a client that has just read the
+    response waits a moment for it."""
+    deadline = time.monotonic() + 5.0
+    while True:
+        found = [e for e in rec.events() if e["kind"] == "request_span"
+                 and (rid is None or e.get("request_id") == rid)]
+        if found or rid is None or time.monotonic() >= deadline:
+            return found
+        time.sleep(0.01)
 
 
 def test_request_span_for_200_carries_full_phase_timeline():
     """ISSUE 11: a sampled 200's life — queue → batch_form → infer →
-    serialize — reconstructs from ONE flight event joined by request id."""
+    serialize — reconstructs from ONE flight event joined by request id;
+    ISSUE 28: with the door's own phases around them, tiling the span."""
     rec = _install_recorder()
     server = JsonModelServer(SlowModel(), registry=MetricsRegistry()).start()
     try:
@@ -658,9 +667,11 @@ def test_request_span_for_200_carries_full_phase_timeline():
         assert len(spans) == 1
         ev = spans[0]
         assert ev["outcome"] == "ok" and ev["code"] == 200
-        assert set(ev["phases"]) == {"queue", "batch_form", "infer",
-                                     "serialize"}
+        assert list(ev["phases"]) == ["read", "parse", "queue", "batch_form",
+                                      "infer", "handoff", "serialize", "write"]
         assert all(v >= 0 for v in ev["phases"].values())
+        assert sum(ev["phases"].values()) == pytest.approx(
+            ev["t_end"] - ev["t_start"], abs=1e-6)
         assert ev["batch_rows"] >= 1
     finally:
         server.stop()

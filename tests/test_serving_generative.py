@@ -419,15 +419,17 @@ def test_generative_request_span_carries_decode_timeline():
         assert server.wait_ready(30.0)
         _post_tokens(server.port, [2],
                      headers={"X-Request-Id": "gen-span-1"})
-        spans = [e for e in rec.events() if e["kind"] == "request_span"
-                 and e.get("request_id") == "gen-span-1"]
-        assert len(spans) == 1
-        ev = spans[0]
-        assert ev["outcome"] == "ok" and ev["code"] == 200
-        assert set(ev["phases"]) == {"queue", "prefill", "decode",
-                                     "serialize"}
-        assert ev["steps"] == 3  # 4 tokens = 1 prefill + 3 decode steps
-        assert len(ev["step_ms"]) == 3
     finally:
-        server.stop()
+        server.stop()  # waits for the handler: it records AFTER the response
         flight.set_flight_recorder(None)
+    spans = [e for e in rec.events() if e["kind"] == "request_span"
+             and e.get("request_id") == "gen-span-1"]
+    assert len(spans) == 1
+    ev = spans[0]
+    assert ev["outcome"] == "ok" and ev["code"] == 200
+    assert list(ev["phases"]) == ["read", "parse", "queue", "prefill",
+                                  "decode", "interleave", "loop", "handoff",
+                                  "serialize", "write"]
+    assert ev["steps"] == 3  # 4 tokens = 1 prefill + 3 decode steps
+    assert len(ev["step_ms"]) == 3
+    assert ev["last_step"] - ev["first_step"] == 2
