@@ -1,0 +1,225 @@
+"""Paged decode attention: one Pallas kernel that reads K/V through the block
+tables, live blocks only.
+
+The paged pool (``models/paged_decode.py``) keeps K and V in arenas
+``[L, n_blocks, block_T, H*hd]`` — a block is one contiguous, lane-dense
+``[block_T, H*hd]`` tile holding every head — and gives each slot a row of
+``tables`` mapping logical block -> physical block. A decode step brings
+``W`` tokens a slot (1 plain, ``spec_tokens + 1`` in a verify pass): token
+``w`` of slot ``s`` sits at position ``limits[s, w] - 1`` and its query sees
+the slot's first ``limits[s, w]`` keys. The pool has written the window's own
+K/V into those cells before the call (write-before-read: a stale cell at an
+attended position never survives a step); the kernel only READS the arenas.
+A dead slot has limit 0: it costs no DMA and no compute, and its output is
+zeros.
+
+What the kernel moves is proportional to live tokens. The wrapper turns the
+limits into a flat work list of (slot, chunk) items, a chunk being the
+``chunk_T`` keys of consecutive logical blocks; the kernel walks the list in
+one loop, copies only the blocks a slot's longest query reaches from HBM
+(double-buffered, one item ahead, across slot boundaries) and accumulates an
+online softmax in float32. All heads of a slot go through the MXU at once:
+the query rows are laid out block-diagonally (row ``h`` keeps head ``h``'s
+lanes, zero elsewhere), so ``q2 @ K^T`` gives per-head scores without
+reshaping the ``H*hd`` lanes, and the per-head output is the matching
+diagonal block of ``p @ V``. Operands stay in the arena's dtype (bf16 on the
+chip) with float32 accumulation; scale, mask and softmax are float32.
+
+Off the TPU the same kernel runs in interpret mode (as
+``kernels/attention.py:flash_attention`` does), so the CPU tests exercise
+the path the chip runs.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_NEG_INF = -1e30   # matches kernels.attention masking
+_CHUNK_T = 128     # keys per work item: one lane tile of scores
+
+
+def _kernel(layer_ref, tables_ref, limits_ref, nblk_ref, wslot_ref, wchunk_ref,
+            nwork_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, q2_ref,
+            m_ref, l_ref, acc_ref, *, W, H, hd, Hp, bT, C, MB, scale):
+    """Walk the (slot, chunk) work list; see the module docstring.
+
+    q_ref/o_ref [S*W, D] float32 (rows of a slot are consecutive);
+    k_hbm/v_hbm [L, NB, bT, D], left in HBM; kbuf/vbuf [2, C*bT, D]; q2_ref
+    [W*Hp, D]; m/l [W*Hp, 1]; acc [W*Hp, D]."""
+    D = H * hd
+    T = C * bT
+    layer = layer_ref[0]
+    n_work = nwork_ref[0]
+
+    # dead slots are never visited: their rows must still be defined. Blocks
+    # of a chunk past a slot's live length are not copied either, so the
+    # buffers start finite (masked scores are exactly 0 weight, and 0 x NaN
+    # would not be)
+    o_ref[...] = jnp.zeros_like(o_ref)
+    kbuf[...] = jnp.zeros_like(kbuf)
+    vbuf[...] = jnp.zeros_like(vbuf)
+
+    def block_copies(i, buf, op):
+        """``start`` or ``wait`` the copies of work item i's live blocks (K
+        and V, at most C each) into half ``buf`` of the buffers."""
+        s, c = wslot_ref[i], wchunk_ref[i]
+        for j in range(C):
+            lb = c * C + j
+            phys = tables_ref[s * MB + jnp.minimum(lb, MB - 1)]
+            dst = pl.ds(j * bT, bT)
+
+            @pl.when(lb < nblk_ref[s])
+            def _():
+                for n, (hbm, vmem) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                    getattr(pltpu.make_async_copy(
+                        hbm.at[layer, phys], vmem.at[buf, dst],
+                        sems.at[n, buf]), op)()
+
+    # row h of a query's Hp rows owns lanes [h*hd, (h+1)*hd); rows >= H own none
+    row = jax.lax.broadcasted_iota(jnp.int32, (Hp, D), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (Hp, D), 1)
+    own = (lane >= row * hd) & (lane < (row + 1) * hd)
+
+    @pl.when(n_work > 0)
+    def _():
+        block_copies(0, 0, "start")
+
+    def item(i, carry):
+        buf = i % 2
+        s, c = wslot_ref[i], wchunk_ref[i]
+
+        @pl.when(i + 1 < n_work)
+        def _():
+            block_copies(i + 1, 1 - buf, "start")
+
+        @pl.when(c == 0)
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            for w in range(W):
+                qw = q_ref[pl.ds(s * W + w, 1), :]                    # [1, D]
+                q2_ref[w * Hp:(w + 1) * Hp, :] = jnp.where(
+                    own, qw, 0.0).astype(q2_ref.dtype)
+
+        block_copies(i, buf, "wait")
+        k = kbuf[buf]                                                  # [T, D]
+        v = vbuf[buf]
+        sc = jax.lax.dot_general(q2_ref[...], k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * scale
+        kpos = c * T + jax.lax.broadcasted_iota(jnp.int32, (Hp, T), 1)
+        sc = jnp.concatenate(
+            [jnp.where(kpos < limits_ref[s * W + w],
+                       sc[w * Hp:(w + 1) * Hp], _NEG_INF) for w in range(W)],
+            axis=0)                                                    # [N, T]
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(sc - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+        @pl.when((c + 1) * C >= nblk_ref[s])
+        def _():
+            o = acc_ref[...] / l_ref[...]                              # [N, D]
+            for w in range(W):
+                o_ref[pl.ds(s * W + w, 1), :] = jnp.sum(
+                    jnp.where(own, o[w * Hp:(w + 1) * Hp], 0.0),
+                    axis=0, keepdims=True)
+
+        return carry
+
+    jax.lax.fori_loop(0, n_work, item, 0)
+
+
+def _work_list(limits, block_T: int, C: int, max_items: int):
+    """(nblk [S], work_slot, work_chunk [max_items], n_work [1]) from the
+    per-query limits: slot s is visited ``ceil(nblk[s] / C)`` times, in slot
+    order; entries past ``n_work`` are never read."""
+    S = limits.shape[0]
+    nblk = -(-jnp.max(limits, axis=1) // block_T)
+    nchunk = -(-nblk // C)
+    ends = jnp.cumsum(nchunk)
+    i = jnp.arange(max_items, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1), S - 1)
+    chunk = i - (ends - nchunk)[slot]
+    return (nblk.astype(jnp.int32), slot.astype(jnp.int32),
+            chunk.astype(jnp.int32), ends[-1:].astype(jnp.int32))
+
+
+def paged_decode_attention(q, k_arena, v_arena, tables, limits, *, layer,
+                           n_heads: int):
+    """Attend each slot's live keys through its block table:
+    softmax(q K^T / sqrt(hd)) V.
+
+    q: [S, W, H*hd] — W tokens a slot. k_arena, v_arena:
+    [L, n_blocks, block_T, H*hd], the window's own K/V already in their
+    cells; ``layer`` (an int or an int32 scalar) picks the layer. tables:
+    [S, max_blocks] int32, logical -> physical block. limits: [S, W] int32 —
+    query w of slot s attends keys ``0 .. limits[s, w] - 1``; 0 for every w
+    marks a dead slot. Returns out [S, W, H*hd] in q's dtype (a dead slot's
+    rows are zeros)."""
+    S, W, D = q.shape
+    if D != k_arena.shape[-1] or D % n_heads or v_arena.shape != k_arena.shape:
+        raise ValueError(f"q {q.shape} / heads {n_heads} do not match arenas "
+                         f"{k_arena.shape}, {v_arena.shape}")
+    if tables.shape[0] != S or limits.shape != (S, W):
+        raise ValueError(f"tables {tables.shape} / limits {limits.shape} do "
+                         f"not match q {q.shape}")
+    # off the TPU the same kernel is emulated, as kernels/attention.py does
+    return _paged_call(q, k_arena, v_arena, tables, limits,
+                       jnp.asarray(layer, jnp.int32).reshape(1),
+                       n_heads=n_heads,
+                       interpret=jax.default_backend() != "tpu")
+
+
+# jitted with the layer as DATA: a model's layers share one trace and one
+# Mosaic lowering of the kernel (traced a layer, 36 of them cost a served
+# model 7 s of set-up before any compile cache is asked)
+@functools.partial(jax.jit, static_argnames=("n_heads", "interpret"))
+def _paged_call(q, k_arena, v_arena, tables, limits, layer, *, n_heads: int,
+                interpret: bool):
+    S, W, D = q.shape
+    bT = k_arena.shape[2]
+    H, hd, MB = n_heads, D // n_heads, tables.shape[1]
+    C = max(1, min(_CHUNK_T // bT, MB))          # blocks per work item
+    Hp = -(-H // 16) * 16                        # whole bf16 sublane tiles
+    max_items = S * -(-MB // C)
+    nblk, wslot, wchunk, nwork = _work_list(limits, bT, C, max_items)
+
+    kernel = functools.partial(
+        _kernel, W=W, H=H, hd=hd, Hp=Hp, bT=bT, C=C, MB=MB,
+        scale=1.0 / math.sqrt(hd))
+    rows = pl.BlockSpec((S * W, D), lambda i, *_: (0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(1,),
+            in_specs=[rows, hbm, hbm],
+            out_specs=rows,
+            scratch_shapes=[
+                pltpu.VMEM((2, C * bT, D), k_arena.dtype),
+                pltpu.VMEM((2, C * bT, D), v_arena.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((W * Hp, D), k_arena.dtype),
+                pltpu.VMEM((W * Hp, 1), jnp.float32),
+                pltpu.VMEM((W * Hp, 1), jnp.float32),
+                pltpu.VMEM((W * Hp, D), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((S * W, D), jnp.float32),
+        interpret=interpret,
+        name="paged_decode_attn",  # what a device trace calls the kernel
+    )(layer, tables.reshape(-1).astype(jnp.int32),
+      limits.reshape(-1).astype(jnp.int32), nblk, wslot, wchunk, nwork,
+      q.reshape(S * W, D).astype(jnp.float32), k_arena, v_arena)
+    return out.reshape(S, W, D).astype(q.dtype)

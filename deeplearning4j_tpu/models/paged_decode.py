@@ -5,17 +5,22 @@ PR 12's :class:`~.transformer.DecodeSlotPool` provisions a dense
 its sequence is 12 tokens or 500.  This module replaces that storage with a
 vLLM-shape paged arena behind the SAME one-signature decode step:
 
-- **arena** — K/V live in ``[L, n_blocks, block_T, H, hd]``; block 0 is a
-  scratch ("trash") block that absorbs writes from dead slots and from
-  prefill positions that belong to a shared block, so the jitted step never
-  branches on liveness;
+- **arena** — K/V live in ``[L, n_blocks, block_T, H*hd]`` (one block is a
+  contiguous, lane-dense tile holding every head); block 0 is a scratch
+  ("trash") block that absorbs a prefill's writes to positions that belong
+  to a shared block or lie past the reservation, so the jitted prefill
+  never branches on sharing (a decode step writes nothing of a dead slot).
+  Every program that takes the arenas updates them IN PLACE: they are
+  donated, each layer scatters its window's K/V into its cells of the
+  buffer it was given, and the same buffer is the result;
 - **block tables** — each slot owns a ``[max_blocks]`` int32 row mapping
   logical block -> physical block (0 = unmapped/trash).  The decode math
-  reaches its keys via ``arena[tables]`` — a gather that reproduces the
-  dense logical layout ``[S, max_len, H, hd]``, after which the einsum /
-  mask / softmax are byte-for-byte the dense pool's.  Tables change every
-  admission; shapes never do, so ``decode_traces`` still pins to 1 under
-  admit/retire/alloc churn;
+  reaches its keys through the tables in
+  :func:`~..kernels.paged_attention.paged_decode_attention`, which copies
+  only the blocks a slot's live length reaches: bytes moved follow live
+  tokens, not ``slots x max_len``.  Scale, mask and softmax are the dense
+  pool's.  Tables and lengths change every step; shapes never do, so
+  ``decode_traces`` still pins to 1 under admit/retire/alloc churn;
 - **copy-on-write prefix sharing** — an exact-match index (keyed on the
   literal prompt token bytes — no hash-collision wrongness) maps full
   prompt-prefix blocks and partial prompt tails to physical blocks.  An
@@ -43,19 +48,18 @@ offline ``generate`` driver) is the only caller — no internal locking.
 
 from __future__ import annotations
 
-import math
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.paged_attention import paged_decode_attention
 from ..monitoring.trace import span
 from .transformer import (
     TransformerConfig,
     KvCacheLostError,
     _layer_norm,
-    _NEG_INF,
     mlm_head,
     prefill_forward,
 )
@@ -136,37 +140,40 @@ def _embed_window(params, cfg: TransformerConfig, tokens, positions):
     return _layer_norm(h, e["ln_scale"], e["ln_bias"]).astype(cfg.compute_dtype)
 
 
-def _paged_window_block(cfg: TransformerConfig, p, h, kf, vf, tables, cells,
-                        kv_mask, n_blocks: int, block_T: int):
+def _write_window(arena, layer: int, tables, limits, x):
+    """``arena[layer, block, cell] = x[s, w]`` at position ``limits[s, w] - 1``
+    of every live slot s, through its table, in place; nothing of a dead slot
+    (limits 0) is written.  arena [L, n_blocks, block_T, D]; x [S, W, D]."""
+    n_blocks, block_T = arena.shape[1], arena.shape[2]
+    pos = limits - 1
+    block = jnp.take_along_axis(tables, jnp.maximum(pos, 0) // block_T, axis=1)
+    block = jnp.where(pos >= 0, block, n_blocks)  # out of range: dropped
+    return arena.at[layer, block.reshape(-1), (pos % block_T).reshape(-1)].set(
+        x.reshape(-1, x.shape[-1]).astype(arena.dtype), mode="drop")
+
+
+def _paged_window_block(cfg: TransformerConfig, p, h, kc, vc, layer: int,
+                        tables, limits):
     """One transformer block over a W-token decode window with paged K/V.
 
-    h [S,W,D]; kf/vf [n_blocks*block_T, H, hd] (this layer's FLAT arena);
-    tables [S, max_blocks] logical->physical; cells [S,W] flat arena cells
-    where this window's K/V land; kv_mask [S,W,max_len] over LOGICAL key
-    positions.  The gather ``arena[tables]`` rebuilds the dense logical
-    ``[S, max_len, H, hd]`` view, so everything after it — scale, mask
-    constant, softmax, dtype discipline — mirrors the dense
-    ``_decode_block`` exactly.  Returns (h, new_kf, new_vf)."""
-    S, W, D = h.shape
-    H, hd = cfg.n_heads, cfg.head_dim
+    h [S,W,D]; kc/vc [L, n_blocks, block_T, H*hd] (the WHOLE arenas, updated
+    in place at ``layer``); tables [S, max_blocks] logical->physical; limits
+    [S,W] — token w sits at position ``limits[s, w] - 1`` and attends its
+    slot's first ``limits[s, w]`` keys (0: dead slot).  Scale, mask
+    constant, softmax and dtype discipline mirror the dense
+    ``_decode_block``.  Returns (h, kc, vc)."""
     cd = cfg.compute_dtype
-    scale = 1.0 / math.sqrt(hd)
-    written = {}
+    arenas = {}
 
     def attn_sub(x):
         qkv = x @ p["qkv_w"].astype(cd) + p["qkv_b"].astype(cd)
-        q, k, v = (t.reshape(S, W, H, hd) for t in jnp.split(qkv, 3, axis=-1))
+        q, k, v = jnp.split(qkv, 3, axis=-1)
         # write-before-read: this window's K/V land in their cells first, so
         # stale/garbage cells at <= attended positions never survive a step
-        nkf = kf.at[cells.reshape(-1)].set(k.reshape(S * W, H, hd).astype(kf.dtype))
-        nvf = vf.at[cells.reshape(-1)].set(v.reshape(S * W, H, hd).astype(vf.dtype))
-        written["k"], written["v"] = nkf, nvf
-        g_k = nkf.reshape(n_blocks, block_T, H, hd)[tables].reshape(S, -1, H, hd)
-        g_v = nvf.reshape(n_blocks, block_T, H, hd)[tables].reshape(S, -1, H, hd)
-        scores = jnp.einsum("swhd,sthd->swht", q, g_k.astype(cd)) * scale
-        scores = jnp.where(kv_mask[:, :, None, :], scores, _NEG_INF)
-        w = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("swht,sthd->swhd", w, g_v.astype(cd)).reshape(S, W, D)
+        arenas["k"] = _write_window(kc, layer, tables, limits, k)
+        arenas["v"] = _write_window(vc, layer, tables, limits, v)
+        o = paged_decode_attention(q, arenas["k"], arenas["v"], tables, limits,
+                                   layer=layer, n_heads=cfg.n_heads)
         return o @ p["out_w"].astype(cd) + p["out_b"].astype(cd)
 
     def ffn_sub(x):
@@ -182,30 +189,33 @@ def _paged_window_block(cfg: TransformerConfig, p, h, kf, vf, tables, cells,
                         p["ln1_scale"], p["ln1_bias"]).astype(h.dtype)
         h = _layer_norm(h + ffn_sub(h.astype(cd)).astype(h.dtype),
                         p["ln2_scale"], p["ln2_bias"]).astype(h.dtype)
-    return h, written["k"], written["v"]
+    return h, arenas["k"], arenas["v"]
 
 
-def _paged_forward(params, cfg: TransformerConfig, tokens, positions, kfs, vfs,
-                   tables, n_blocks: int, block_T: int):
-    """Full-model W-token decode window over flat per-layer arenas.
+def _paged_forward(params, cfg: TransformerConfig, tokens, positions, kc, vc,
+                   tables):
+    """Full-model W-token decode window over the paged arenas.
 
-    tokens/positions [S,W]; kfs/vfs: python lists of per-layer flat arenas
-    (functional update — returns new lists).  Returns
-    (logits [S,W,V] fp32, new_kfs, new_vfs)."""
-    max_len = tables.shape[1] * block_T
+    tokens/positions [S,W]; kc/vc [L, n_blocks, block_T, H*hd], written in
+    place layer by layer.  A slot is live iff its logical block 0 is mapped
+    (a released slot's table row is all trash).  Returns
+    (logits [S,W,V] fp32, kc, vc)."""
     h = _embed_window(params, cfg, tokens, positions)
-    lb = positions // block_T
-    phys = jnp.take_along_axis(tables, lb, axis=1)
-    cells = phys * block_T + positions % block_T
-    kv_mask = jnp.arange(max_len)[None, None, :] <= positions[:, :, None]
-    new_k, new_v = [], []
+    limits = jnp.where(tables[:, :1] > 0, positions + 1, 0)
     for l in range(cfg.n_layers):
-        h, k_l, v_l = _paged_window_block(
-            cfg, params["blocks"][l], h, kfs[l], vfs[l], tables, cells,
-            kv_mask, n_blocks, block_T)
-        new_k.append(k_l)
-        new_v.append(v_l)
-    return mlm_head(params, h, cfg), new_k, new_v
+        h, kc, vc = _paged_window_block(
+            cfg, params["blocks"][l], h, kc, vc, l, tables, limits)
+    return mlm_head(params, h, cfg), kc, vc
+
+
+def _write_blocks(arena, dest_blocks, x):
+    """``arena[:, dest_blocks[j]] = x[:, j]`` for every j, in place:
+    arena [L, n_blocks, block_T, D], x [L, nb, block_T, D]."""
+    def put(j, a):
+        blk = jax.lax.dynamic_slice_in_dim(x, j, 1, axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(a, blk.astype(a.dtype),
+                                                   dest_blocks[j], axis=1)
+    return jax.lax.fori_loop(0, x.shape[1], put, arena)
 
 
 class PagedDecodeSlotPool:
@@ -217,8 +227,9 @@ class PagedDecodeSlotPool:
 
     - ``can_admit``/``request_blocks``/``total_blocks`` — block-priced
       admission control (queue-head gating and at-the-door 400s);
-    - ``block_stats()`` — occupancy, CoW sharing and speculative counters
-      for ``stats()``/telemetry;
+    - ``block_stats()`` — occupancy, CoW sharing, speculative counters and
+      the blocks attention read against the blocks mapped, for
+      ``stats()``/telemetry;
     - multi-token steps: ``step()`` returns ``{slot: [tokens...]}`` (one
       token per step plain, up to ``spec_tokens + 1`` speculative), each
       list clamped to the slot's remaining ``max_new_tokens`` budget.
@@ -302,34 +313,28 @@ class PagedDecodeSlotPool:
         # cumulative speculative counters (0 forever on a plain pool)
         self.spec_proposed = 0
         self.spec_accepted = 0
+        # cumulative blocks a step's attention is asked to visit (live slots,
+        # up to their live length) against the blocks the tables map
+        self.kv_blocks_read = 0
+        self.kv_blocks_mapped = 0
         # python-side trace counters: incremented when jax TRACES (not runs)
         # the fns — tests pin "one decode signature under membership churn"
         self.decode_traces = 0
         self.prefill_traces = 0
 
-        NB, bT = self.n_blocks, self.block_T
+        bT = self.block_T
         spec = draft_cfg is not None
         k = self.spec_tokens
 
-        def _flat(kc):
-            return [kc[l].reshape(NB * bT, kc.shape[3], kc.shape[4])
-                    for l in range(kc.shape[0])]
-
-        def _stack(flats, H, hd):
-            return jnp.stack([f.reshape(NB, bT, H, hd) for f in flats])
-
         def _decode(params, kc, vc, tables, tokens, positions):
             self.decode_traces += 1
-            logits, nk, nv = _paged_forward(
-                params, cfg, tokens[:, None], positions[:, None],
-                _flat(kc), _flat(vc), tables, NB, bT)
+            logits, kc, vc = _paged_forward(
+                params, cfg, tokens[:, None], positions[:, None], kc, vc, tables)
             nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-            return (_stack(nk, cfg.n_heads, cfg.head_dim),
-                    _stack(nv, cfg.n_heads, cfg.head_dim), nxt)
+            return kc, vc, nxt
 
         def _spec(params, dparams, kc, vc, dkc, dvc, tables, tokens, positions):
             self.decode_traces += 1
-            dkf, dvf = _flat(dkc), _flat(dvc)
             # --- draft phase: k+1 chained single-token passes.  Pass j
             # consumes window[j] at position p+j; passes 0..k-1 propose
             # d_1..d_k; pass k only WRITES draft K/V at p+k so a fully
@@ -337,39 +342,35 @@ class PagedDecodeSlotPool:
             window = [tokens]
             for j in range(k + 1):
                 pos_j = (positions + j)[:, None]
-                logits, dkf, dvf = _paged_forward(
+                logits, dkc, dvc = _paged_forward(
                     dparams, draft_cfg, window[j][:, None], pos_j,
-                    dkf, dvf, tables, NB, bT)
+                    dkc, dvc, tables)
                 if j < k:
                     window.append(
                         jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32))
             win = jnp.stack(window, axis=1)                      # [S, k+1]
             pos_w = positions[:, None] + jnp.arange(k + 1)[None, :]
             # --- verify phase: ONE batched target forward over the window
-            logits, nk, nv = _paged_forward(
-                params, cfg, win, pos_w, _flat(kc), _flat(vc), tables, NB, bT)
+            logits, kc, vc = _paged_forward(
+                params, cfg, win, pos_w, kc, vc, tables)
             ver = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, k+1]
             # greedy acceptance: d_i accepted while it matches the target's
             # own greedy continuation; emitted tokens are ver[:, :n_acc]
             m = (win[:, 1:] == ver[:, :-1]).astype(jnp.int32)
             n_acc = 1 + jnp.cumprod(m, axis=1).sum(axis=1)
-            return (_stack(nk, cfg.n_heads, cfg.head_dim),
-                    _stack(nv, cfg.n_heads, cfg.head_dim),
-                    _stack(dkf, draft_cfg.n_heads, draft_cfg.head_dim),
-                    _stack(dvf, draft_cfg.n_heads, draft_cfg.head_dim),
-                    ver, n_acc.astype(jnp.int32))
+            return kc, vc, dkc, dvc, ver, n_acc.astype(jnp.int32)
 
         def _prefill_blocked(ks):
-            # [L, 1, H, Tb, hd] -> [L, Tb//bT, bT, H, hd] for the arena layout
+            # [L, 1, H, Tb, hd] -> [L, Tb//bT, bT, H*hd] for the arena layout
             x = jnp.transpose(ks[:, 0], (0, 2, 1, 3))
             L_, Tb, H_, hd_ = x.shape
-            return x.reshape(L_, Tb // bT, bT, H_, hd_)
+            return x.reshape(L_, Tb // bT, bT, H_ * hd_)
 
         def _prefill(params, kc, vc, dest_blocks, tokens, length):
             self.prefill_traces += 1
             h, ks, vs = prefill_forward(params, tokens, cfg)
-            kc = kc.at[:, dest_blocks].set(_prefill_blocked(ks).astype(kc.dtype))
-            vc = vc.at[:, dest_blocks].set(_prefill_blocked(vs).astype(vc.dtype))
+            kc = _write_blocks(kc, dest_blocks, _prefill_blocked(ks))
+            vc = _write_blocks(vc, dest_blocks, _prefill_blocked(vs))
             last = h[0, length - 1]
             logits = mlm_head(params, last[None], cfg)[0]
             return kc, vc, jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -378,29 +379,28 @@ class PagedDecodeSlotPool:
                           tokens, length):
             self.prefill_traces += 1
             h, ks, vs = prefill_forward(params, tokens, cfg)
-            kc = kc.at[:, dest_blocks].set(_prefill_blocked(ks).astype(kc.dtype))
-            vc = vc.at[:, dest_blocks].set(_prefill_blocked(vs).astype(vc.dtype))
+            kc = _write_blocks(kc, dest_blocks, _prefill_blocked(ks))
+            vc = _write_blocks(vc, dest_blocks, _prefill_blocked(vs))
             _, dks, dvs = prefill_forward(dparams, tokens, draft_cfg)
-            dkc = dkc.at[:, dest_blocks].set(_prefill_blocked(dks).astype(dkc.dtype))
-            dvc = dvc.at[:, dest_blocks].set(_prefill_blocked(dvs).astype(dvc.dtype))
+            dkc = _write_blocks(dkc, dest_blocks, _prefill_blocked(dks))
+            dvc = _write_blocks(dvc, dest_blocks, _prefill_blocked(dvs))
             last = h[0, length - 1]
             logits = mlm_head(params, last[None], cfg)[0]
             return kc, vc, dkc, dvc, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
+        def _copy_block(a, src, dst):
+            blk = jax.lax.dynamic_slice_in_dim(a, src, 1, axis=1)
+            return jax.lax.dynamic_update_slice_in_dim(a, blk, dst, axis=1)
+
         def _copy(kc, vc, src, dst):
-            kc = kc.at[:, dst].set(kc[:, src])
-            vc = vc.at[:, dst].set(vc[:, src])
-            return kc, vc
+            return _copy_block(kc, src, dst), _copy_block(vc, src, dst)
 
         def _copy_spec(kc, vc, dkc, dvc, src, dst):
-            kc = kc.at[:, dst].set(kc[:, src])
-            vc = vc.at[:, dst].set(vc[:, src])
-            dkc = dkc.at[:, dst].set(dkc[:, src])
-            dvc = dvc.at[:, dst].set(dvc[:, src])
-            return kc, vc, dkc, dvc
+            return (_copy_block(kc, src, dst), _copy_block(vc, src, dst),
+                    _copy_block(dkc, src, dst), _copy_block(dvc, src, dst))
 
-        # arena buffers are donated: steps update them in place instead of
-        # holding two live copies of the pool's largest allocation
+        # arena buffers are donated and every program above returns the buffer
+        # it was given, updated: a step moves the bytes it writes, not the arena
         if spec:
             self._decode_fn = jax.jit(_spec, donate_argnums=(2, 3, 4, 5))
             self._prefill_fn = jax.jit(_prefill_spec, donate_argnums=(2, 3, 4, 5))
@@ -412,7 +412,7 @@ class PagedDecodeSlotPool:
 
     def _new_arena(self, cfg: TransformerConfig):
         shape = (cfg.n_layers, self.n_blocks, self.block_T,
-                 cfg.n_heads, cfg.head_dim)
+                 cfg.n_heads * cfg.head_dim)
         return (jnp.zeros(shape, cfg.compute_dtype),
                 jnp.zeros(shape, cfg.compute_dtype))
 
@@ -455,7 +455,11 @@ class PagedDecodeSlotPool:
 
     def block_stats(self) -> Dict[str, int]:
         """Occupancy / sharing / speculation counters for ``stats()`` and
-        the ``tdl_decode_blocks_*`` + ``tdl_decode_spec_*`` families."""
+        the ``tdl_decode_blocks_*`` + ``tdl_decode_spec_*`` families, and
+        two cumulative counts a step: ``kv_blocks_read`` (what the attention
+        kernel is asked to visit: over live slots, the blocks up to the
+        window's last position) and ``kv_blocks_mapped`` (``slots x
+        max_blocks``: what a dense gather through the tables would visit)."""
         rc = self._alloc.refcount[1:]  # trash block is bookkeeping, not capacity
         return {
             "blocks_total": self.total_blocks,
@@ -464,6 +468,8 @@ class PagedDecodeSlotPool:
             "cow_saved_blocks": int(np.maximum(rc - 1, 0).sum()),
             "spec_proposed": self.spec_proposed,
             "spec_accepted": self.spec_accepted,
+            "kv_blocks_read": self.kv_blocks_read,
+            "kv_blocks_mapped": self.kv_blocks_mapped,
         }
 
     # -- admission planning ------------------------------------------------
@@ -662,9 +668,15 @@ class PagedDecodeSlotPool:
             tables = jnp.asarray(self._tables)
             toks = jnp.asarray(self._tokens)
             pos = jnp.asarray(self._positions)
+        live_blocks = int(
+            (-(-(self._positions[live] + window) // self.block_T)).sum())
+        mapped_blocks = self.slots * self.max_blocks
+        self.kv_blocks_read += live_blocks
+        self.kv_blocks_mapped += mapped_blocks
         out: Dict[int, List[int]] = {}
         try:
-            with span("kv.step.dispatch"):
+            with span("kv.step.dispatch", live_blocks=live_blocks,
+                      mapped_blocks=mapped_blocks):
                 if self.draft_cfg is not None:
                     (self._kc, self._vc, self._dkc, self._dvc, ver, n_acc) = \
                         self._decode_fn(self.params, self.draft_params,

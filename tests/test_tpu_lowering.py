@@ -1,0 +1,161 @@
+"""Ahead-of-time compiles for a described TPU v5e, from the CPU: what the
+chip's compiler (Mosaic included) would refuse is refused here, at no chip
+time, and the compiled program shows whether the paged arenas are updated in
+place. Nothing runs, so nothing here is a time or a result.
+
+All such compiles live in THIS file: only one process may hold the TPU
+library, the worker that is given this file loads it inside the fixture, and
+every other worker merely collects the tests (on-chip-measurement guide,
+section 2). The kernels choose interpret mode from ``jax.default_backend()``,
+which is the CPU here, so the tests steer that one call onto the chip's path.
+"""
+
+import os
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.kernels.paged_attention import paged_decode_attention
+from deeplearning4j_tpu.models import transformer as tfm
+from deeplearning4j_tpu.models.paged_decode import PagedDecodeSlotPool
+
+# gpt2-large.chat's widths (benchmark/configs/gpt2-large.json,
+# benchmark/traffic/chat.json); the depth is cut to 2 for the test's time
+SLOTS, BLOCK_T, MAX_LEN, HEADS, HEAD_DIM, LAYERS = 16, 32, 1024, 20, 64, 2
+D_MODEL = HEADS * HEAD_DIM
+MAX_BLOCKS = MAX_LEN // BLOCK_T
+N_BLOCKS = 1 + SLOTS * MAX_BLOCKS
+ARENA = (LAYERS, N_BLOCKS, BLOCK_T, D_MODEL)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_chip_path(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _shape(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.fixture(scope="module")
+def pool_and_params(one_chip):
+    """The cell's pool over SHAPES: no parameter and no arena is allocated."""
+    cfg = tfm.TransformerConfig(
+        vocab_size=50257, max_len=MAX_LEN, d_model=D_MODEL, n_heads=HEADS,
+        n_layers=LAYERS, d_ff=4 * D_MODEL, causal=True, dropout=0.0,
+        compute_dtype=jnp.bfloat16, norm_position="pre")
+    shapes = jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg))
+    params = jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), shapes)
+    mp = pytest.MonkeyPatch()
+    mp.setattr(PagedDecodeSlotPool, "_new_arena", lambda self, cfg: (None, None))
+    try:
+        pool = PagedDecodeSlotPool(shapes, cfg, slots=SLOTS, block_T=BLOCK_T,
+                                   max_len=MAX_LEN)
+    finally:
+        mp.undo()
+    assert pool.n_blocks == N_BLOCKS and pool.max_blocks == MAX_BLOCKS
+    return pool, params
+
+
+def _arena_ops(hlo_text):
+    """(opcode, shape) of every instruction of the entry computation (what
+    the device runs, one kernel each) whose result is shaped like an arena or
+    a layer of one, apart from parameters and tuple plumbing."""
+    found = []
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    for m in re.finditer(r"= (\w+\[[\d,]+\])\S* ([\w-]+)\(", entry):
+        dims = m.group(1)
+        if any(s in dims for s in (f"[{LAYERS},{N_BLOCKS},", f"[1,{N_BLOCKS},",
+                                   f"[{N_BLOCKS},{BLOCK_T},",
+                                   f"[{N_BLOCKS * BLOCK_T},")):
+            if m.group(2) not in ("parameter", "get-tuple-element", "bitcast",
+                                  "tuple", "custom-call"):
+                found.append((m.group(2), dims))
+    return found
+
+
+def _assert_in_place(lowered, compiled, n_arenas):
+    """Donation survived: the lowered module ties each arena argument to a
+    result, the compiled one aliases all their bytes and holds no temporary
+    of an arena's size, and no copy of an arena (or of a layer of one) is
+    left in it."""
+    text = lowered.as_text()
+    arena_type = "tensor<" + "x".join(str(d) for d in ARENA) + "xbf16>"
+    tied = re.findall(re.escape(arena_type) + r" \{[^%]*?tf\.aliasing_output = (\d+)",
+                      text)
+    assert len(tied) == len(set(tied)) == n_arenas, tied
+    arena_bytes = 2 * LAYERS * N_BLOCKS * BLOCK_T * D_MODEL
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= n_arenas * arena_bytes
+    assert mem.temp_size_in_bytes < arena_bytes // LAYERS // 2
+    ops = _arena_ops(compiled.as_text())
+    assert not [op for op in ops if op[0].startswith("copy")], ops
+    return ops
+
+
+def test_decode_program_compiles_for_the_chip_with_arenas_in_place(
+        one_chip, on_chip_path, pool_and_params):
+    pool, params = pool_and_params
+    arena = _shape(one_chip, ARENA, jnp.bfloat16)
+    lowered = pool._decode_fn.lower(
+        params, arena, arena, _shape(one_chip, (SLOTS, MAX_BLOCKS), jnp.int32),
+        _shape(one_chip, (SLOTS,), jnp.int32), _shape(one_chip, (SLOTS,), jnp.int32))
+    compiled = lowered.compile()  # a Mosaic error would be raised here
+    ops = _assert_in_place(lowered, compiled, n_arenas=2)
+    # what XLA does to an arena is one in-place scatter of the window's rows
+    # a layer, for K and for V, and nothing else
+    assert len(ops) == 2 * LAYERS and all(op == "fusion" for op, _ in ops), ops
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == LAYERS
+    assert "paged_decode_attn" in text
+
+
+@pytest.mark.parametrize("bucket", [128, 1024])
+def test_prefill_program_compiles_for_the_chip_with_arenas_in_place(
+        one_chip, on_chip_path, pool_and_params, bucket):
+    pool, params = pool_and_params
+    arena = _shape(one_chip, ARENA, jnp.bfloat16)
+    lowered = pool._prefill_fn.lower(
+        params, arena, arena, _shape(one_chip, (bucket // BLOCK_T,), jnp.int32),
+        _shape(one_chip, (1, bucket), jnp.int32), _shape(one_chip, (), jnp.int32))
+    _assert_in_place(lowered, lowered.compile(), n_arenas=2)
+
+
+def test_copy_on_write_program_compiles_for_the_chip_in_place(
+        one_chip, on_chip_path, pool_and_params):
+    pool, _ = pool_and_params
+    arena = _shape(one_chip, ARENA, jnp.bfloat16)
+    scalar = _shape(one_chip, (), jnp.int32)
+    lowered = pool._copy_fn.lower(arena, arena, scalar, scalar)
+    _assert_in_place(lowered, lowered.compile(), n_arenas=2)
+
+
+@pytest.mark.parametrize("W", [1, 5], ids=["W1", "W5"])
+def test_paged_attention_kernel_compiles_for_the_chip(one_chip, on_chip_path, W):
+    """The verify pass of speculation (W = spec_tokens + 1) has no cell: its
+    kernel is compiled here at the cell's widths all the same."""
+    fn = jax.jit(lambda q, k, v, tables, limits: paged_decode_attention(
+        q, k, v, tables, limits, layer=1, n_heads=HEADS))
+    arena = _shape(one_chip, ARENA, jnp.bfloat16)
+    compiled = fn.lower(_shape(one_chip, (SLOTS, W, D_MODEL), jnp.bfloat16),
+                        arena, arena,
+                        _shape(one_chip, (SLOTS, MAX_BLOCKS), jnp.int32),
+                        _shape(one_chip, (SLOTS, W), jnp.int32)).compile()
+    assert not _arena_ops(compiled.as_text())  # the arenas are read where they lie
+    assert "paged_decode_attn" in compiled.as_text()
