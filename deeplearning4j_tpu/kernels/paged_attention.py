@@ -1,5 +1,7 @@
-"""Paged decode attention: one Pallas kernel that reads K/V through the block
-tables, live blocks only.
+"""Paged decode attention: Pallas kernels that read the cache through the
+block tables, live blocks only. One kernel a family of layers:
+``paged_decode_attention`` for heads with their own K and V (below), and
+``paged_mla_decode_attention`` for absorbed latent attention (at the end).
 
 The paged pool (``models/paged_decode.py``) keeps K and V in arenas
 ``[L, n_blocks, block_T, H*hd]`` — a block is one contiguous, lane-dense
@@ -223,3 +225,148 @@ def _paged_call(q, k_arena, v_arena, tables, limits, layer, *, n_heads: int,
       limits.reshape(-1).astype(jnp.int32), nblk, wslot, wchunk, nwork,
       q.reshape(S * W, D).astype(jnp.float32), k_arena, v_arena)
     return out.reshape(S, W, D).astype(q.dtype)
+
+
+# ------------------------------------------------- absorbed latent attention
+#
+# A latent (MLA) model caches ONE row a token, ``[c | kr]``: the latent that
+# every head's keys and values are made from, then the rotary key they share.
+# In the absorbed form a head's query is already in that space
+# (``[q_nope Wuk^T | q_rope]``), so a slot's attention is its H query rows
+# against one ``[keys, C + R]`` tile whose first C lanes are also the values:
+# no block-diagonal layout, one key tile for all heads. Work list, prefetch,
+# masking and the online softmax are the kernel's above.
+
+
+def _mla_kernel(layer_ref, tables_ref, limits_ref, nblk_ref, wslot_ref,
+                wchunk_ref, nwork_ref, q_ref, kv_hbm, o_ref, kvbuf, sems,
+                m_ref, l_ref, acc_ref, *, H, C, bT, NC, MB, scale):
+    """q_ref [S*H, C+R] (a slot's heads are consecutive rows); kv_hbm
+    [L, NB, bT, C+R], left in HBM; o_ref [S*H, C]; kvbuf [2, NC*bT, C+R];
+    m/l [H, 1]; acc [H, C]."""
+    T = NC * bT
+    layer = layer_ref[0]
+    n_work = nwork_ref[0]
+
+    # as above: dead slots are never visited, and blocks past a slot's live
+    # length are not copied, so rows and buffers start defined and finite
+    o_ref[...] = jnp.zeros_like(o_ref)
+    kvbuf[...] = jnp.zeros_like(kvbuf)
+
+    def block_copies(i, buf, op):
+        s, c = wslot_ref[i], wchunk_ref[i]
+        for j in range(NC):
+            lb = c * NC + j
+            phys = tables_ref[s * MB + jnp.minimum(lb, MB - 1)]
+
+            @pl.when(lb < nblk_ref[s])
+            def _():
+                getattr(pltpu.make_async_copy(
+                    kv_hbm.at[layer, phys], kvbuf.at[buf, pl.ds(j * bT, bT)],
+                    sems.at[buf]), op)()
+
+    @pl.when(n_work > 0)
+    def _():
+        block_copies(0, 0, "start")
+
+    def item(i, carry):
+        buf = i % 2
+        s, c = wslot_ref[i], wchunk_ref[i]
+
+        @pl.when(i + 1 < n_work)
+        def _():
+            block_copies(i + 1, 1 - buf, "start")
+
+        @pl.when(c == 0)
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        block_copies(i, buf, "wait")
+        kv = kvbuf[buf]                                            # [T, C+R]
+        q = q_ref[pl.ds(pl.multiple_of(s * H, H), H), :]           # [H, C+R]
+        sc = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * scale
+        kpos = c * T + jax.lax.broadcasted_iota(jnp.int32, (H, T), 1)
+        sc = jnp.where(kpos < limits_ref[s], sc, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(sc - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(kv.dtype), kv[:, :C], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+        @pl.when((c + 1) * NC >= nblk_ref[s])
+        def _():
+            o_ref[pl.ds(pl.multiple_of(s * H, H), H), :] = (
+                acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+        return carry
+
+    jax.lax.fori_loop(0, n_work, item, 0)
+
+
+def paged_mla_decode_attention(q, arena, tables, limits, *, layer, scale: float,
+                               latent_width: int):
+    """Absorbed latent attention of one decode step through the block tables:
+    ``softmax(q K^T * scale) K[:, :latent_width]`` over each slot's live rows.
+
+    q: [S, H, C + R], a slot's H heads already in the cache's space
+    (``[q_nope Wuk^T | q_rope]``). arena: [L, n_blocks, block_T, C + R], the
+    step's own rows already in their cells; ``layer`` picks the layer.
+    tables: [S, max_blocks] int32. limits: [S] int32: slot s attends rows
+    ``0 .. limits[s] - 1``; 0 marks a dead slot. Returns [S, H, C] in q's
+    dtype (a dead slot's rows are zeros)."""
+    S, H, D = q.shape
+    if D != arena.shape[-1] or not 0 < latent_width <= D:
+        raise ValueError(f"q {q.shape} / latent width {latent_width} do not "
+                         f"match the arena {arena.shape}")
+    if tables.shape[0] != S or limits.shape != (S,):
+        raise ValueError(f"tables {tables.shape} / limits {limits.shape} do "
+                         f"not match q {q.shape}")
+    return _mla_call(q, arena, tables, limits,
+                     jnp.asarray(layer, jnp.int32).reshape(1),
+                     scale=float(scale), latent_width=latent_width,
+                     interpret=jax.default_backend() != "tpu")
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "latent_width", "interpret"))
+def _mla_call(q, arena, tables, limits, layer, *, scale: float,
+              latent_width: int, interpret: bool):
+    S, H, D = q.shape
+    bT, MB, C = arena.shape[2], tables.shape[1], latent_width
+    NC = max(1, min(_CHUNK_T // bT, MB))         # blocks per work item
+    max_items = S * -(-MB // NC)
+    nblk, wslot, wchunk, nwork = _work_list(limits[:, None], bT, NC, max_items)
+
+    kernel = functools.partial(_mla_kernel, H=H, C=C, bT=bT, NC=NC, MB=MB,
+                               scale=scale)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(1,),
+            in_specs=[pl.BlockSpec((S * H, D), lambda i, *_: (0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((S * H, C), lambda i, *_: (0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, NC * bT, D), arena.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, C), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((S * H, C), q.dtype),
+        # every slot's queries and outputs stay in VMEM for the whole call:
+        # 64 slots x 64 heads x (576 + 512) values is past the default limit
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+        name="paged_mla_decode_attn",  # what a device trace calls the kernel
+    )(layer, tables.reshape(-1).astype(jnp.int32), limits.astype(jnp.int32),
+      nblk, wslot, wchunk, nwork, q.reshape(S * H, D).astype(arena.dtype), arena)
+    return out.reshape(S, H, C)

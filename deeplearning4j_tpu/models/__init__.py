@@ -24,13 +24,13 @@ from .nasnet import NASNet
 from .vgg import VGG16, VGG19
 from .text_lstm import TextGenerationLSTM
 from .zoo_ext import AlexNet, Darknet19, SqueezeNet, UNet, Xception
-from .moe import MoEConfig, init_moe_params, moe_ffn, moe_partition_specs
+from .kimi_k2 import KimiK2Config
 from .vae import VariationalAutoencoder
 from .yolo import TinyYOLO, Yolo2OutputLayer
 
 __all__ = [
     "AlexNet", "Darknet19", "SqueezeNet", "UNet", "Xception",
-    "MoEConfig", "init_moe_params", "moe_ffn", "moe_partition_specs",
+    "KimiK2Config",
     "VariationalAutoencoder", "TinyYOLO", "Yolo2OutputLayer",
     "TransformerConfig",
     "transformer_forward",

@@ -42,6 +42,19 @@ vLLM-shape paged arena behind the SAME one-signature decode step:
   positions hold stale K/V that the sequential write-before-read discipline
   overwrites before it is ever attended.
 
+**Model families.** The pool does not know a layer. The config it is given
+answers ``decode_family()`` with a small object that says what one token
+stores in a block (``cache_widths``: one arena a width, ``[L, n_blocks,
+block_T, width]``) and runs the layers: ``prefill`` (a padded prompt ->
+its last hidden state and the rows to store), ``decode_window`` (a step of
+every slot over the arenas, through the tables) and ``head``. The GPT-2 /
+BERT family (:class:`TransformerDecodeFamily`: K and V arenas of ``H*hd``)
+and the latent family of ``models/kimi_k2.py`` (one arena of 576) share the
+allocator, the tables, the prefix index, copy-on-write, admit / step /
+release, the counters and the donated in-place programs below. A family
+with ``stat_names`` returns that many int32 counters from a step; they come
+back in the one fetch that brings the tokens.
+
 Single-owner object like the dense pool: the decode loop thread (or the
 offline ``generate`` driver) is the only caller — no internal locking.
 """
@@ -208,6 +221,45 @@ def _paged_forward(params, cfg: TransformerConfig, tokens, positions, kc, vc,
     return mlm_head(params, h, cfg), kc, vc
 
 
+class TransformerDecodeFamily:
+    """``models/transformer.py``'s layers for the slot pool: every head has
+    its own K and V, so a token stores ``H*hd`` values in each of two
+    arenas. ``TransformerConfig.decode_family()`` returns one."""
+
+    speculative = True   # ``decode_window`` takes W = spec_tokens + 1 tokens
+    stat_names = ()      # a step counts nothing of its own
+    name = "transformer"
+
+    def __init__(self, cfg: TransformerConfig):
+        self.cfg = cfg
+        self.n_layers = cfg.n_layers
+        self.cache_widths = (cfg.n_heads * cfg.head_dim,) * 2
+        self.cache_dtype = cfg.compute_dtype
+
+    def prefill(self, params, tokens, length):
+        """tokens [1, Tb] -> (hidden state at ``length - 1`` [D], the rows to
+        store: K and V, each [L, Tb, H*hd])."""
+        h, ks, vs = prefill_forward(params, tokens, self.cfg)
+
+        def rows(x):  # [L, 1, H, Tb, hd] -> [L, Tb, H*hd]
+            x = jnp.transpose(x[:, 0], (0, 2, 1, 3))
+            return x.reshape(*x.shape[:2], -1)
+
+        return h[0, length - 1], (rows(ks), rows(vs))
+
+    def head(self, params, h):
+        return mlm_head(params, h, self.cfg)
+
+    def decode_window(self, params, tokens, positions, arenas, tables):
+        """tokens / positions [S, W] -> (logits [S, W, V], arenas, None)."""
+        logits, kc, vc = _paged_forward(params, self.cfg, tokens, positions,
+                                        *arenas, tables)
+        return logits, (kc, vc), None
+
+    def cumulative_stats(self, sums, steps) -> Dict[str, int]:
+        return {}
+
+
 def _write_blocks(arena, dest_blocks, x):
     """``arena[:, dest_blocks[j]] = x[:, j]`` for every j, in place:
     arena [L, n_blocks, block_T, D], x [L, nb, block_T, D]."""
@@ -239,12 +291,17 @@ class PagedDecodeSlotPool:
     ``spec_tokens`` drafted per target step.
     """
 
-    def __init__(self, params, cfg: TransformerConfig, *, slots: int = 8,
+    def __init__(self, params, cfg, *, slots: int = 8,
                  block_T: int = 16, n_blocks: Optional[int] = None,
                  max_len: Optional[int] = None, eos_id: Optional[int] = None,
                  min_prompt_bucket: int = 16,
-                 draft_params=None, draft_cfg: Optional[TransformerConfig] = None,
-                 spec_tokens: int = 4):
+                 draft_params=None, draft_cfg=None, spec_tokens: int = 4):
+        fam = cfg.decode_family()
+        dfam = draft_cfg.decode_family() if draft_cfg is not None else None
+        if draft_cfg is not None and not fam.speculative:
+            raise ValueError(
+                f"speculative decoding is not built for the {fam.name} "
+                f"family: its decode step takes one token a slot")
         if not cfg.causal:
             raise ValueError(
                 "autoregressive decode needs a causal config "
@@ -293,10 +350,11 @@ class PagedDecodeSlotPool:
                     f"draft positional range {draft_cfg.max_len} < pool "
                     f"max_len {self.max_len}")
 
+        self.family = fam
         self._alloc = BlockAllocator(self.n_blocks)
-        self._kc, self._vc = self._new_arena(cfg)
-        self._dkc, self._dvc = (self._new_arena(draft_cfg)
-                                if draft_cfg is not None else (None, None))
+        self._arenas = tuple(self._new_arena(cfg))
+        self._draft_arenas = (tuple(self._new_arena(draft_cfg))
+                              if draft_cfg is not None else ())
         self._tables = np.zeros((slots, self.max_blocks), np.int32)
         self._active = np.zeros(slots, bool)
         self._positions = np.zeros(slots, np.int32)
@@ -317,6 +375,11 @@ class PagedDecodeSlotPool:
         # up to their live length) against the blocks the tables map
         self.kv_blocks_read = 0
         self.kv_blocks_mapped = 0
+        # what the family's steps counted (``stat_names``): the last step's
+        # and the running sums; a family that counts nothing has neither
+        self.last_step_stats: Dict[str, int] = {}
+        self.family_stats = {name: 0 for name in fam.stat_names}
+        self.family_steps = 0
         # python-side trace counters: incremented when jax TRACES (not runs)
         # the fns — tests pin "one decode signature under membership churn"
         self.decode_traces = 0
@@ -325,16 +388,26 @@ class PagedDecodeSlotPool:
         bT = self.block_T
         spec = draft_cfg is not None
         k = self.spec_tokens
+        n = len(fam.cache_widths)                        # arenas of the target
+        m = len(dfam.cache_widths) if spec else 0        # and of the draft
 
-        def _decode(params, kc, vc, tables, tokens, positions):
+        # every program takes its arenas as leading positional arguments
+        # (after the parameters), donates them and returns them first
+
+        def _decode(params, *args):
             self.decode_traces += 1
-            logits, kc, vc = _paged_forward(
-                params, cfg, tokens[:, None], positions[:, None], kc, vc, tables)
+            arenas, (tables, tokens, positions) = args[:n], args[n:]
+            logits, arenas, stats = fam.decode_window(
+                params, tokens[:, None], positions[:, None], arenas, tables)
             nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-            return kc, vc, nxt
+            # the step's counters ride behind the tokens: one fetch brings both
+            out = nxt if stats is None else jnp.concatenate([nxt, stats])
+            return (*arenas, out)
 
-        def _spec(params, dparams, kc, vc, dkc, dvc, tables, tokens, positions):
+        def _spec(params, dparams, *args):
             self.decode_traces += 1
+            arenas, darenas = args[:n], args[n:n + m]
+            tables, tokens, positions = args[n + m:]
             # --- draft phase: k+1 chained single-token passes.  Pass j
             # consumes window[j] at position p+j; passes 0..k-1 propose
             # d_1..d_k; pass k only WRITES draft K/V at p+k so a fully
@@ -342,79 +415,105 @@ class PagedDecodeSlotPool:
             window = [tokens]
             for j in range(k + 1):
                 pos_j = (positions + j)[:, None]
-                logits, dkc, dvc = _paged_forward(
-                    dparams, draft_cfg, window[j][:, None], pos_j,
-                    dkc, dvc, tables)
+                logits, darenas, _ = dfam.decode_window(
+                    dparams, window[j][:, None], pos_j, darenas, tables)
                 if j < k:
                     window.append(
                         jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32))
             win = jnp.stack(window, axis=1)                      # [S, k+1]
             pos_w = positions[:, None] + jnp.arange(k + 1)[None, :]
             # --- verify phase: ONE batched target forward over the window
-            logits, kc, vc = _paged_forward(
-                params, cfg, win, pos_w, kc, vc, tables)
+            logits, arenas, _ = fam.decode_window(params, win, pos_w, arenas,
+                                                  tables)
             ver = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, k+1]
             # greedy acceptance: d_i accepted while it matches the target's
             # own greedy continuation; emitted tokens are ver[:, :n_acc]
-            m = (win[:, 1:] == ver[:, :-1]).astype(jnp.int32)
-            n_acc = 1 + jnp.cumprod(m, axis=1).sum(axis=1)
-            return kc, vc, dkc, dvc, ver, n_acc.astype(jnp.int32)
+            acc = (win[:, 1:] == ver[:, :-1]).astype(jnp.int32)
+            n_acc = 1 + jnp.cumprod(acc, axis=1).sum(axis=1)
+            return (*arenas, *darenas, ver, n_acc.astype(jnp.int32))
 
-        def _prefill_blocked(ks):
-            # [L, 1, H, Tb, hd] -> [L, Tb//bT, bT, H*hd] for the arena layout
-            x = jnp.transpose(ks[:, 0], (0, 2, 1, 3))
-            L_, Tb, H_, hd_ = x.shape
-            return x.reshape(L_, Tb // bT, bT, H_ * hd_)
+        def _store(family, params, arenas, dest_blocks, tokens, length):
+            """Prefill through ``family``: (arenas with the prompt's rows in
+            their blocks, the last live hidden state)."""
+            last, rows = family.prefill(params, tokens, length)
+            arenas = tuple(
+                _write_blocks(a, dest_blocks, r.reshape(
+                    r.shape[0], r.shape[1] // bT, bT, r.shape[2]))
+                for a, r in zip(arenas, rows))
+            return arenas, last
 
-        def _prefill(params, kc, vc, dest_blocks, tokens, length):
+        def _prefill(params, *args):
             self.prefill_traces += 1
-            h, ks, vs = prefill_forward(params, tokens, cfg)
-            kc = _write_blocks(kc, dest_blocks, _prefill_blocked(ks))
-            vc = _write_blocks(vc, dest_blocks, _prefill_blocked(vs))
-            last = h[0, length - 1]
-            logits = mlm_head(params, last[None], cfg)[0]
-            return kc, vc, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            arenas, last = _store(fam, params, args[:n], *args[n:])
+            logits = fam.head(params, last[None])[0]
+            return (*arenas, jnp.argmax(logits, axis=-1).astype(jnp.int32))
 
-        def _prefill_spec(params, dparams, kc, vc, dkc, dvc, dest_blocks,
-                          tokens, length):
+        def _prefill_spec(params, dparams, *args):
             self.prefill_traces += 1
-            h, ks, vs = prefill_forward(params, tokens, cfg)
-            kc = _write_blocks(kc, dest_blocks, _prefill_blocked(ks))
-            vc = _write_blocks(vc, dest_blocks, _prefill_blocked(vs))
-            _, dks, dvs = prefill_forward(dparams, tokens, draft_cfg)
-            dkc = _write_blocks(dkc, dest_blocks, _prefill_blocked(dks))
-            dvc = _write_blocks(dvc, dest_blocks, _prefill_blocked(dvs))
-            last = h[0, length - 1]
-            logits = mlm_head(params, last[None], cfg)[0]
-            return kc, vc, dkc, dvc, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            arenas, last = _store(fam, params, args[:n], *args[n + m:])
+            darenas, _ = _store(dfam, dparams, args[n:n + m], *args[n + m:])
+            logits = fam.head(params, last[None])[0]
+            return (*arenas, *darenas,
+                    jnp.argmax(logits, axis=-1).astype(jnp.int32))
 
-        def _copy_block(a, src, dst):
-            blk = jax.lax.dynamic_slice_in_dim(a, src, 1, axis=1)
-            return jax.lax.dynamic_update_slice_in_dim(a, blk, dst, axis=1)
+        def _copy(*args):
+            """Block ``src`` to block ``dst`` in every arena, in place."""
+            *arenas, src, dst = args
 
-        def _copy(kc, vc, src, dst):
-            return _copy_block(kc, src, dst), _copy_block(vc, src, dst)
+            def one(a):
+                blk = jax.lax.dynamic_slice_in_dim(a, src, 1, axis=1)
+                return jax.lax.dynamic_update_slice_in_dim(a, blk, dst, axis=1)
 
-        def _copy_spec(kc, vc, dkc, dvc, src, dst):
-            return (_copy_block(kc, src, dst), _copy_block(vc, src, dst),
-                    _copy_block(dkc, src, dst), _copy_block(dvc, src, dst))
+            return tuple(one(a) for a in arenas)
 
         # arena buffers are donated and every program above returns the buffer
         # it was given, updated: a step moves the bytes it writes, not the arena
         if spec:
-            self._decode_fn = jax.jit(_spec, donate_argnums=(2, 3, 4, 5))
-            self._prefill_fn = jax.jit(_prefill_spec, donate_argnums=(2, 3, 4, 5))
-            self._copy_fn = jax.jit(_copy_spec, donate_argnums=(0, 1, 2, 3))
+            donated = tuple(range(2, 2 + n + m))
+            self._decode_fn = jax.jit(_spec, donate_argnums=donated)
+            self._prefill_fn = jax.jit(_prefill_spec, donate_argnums=donated)
         else:
-            self._decode_fn = jax.jit(_decode, donate_argnums=(1, 2))
-            self._prefill_fn = jax.jit(_prefill, donate_argnums=(1, 2))
-            self._copy_fn = jax.jit(_copy, donate_argnums=(0, 1))
+            donated = tuple(range(1, 1 + n))
+            self._decode_fn = jax.jit(_decode, donate_argnums=donated)
+            self._prefill_fn = jax.jit(_prefill, donate_argnums=donated)
+        self._copy_fn = jax.jit(_copy, donate_argnums=tuple(range(n + m)))
 
-    def _new_arena(self, cfg: TransformerConfig):
-        shape = (cfg.n_layers, self.n_blocks, self.block_T,
-                 cfg.n_heads * cfg.head_dim)
-        return (jnp.zeros(shape, cfg.compute_dtype),
-                jnp.zeros(shape, cfg.compute_dtype))
+    def _new_arena(self, cfg):
+        """Zeroed arenas for ``cfg``'s family: one a cache width."""
+        fam = cfg.decode_family()
+        return tuple(jnp.zeros((fam.n_layers, self.n_blocks, self.block_T, w),
+                               fam.cache_dtype) for w in fam.cache_widths)
+
+    # the K and V arenas of a family that has them, by their old names
+    @property
+    def _kc(self):
+        return self._arenas[0]
+
+    @_kc.setter
+    def _kc(self, value):
+        self._arenas = (value, *self._arenas[1:])
+
+    @property
+    def _vc(self):
+        return self._arenas[1]
+
+    @_vc.setter
+    def _vc(self, value):
+        self._arenas = (self._arenas[0], value, *self._arenas[2:])
+
+    def _set_arenas(self, arenas) -> None:
+        n = len(self._arenas)
+        self._arenas, self._draft_arenas = tuple(arenas[:n]), tuple(arenas[n:])
+
+    def _run(self, program, *args):
+        """Call a donated program on the parameters and arenas the pool
+        holds, keep the arenas it returns, return the rest of its results."""
+        params = ((self.params,) if self.draft_cfg is None
+                  else (self.params, self.draft_params))
+        out = program(*params, *self._arenas, *self._draft_arenas, *args)
+        held = len(self._arenas) + len(self._draft_arenas)
+        self._set_arenas(out[:held])
+        return out[held:]
 
     # -- capacity ----------------------------------------------------------
 
@@ -459,9 +558,18 @@ class PagedDecodeSlotPool:
         two cumulative counts a step: ``kv_blocks_read`` (what the attention
         kernel is asked to visit: over live slots, the blocks up to the
         window's last position) and ``kv_blocks_mapped`` (``slots x
-        max_blocks``: what a dense gather through the tables would visit)."""
+        max_blocks``: what a dense gather through the tables would visit).
+        ``kv_cache_bytes_per_token`` is what one token stores over all layers
+        and arenas. A family whose steps count (``stat_names``) adds what it
+        makes of the running sums (``cumulative_stats``: the latent family's
+        ``moe_*`` counters); for another family they are absent."""
         rc = self._alloc.refcount[1:]  # trash block is bookkeeping, not capacity
+        fam = self.family
         return {
+            **fam.cumulative_stats(self.family_stats, self.family_steps),
+            "kv_cache_bytes_per_token": int(
+                fam.n_layers * sum(fam.cache_widths)
+                * jnp.dtype(fam.cache_dtype).itemsize),
             "blocks_total": self.total_blocks,
             "blocks_free": self._alloc.free_blocks,
             "cow_shared_blocks": int((rc > 1).sum()),
@@ -573,15 +681,7 @@ class PagedDecodeSlotPool:
         try:
             with span("kv.prefill", bucket=bucket,
                       shared_blocks=len(shared_set), new_blocks=len(new_blocks)):
-                if self.draft_cfg is not None:
-                    self._kc, self._vc, self._dkc, self._dvc, first = \
-                        self._prefill_fn(self.params, self.draft_params,
-                                         self._kc, self._vc, self._dkc,
-                                         self._dvc, dest, padded, np.int32(n))
-                else:
-                    self._kc, self._vc, first = self._prefill_fn(
-                        self.params, self._kc, self._vc, dest, padded,
-                        np.int32(n))
+                first = self._run(self._prefill_fn, dest, padded, np.int32(n))[0]
                 with span("kv.prefill.fetch"):
                     first = int(first)  # the host waits for the prefill here
         except Exception as e:
@@ -629,13 +729,9 @@ class PagedDecodeSlotPool:
                 self._alloc.reserved -= 1
             new = self._alloc.alloc(1)[0]
             try:
-                if self.draft_cfg is not None:
-                    self._kc, self._vc, self._dkc, self._dvc = self._copy_fn(
-                        self._kc, self._vc, self._dkc, self._dvc,
-                        np.int32(old), np.int32(new))
-                else:
-                    self._kc, self._vc = self._copy_fn(
-                        self._kc, self._vc, np.int32(old), np.int32(new))
+                self._set_arenas(self._copy_fn(
+                    *self._arenas, *self._draft_arenas, np.int32(old),
+                    np.int32(new)))
             except Exception as e:
                 self._reset_after_failure()
                 raise KvCacheLostError(
@@ -675,24 +771,18 @@ class PagedDecodeSlotPool:
         self.kv_blocks_mapped += mapped_blocks
         out: Dict[int, List[int]] = {}
         try:
+            # a step's own routing is known when its tokens come back: the
+            # span carries the counters of the step fetched last
             with span("kv.step.dispatch", live_blocks=live_blocks,
-                      mapped_blocks=mapped_blocks):
-                if self.draft_cfg is not None:
-                    (self._kc, self._vc, self._dkc, self._dvc, ver, n_acc) = \
-                        self._decode_fn(self.params, self.draft_params,
-                                        self._kc, self._vc, self._dkc,
-                                        self._dvc, tables, toks, pos)
-                else:
-                    self._kc, self._vc, nxt = self._decode_fn(
-                        self.params, self._kc, self._vc, tables, toks, pos)
+                      mapped_blocks=mapped_blocks, **self.last_step_stats):
+                results = self._run(self._decode_fn, tables, toks, pos)
             # the one host round trip a step (S4): the device runs the step
             # while the host waits here
             with span("kv.step.fetch") as fetch:
                 if self.draft_cfg is None:
-                    nxt = np.asarray(nxt)
+                    nxt = np.asarray(results[0])
                 else:
-                    ver = np.asarray(ver)
-                    n_acc = np.asarray(n_acc)
+                    ver, n_acc = (np.asarray(r) for r in results)
         except Exception as e:
             self._reset_after_failure()
             raise KvCacheLostError(
@@ -701,6 +791,14 @@ class PagedDecodeSlotPool:
                 f"sequences lost") from e
         self.last_fetch_s = fetch.duration_s
         if self.draft_cfg is None:
+            if self.family.stat_names:
+                nxt, counted = nxt[:self.slots], nxt[self.slots:]
+                self.last_step_stats = {
+                    name: int(v) for name, v in zip(self.family.stat_names,
+                                                    counted)}
+                for name, v in self.last_step_stats.items():
+                    self.family_stats[name] += v
+                self.family_steps += 1
             for slot in live:
                 slot = int(slot)
                 out[slot] = [int(nxt[slot])]
@@ -746,9 +844,9 @@ class PagedDecodeSlotPool:
         allocator (the prefix index dies with the K/V it pointed at), all
         slots free.  In-flight sequences are lost (the caller tells their
         riders); the pool itself keeps serving."""
-        self._kc, self._vc = self._new_arena(self.cfg)
+        self._arenas = tuple(self._new_arena(self.cfg))
         if self.draft_cfg is not None:
-            self._dkc, self._dvc = self._new_arena(self.draft_cfg)
+            self._draft_arenas = tuple(self._new_arena(self.draft_cfg))
         self._alloc = BlockAllocator(self.n_blocks)
         self._tables[:] = 0
         self._active[:] = False

@@ -52,6 +52,12 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    def decode_family(self):
+        """What the paged slot pool runs for this config's layers."""
+        from .paged_decode import TransformerDecodeFamily  # imports this module
+
+        return TransformerDecodeFamily(self)
+
     @staticmethod
     def bert_base(**kw) -> "TransformerConfig":
         return TransformerConfig(**kw)

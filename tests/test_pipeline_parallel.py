@@ -277,13 +277,18 @@ class TestPipelineTrainer:
         # measured per-stage seconds vs the cost model: uniform layers,
         # 3|3 split -> predicted fractions 0.5/0.5; measured must agree
         # within the 15% acceptance bar (compared as fractions so a
-        # loaded CI host's common slowdown divides out)
-        times = trainer.profile_stages(seq=32, batch_size=2, repeats=6)
+        # loaded CI host's common slowdown divides out). Stage times are
+        # 1-2 ms of CPU wall clock beside five other test workers: one
+        # preempted repeat is not a wrong cost model, so measure up to
+        # three times
         pred = trainer.predicted_stage_costs()
-        m_frac = [t / sum(times) for t in times]
         p_frac = [c / sum(pred) for c in pred]
-        for m, p in zip(m_frac, p_frac):
-            assert abs(m - p) / p <= 0.15, (times, pred)
+        for _ in range(3):
+            times = trainer.profile_stages(seq=32, batch_size=2, repeats=6)
+            off = max(abs(t / sum(times) - p) / p for t, p in zip(times, p_frac))
+            if off <= 0.15:
+                break
+        assert off <= 0.15, (times, pred)
 
         # balanced timings -> no rebalance
         assert trainer.maybe_rebalance([1.0, 1.0]) is None

@@ -288,6 +288,30 @@ def test_served_path_span_names_are_the_declared_vocabulary():
     assert not missing, f"not tabled in docs/OBSERVABILITY.md: {missing}"
 
 
+def test_a_steps_routing_counters_ride_the_dispatch_span_and_are_tabled():
+    """ISSUE 31: ``kv.step.dispatch`` carries what the family's last fetched
+    step counted (``last_step_stats``: for the kimi_k2 family its routing),
+    and the table of spans names every such stat in that span's row."""
+    from deeplearning4j_tpu.models.kimi_k2 import MOE_STATS
+
+    path = os.path.join(ROOT, "deeplearning4j_tpu", "models", "paged_decode.py")
+    dispatch = [node for node in ast.walk(ast.parse(open(path).read(), path))
+                if isinstance(node, ast.Call) and isinstance(node.func, ast.Name)
+                and node.func.id == "span" and node.args
+                and node.args[0].value == "kv.step.dispatch"]
+    assert len(dispatch) == 1
+    named = {kw.arg for kw in dispatch[0].keywords if kw.arg}
+    spread = [kw.value for kw in dispatch[0].keywords if kw.arg is None]
+    assert named == {"live_blocks", "mapped_blocks"}
+    assert [ast.unparse(v) for v in spread] == ["self.last_step_stats"]
+    doc = open(os.path.join(ROOT, "docs", "OBSERVABILITY.md")).read()
+    row = next(line for line in doc.splitlines()
+               if line.startswith("| `kv.step.dispatch`"))
+    assert {"routed_tokens", "resident_assignments", "experts_touched"} <= set(MOE_STATS)
+    missing = [n for n in MOE_STATS if f"`{n}`" not in row]
+    assert not missing, f"not in the span's row of docs/OBSERVABILITY.md: {missing}"
+
+
 # -- the readers under benchmark/metrics/ ------------------------------------
 
 

@@ -159,3 +159,83 @@ def test_paged_attention_kernel_compiles_for_the_chip(one_chip, on_chip_path, W)
                         _shape(one_chip, (SLOTS, W), jnp.int32)).compile()
     assert not _arena_ops(compiled.as_text())  # the arenas are read where they lie
     assert "paged_decode_attn" in compiled.as_text()
+
+
+# -- the kimi_k2 family at Kimi-K2.5's published widths (ISSUE 31) ------------
+#
+# benchmark/configs/kimi-k2-5.json and benchmark/traffic/agent-decode.json:
+# 64 slots x 5120 positions in blocks of 32, 12 resident experts of 384, an
+# eighth of the vocabulary; the depth is cut to 1 dense + 1 sparse layer for
+# the test's time. A cached row is 576 values in 640 lanes (five whole
+# 128-lane tiles: the chip's DMA engine moves no half tile).
+
+K2_SLOTS, K2_MAX_LEN, K2_LAYERS, K2_LANES = 64, 5120, 2, 640
+K2_MAX_BLOCKS = K2_MAX_LEN // BLOCK_T
+K2_N_BLOCKS = 1 + K2_SLOTS * K2_MAX_BLOCKS
+K2_ARENA = (K2_LAYERS, K2_N_BLOCKS, BLOCK_T, K2_LANES)
+
+
+@pytest.fixture(scope="module")
+def latent_pool_and_params(one_chip):
+    from deeplearning4j_tpu.models import kimi_k2 as k2
+
+    cfg = k2.KimiK2Config(vocab_size=20480, num_hidden_layers=K2_LAYERS,
+                          n_resident_experts=12, max_position_embeddings=K2_MAX_LEN)
+    assert cfg.cache_width == 576 and cfg.arena_width == K2_LANES
+    shapes = jax.eval_shape(lambda: k2.init_params(jax.random.key(0), cfg))
+    params = jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), shapes)
+    mp = pytest.MonkeyPatch()
+    mp.setattr(PagedDecodeSlotPool, "_new_arena", lambda self, cfg: (None,))
+    try:
+        pool = PagedDecodeSlotPool(shapes, cfg, slots=K2_SLOTS, block_T=BLOCK_T,
+                                   max_len=K2_MAX_LEN)
+    finally:
+        mp.undo()
+    assert pool.n_blocks == K2_N_BLOCKS and pool.max_blocks == K2_MAX_BLOCKS
+    return pool, params
+
+
+def _assert_latent_arena_in_place(lowered, compiled):
+    arena_type = "tensor<" + "x".join(str(d) for d in K2_ARENA) + "xbf16>"
+    tied = re.findall(re.escape(arena_type) + r" \{[^%]*?tf\.aliasing_output = (\d+)",
+                      lowered.as_text())
+    assert len(tied) == 1, tied
+    arena_bytes = 2 * K2_LAYERS * K2_N_BLOCKS * BLOCK_T * K2_LANES
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= arena_bytes
+    # nothing of an arena's size is made beside it: a layer of it is 0.42 GB
+    assert mem.temp_size_in_bytes < arena_bytes // K2_LAYERS
+    entry = compiled.as_text()
+    entry = entry[entry.index("\nENTRY "):]
+    copies = re.findall(r"= bf16\[(?:%d,)?%d,%d,%d\]\S* copy\(" % (
+        K2_LAYERS, K2_N_BLOCKS, BLOCK_T, K2_LANES), entry)
+    assert not copies, copies
+
+
+def test_latent_decode_program_compiles_for_the_chip_with_the_arena_in_place(
+        one_chip, on_chip_path, latent_pool_and_params):
+    pool, params = latent_pool_and_params
+    lowered = pool._decode_fn.lower(
+        params, _shape(one_chip, K2_ARENA, jnp.bfloat16),
+        _shape(one_chip, (K2_SLOTS, K2_MAX_BLOCKS), jnp.int32),
+        _shape(one_chip, (K2_SLOTS,), jnp.int32),
+        _shape(one_chip, (K2_SLOTS,), jnp.int32))
+    compiled = lowered.compile()  # a Mosaic error would be raised here
+    _assert_latent_arena_in_place(lowered, compiled)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == K2_LAYERS
+    assert "paged_mla_decode_attn" in text
+
+
+def test_latent_prefill_program_compiles_for_the_chip_with_the_arena_in_place(
+        one_chip, on_chip_path, latent_pool_and_params):
+    """The 1024 bucket (the cell's median prompt): flash attention with
+    192-wide q and k, the expert rows in tiles, the rows stored in place."""
+    pool, params = latent_pool_and_params
+    lowered = pool._prefill_fn.lower(
+        params, _shape(one_chip, K2_ARENA, jnp.bfloat16),
+        _shape(one_chip, (1024 // BLOCK_T,), jnp.int32),
+        _shape(one_chip, (1, 1024), jnp.int32), _shape(one_chip, (), jnp.int32))
+    compiled = lowered.compile()
+    _assert_latent_arena_in_place(lowered, compiled)
+    assert "flash_fwd" in compiled.as_text()
