@@ -435,7 +435,7 @@ def phase_kernels(sizes, on_tpu: bool):
         cases[name] = dict(max_rel_err=round(max(errs), 5),
                            fwd_bwd_s=round(wall, 4))
 
-    # long T: resolve_blocks switches to (512, 1024); compiled + finite
+    # long T: the static table answers 1024 x 1024 blocks; compiled + finite
     B, H, T, D = sizes["kernels"]["long"]
     q, k, v, ct = qkv(B, H, T, T, 99)
     flash = fwd_bwd(lambda q, k, v, m: flash_attention(

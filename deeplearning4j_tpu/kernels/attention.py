@@ -60,17 +60,57 @@ def mha_reference(q, k, v, mask=None, *, causal: bool = False, scale: Optional[f
 
 
 def _seg_mask(s, qseg, kseg):
-    """Apply segment-id masking to a [bq, bk] score block.
+    """Apply segment-id masking to a score block — attend iff equal.
 
-    qseg: [bq, 1] int32, kseg: [1, bk] int32 — attend iff equal."""
+    One of qseg / kseg is a column ([n, 1], ids along the block's rows), the
+    other a row ([1, n]), int32."""
     return jnp.where(qseg == kseg, s, _NEG_INF)
+
+
+def _dot(a, b, contract):
+    """One MXU matmul: operands in the dtype they arrive in (bf16 blocks go
+    in as bf16, the product of two bf16 numbers is exact in float32),
+    accumulated in float32."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))  # a @ b.T
+_NN = ((1,), (0,))  # a @ b
+
+
+def _dot_f32(p, x, contract):
+    """A matmul whose left operand is a float32 block the kernel computed (P
+    or dS): the loaded block ``x`` [T, D] is cast up to meet it. The MXU path
+    rounds a float32 operand to bf16 on its way in, in one pass (measured on
+    a v5e: the product equals that of the bf16-rounded operands to 7e-8), so
+    this is the arithmetic of casting P down, without the pass of the VPU
+    over a [bq, bk] block that the cast would cost."""
+    return _dot(p, x.astype(p.dtype), contract)
+
+
+def _block_live(qb_id, kb_id, block_q, block_k, q_offset):
+    """False iff the causal mask zeroes the whole (q-block, k-block) pair —
+    those blocks are skipped, forward and backward: about half the FLOPs
+    where the blocks are small beside the sequence."""
+    return q_offset + (qb_id + 1) * block_q - 1 >= kb_id * block_k
+
+
+def _causal_mask(s, q0, k0, q_dim):
+    """Mask a score block whose first query sits at position ``q0`` and first
+    key at ``k0``; queries run along ``q_dim`` of ``s``."""
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_dim)
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_dim)
+    return jnp.where(qpos >= kpos, s, _NEG_INF)
 
 
 def _flash_kernel(*refs, scale, causal, block_q, block_k, num_k, q_offset, has_mask):
     """One (q-block, k-block) grid step of online-softmax flash attention.
 
     TPU grid iterates the LAST axis sequentially, so scratch (m/l/acc)
-    persists across the k-block sweep for a fixed q-block.
+    persists across the k-block sweep for a fixed q-block. Q, K and V go
+    into the MXU in the dtype they arrive in; the statistics (m, l, the
+    accumulator, the log-sum-exp, the ``exp``) are float32 whatever that is.
     """
     if has_mask:
         (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
@@ -78,7 +118,28 @@ def _flash_kernel(*refs, scale, causal, block_q, block_k, num_k, q_offset, has_m
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
         qseg_ref = kseg_ref = None
-    kb = pl.program_id(2)
+    qb, kb = pl.program_id(1), pl.program_id(2)
+
+    def _scores():
+        s = _dot(q_ref[0], k_ref[0], _NT) * scale  # [bq, bk]
+        if causal:
+            # q_offset aligns query positions to the END of the key axis when
+            # Tq != Tk (decode-with-prefix), matching mha_reference
+            s = _causal_mask(s, q_offset + qb * block_q, kb * block_k, 0)
+        if has_mask:
+            s = _seg_mask(s, qseg_ref[0], kseg_ref[0])
+        return s
+
+    if num_k == 1:
+        # the whole key axis in one block: a plain softmax, no running state
+        # (a third off the forward at T 512: PERF.md section 6, PR 32)
+        s = _scores()
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        o_ref[0] = (_dot_f32(p, v_ref[0], _NN) / l).astype(o_ref.dtype)
+        lse_ref[0] = m + jnp.log(l)
+        return
 
     @pl.when(kb == 0)
     def _init():
@@ -86,30 +147,23 @@ def _flash_kernel(*refs, scale, causal, block_q, block_k, num_k, q_offset, has_m
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)  # [block_q, D]
-    k = k_ref[0].astype(jnp.float32)  # [block_k, D]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-    if causal:
-        qb = pl.program_id(1)
-        # q_offset aligns query positions to the END of the key axis when
-        # Tq != Tk (decode-with-prefix), matching mha_reference
-        qpos = q_offset + qb * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kpos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(qpos >= kpos, s, _NEG_INF)
-    if has_mask:
-        s = _seg_mask(s, qseg_ref[0], kseg_ref[0])
+    def _accumulate():
+        s = _scores()
+        m_prev = m_ref[:]          # [bq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)     # [bq, bk]
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * corr + _dot_f32(p, v_ref[0], _NN)
+        m_ref[:] = m_new
 
-    m_prev = m_ref[:]          # [bq, 1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)     # [bq, bk]
-    l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-        p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[:] = m_new
+    if causal:
+        # the first key block always runs, so a q-block with no live key at
+        # all (Tq > Tk) still ends with l > 0; flash_attention then gives its
+        # rows the reference's uniform answer
+        pl.when((kb == 0) | _block_live(qb, kb, block_q, block_k, q_offset))(_accumulate)
+    else:
+        _accumulate()
 
     @pl.when(kb == num_k - 1)
     def _fin():
@@ -117,14 +171,12 @@ def _flash_kernel(*refs, scale, causal, block_q, block_k, num_k, q_offset, has_m
         lse_ref[0] = m_ref[:] + jnp.log(l_ref[:])
 
 
-def _mask_specs(H, block_q, block_k, *, q_ix, k_ix):
-    """BlockSpecs for qseg [B,Tq,1] / kseg [B,1,Tk] on a (B*H, …) grid.
-
-    ``q_ix``/``k_ix`` pick which grid axis sweeps the q-/k-blocks (the two
-    backward kernels iterate them in opposite orders)."""
+def _mask_specs(H, block_q, block_k):
+    """BlockSpecs for qseg [B,Tq,1] / kseg [B,1,Tk] on a (B*H, q-blocks,
+    k-blocks) grid."""
     return [
-        pl.BlockSpec((1, block_q, 1), lambda b, i, j, _f=q_ix: (b // H, _f(i, j), 0)),
-        pl.BlockSpec((1, 1, block_k), lambda b, i, j, _f=k_ix: (b // H, 0, _f(i, j))),
+        pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b // H, i, 0)),
+        pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b // H, 0, j)),
     ]
 
 
@@ -147,8 +199,7 @@ def _flash_forward(q, k, v, qseg, kseg, causal, scale, block_q, block_k, interpr
     ]
     if has_mask:
         args += [qseg[:, :, None], kseg[:, None, :]]
-        in_specs += _mask_specs(H, block_q, block_k,
-                                q_ix=lambda i, j: i, k_ix=lambda i, j: j)
+        in_specs += _mask_specs(H, block_q, block_k)
 
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, has_mask=has_mask,
@@ -176,42 +227,30 @@ def _flash_forward(q, k, v, qseg, kseg, causal, scale, block_q, block_k, interpr
     return out.reshape(B, H, Tq, D), lse.reshape(B, H, Tq, 1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _flash_attention(q, k, v, qseg, kseg, causal, scale, block_q, block_k, interpret, q_offset):
-    out, _ = _flash_fwd(q, k, v, qseg, kseg, causal, scale, block_q, block_k,
-                        interpret, q_offset)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_attention(q, k, v, qseg, kseg, causal, scale, blocks, interpret, q_offset):
+    """``blocks``: the (block_q, block_k) of ``flash_fwd``, ``flash_bwd_dkv``
+    and ``flash_bwd_dq``, in that order."""
+    out, _ = _flash_fwd(q, k, v, qseg, kseg, causal, scale, blocks, interpret,
+                        q_offset)
     return out
 
 
-def _flash_fwd(q, k, v, qseg, kseg, causal, scale, block_q, block_k, interpret, q_offset):
-    out, lse = _flash_forward(q, k, v, qseg, kseg, causal, scale, block_q,
-                              block_k, interpret, q_offset)
+def _flash_fwd(q, k, v, qseg, kseg, causal, scale, blocks, interpret, q_offset):
+    out, lse = _flash_forward(q, k, v, qseg, kseg, causal, scale, *blocks[0],
+                              interpret, q_offset)
     return out, (q, k, v, qseg, kseg, out, lse)
 
 
-def _bwd_scores(q, k, lse, scale, causal, qb_id, kb_id, block_q, block_k, q_offset,
-                qseg=None, kseg=None):
-    """Recompute one [bq, bk] prob block from saved LSE (FlashAttention-2:
-    never materialize [T,T] — each block is rebuilt in VMEM on demand)."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if causal:
-        qpos = q_offset + qb_id * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kpos = kb_id * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(qpos >= kpos, s, _NEG_INF)
-    if qseg is not None:
-        s = _seg_mask(s, qseg, kseg)
-    return jnp.exp(s - lse)
-
-
-def _block_live(qb_id, kb_id, block_q, block_k, q_offset):
-    """False iff the causal mask zeroes the whole (q-block, k-block) pair —
-    those blocks are skipped, saving ~half the backward FLOPs at long T."""
-    return q_offset + (qb_id + 1) * block_q - 1 >= kb_id * block_k
-
-
 def _flash_bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, num_q, q_offset, has_mask):
-    """Fixed k-block, sweep q-blocks (grid last axis): accumulate dK, dV."""
+    """Fixed k-block, sweep q-blocks (grid last axis): accumulate dK, dV.
+
+    The block of scores is held TRANSPOSED, [bk, bq], rebuilt from the saved
+    log-sum-exp (FlashAttention-2: [T,T] never materializes), so that dV =
+    P^T dO and dK = dS^T Q are plain row-by-column matmuls (a tenth off the
+    kernel against contracting over the left operand's rows); the per-query
+    vectors (lse, delta, qseg) therefore come in lane-dense, as [1, bq], and
+    kseg as a column."""
     if has_mask:
         (q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, qseg_ref, kseg_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
@@ -227,22 +266,18 @@ def _flash_bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, num_q, q_offse
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32)      # [bq, D]
-        k = k_ref[0].astype(jnp.float32)      # [bk, D]
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)    # [bq, D]
-        p = _bwd_scores(q, k, lse_ref[0], scale, causal,
-                        qb, kb, block_q, block_k, q_offset,
-                        None if qseg_ref is None else qseg_ref[0],
-                        None if kseg_ref is None else kseg_ref[0])
+        q, do, k, v = q_ref[0], do_ref[0], k_ref[0], v_ref[0]
+        lse, delta = lse_ref[0], delta_ref[0]  # [1, bq]
+        st = _dot(k, q, _NT) * scale           # [bk, bq]
+        if causal:
+            st = _causal_mask(st, q_offset + qb * block_q, kb * block_k, 1)
+        if has_mask:
+            st = _seg_mask(st, qseg_ref[0], kseg_ref[0])
+        pt = jnp.exp(st - lse)
         # dV += P^T dO ; dS = P * (dO V^T - delta) * scale ; dK += dS^T Q
-        dv_acc[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0]) * scale
-        dk_acc[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+        dv_acc[:] += _dot_f32(pt, do, _NN)
+        dst = pt * (_dot(v, do, _NT) - delta) * scale
+        dk_acc[:] += _dot_f32(dst, q, _NN)
 
     if causal:
         pl.when(_block_live(qb, kb, block_q, block_k, q_offset))(_accumulate)
@@ -270,19 +305,15 @@ def _flash_bwd_dq_kernel(*refs, scale, causal, block_q, block_k, num_k, q_offset
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        p = _bwd_scores(q, k, lse_ref[0], scale, causal,
-                        qb, kb, block_q, block_k, q_offset,
-                        None if qseg_ref is None else qseg_ref[0],
-                        None if kseg_ref is None else kseg_ref[0])
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0]) * scale
-        dq_acc[:] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+        q, do, k, v = q_ref[0], do_ref[0], k_ref[0], v_ref[0]
+        s = _dot(q, k, _NT) * scale            # [bq, bk]
+        if causal:
+            s = _causal_mask(s, q_offset + qb * block_q, kb * block_k, 0)
+        if has_mask:
+            s = _seg_mask(s, qseg_ref[0], kseg_ref[0])
+        p = jnp.exp(s - lse_ref[0])
+        ds = p * (_dot(do, v, _NT) - delta_ref[0]) * scale
+        dq_acc[:] += _dot_f32(ds, k, _NN)
 
     if causal:
         pl.when(_block_live(qb, kb, block_q, block_k, q_offset))(_accumulate)
@@ -294,49 +325,33 @@ def _flash_bwd_dq_kernel(*refs, scale, causal, block_q, block_k, num_k, q_offset
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, q_offset, res, do):
-    """Blockwise Pallas backward: O(T) memory (VERDICT r2 weak #1 — the dense
-    [B,H,T,T] reconstruction is gone; each prob block is recomputed in VMEM
-    from the saved LSE)."""
-    q, k, v, qseg, kseg, out, lse = res
+def _flash_bwd_dkv(q, k, v, qseg, kseg, do, lse, delta, causal, scale,
+                   bq, bk, interpret, q_offset):
+    """dK, dV [B,H,Tk,D] from the saved log-sum-exp and ``delta`` [B,H,Tq]."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    bq, bk = block_q, block_k
-    num_q, num_k = Tq // bq, Tk // bk
-    has_mask = qseg is not None
-
-    qr, dor = q.reshape(B * H, Tq, D), do.reshape(B * H, Tq, D)
-    kr, vr = k.reshape(B * H, Tk, D), v.reshape(B * H, Tk, D)
-    lser = lse.reshape(B * H, Tq, 1)
-    # delta_i = rowsum(dO_i * O_i) — one cheap fused elementwise+reduce
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True).reshape(B * H, Tq, 1)
-
-    args = [qr, dor, lser, delta, kr, vr]
-    dkv_in_specs = [
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, j, 0)),   # q
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, j, 0)),   # do
-        pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, j, 0)),   # lse
-        pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, j, 0)),   # delta
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, i, 0)),   # k
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, i, 0)),   # v
-    ]
-    if has_mask:
-        args += [qseg[:, :, None], kseg[:, None, :]]
-        dkv_in_specs += _mask_specs(H, bq, bk,
-                                    q_ix=lambda i, j: j, k_ix=lambda i, j: i)
-
-    dkv_kernel = functools.partial(
-        _flash_bwd_dkv_kernel, scale=scale, causal=causal, has_mask=has_mask,
-        block_q=bq, block_k=bk, num_q=num_q, q_offset=q_offset)
+    q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, j, 0))
+    k_spec = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, i, 0))
+    row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, j))
+    args = [q.reshape(B * H, Tq, D), do.reshape(B * H, Tq, D),
+            lse.reshape(B * H, 1, Tq), delta.reshape(B * H, 1, Tq),
+            k.reshape(B * H, Tk, D), v.reshape(B * H, Tk, D)]
+    in_specs = [q_spec, q_spec, row_spec, row_spec, k_spec, k_spec]
+    if qseg is not None:
+        args += [qseg[:, None, :], kseg[:, :, None]]
+        in_specs += [
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b // H, 0, j)),
+            pl.BlockSpec((1, bk, 1), lambda b, i, j: (b // H, i, 0)),
+        ]
+    kernel = functools.partial(
+        _flash_bwd_dkv_kernel, scale=scale, causal=causal,
+        has_mask=qseg is not None, block_q=bq, block_k=bk, num_q=Tq // bq,
+        q_offset=q_offset)
     dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(B * H, num_k, num_q),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, i, 0)),
-        ],
+        kernel,
+        grid=(B * H, Tk // bk, Tq // bq),
+        in_specs=in_specs,
+        out_specs=[k_spec, k_spec],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Tk, D), k.dtype),
             jax.ShapeDtypeStruct((B * H, Tk, D), v.dtype),
@@ -348,35 +363,52 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, q_offset, res, do):
         interpret=interpret,
         name="flash_bwd_dkv",
     )(*args)
+    return dk.reshape(B, H, Tk, D), dv.reshape(B, H, Tk, D)
 
-    dq_in_specs = [
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),   # q
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),   # do
-        pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),   # lse
-        pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),   # delta
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),   # k
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),   # v
-    ]
-    if has_mask:
-        dq_in_specs += _mask_specs(H, bq, bk,
-                                   q_ix=lambda i, j: i, k_ix=lambda i, j: j)
 
-    dq_kernel = functools.partial(
-        _flash_bwd_dq_kernel, scale=scale, causal=causal, has_mask=has_mask,
-        block_q=bq, block_k=bk, num_k=num_k, q_offset=q_offset)
+def _flash_bwd_dq(q, k, v, qseg, kseg, do, lse, delta, causal, scale,
+                  bq, bk, interpret, q_offset):
+    """dQ [B,H,Tq,D] from the saved log-sum-exp and ``delta`` [B,H,Tq]."""
+    B, H, Tq, D = q.shape
+    Tk = k.shape[2]
+    q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
+    k_spec = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0))
+    col_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
+    args = [q.reshape(B * H, Tq, D), do.reshape(B * H, Tq, D),
+            lse.reshape(B * H, Tq, 1), delta.reshape(B * H, Tq, 1),
+            k.reshape(B * H, Tk, D), v.reshape(B * H, Tk, D)]
+    in_specs = [q_spec, q_spec, col_spec, col_spec, k_spec, k_spec]
+    if qseg is not None:
+        args += [qseg[:, :, None], kseg[:, None, :]]
+        in_specs += _mask_specs(H, bq, bk)
+    kernel = functools.partial(
+        _flash_bwd_dq_kernel, scale=scale, causal=causal,
+        has_mask=qseg is not None, block_q=bq, block_k=bk, num_k=Tk // bk,
+        q_offset=q_offset)
     (dq,) = pl.pallas_call(
-        dq_kernel,
-        grid=(B * H, num_q, num_k),
-        in_specs=dq_in_specs,
-        out_specs=[pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))],
+        kernel,
+        grid=(B * H, Tq // bq, Tk // bk),
+        in_specs=in_specs,
+        out_specs=[q_spec],
         out_shape=[jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype)],
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
     )(*args)
+    return dq.reshape(B, H, Tq, D)
 
-    return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
-            dv.reshape(B, H, Tk, D), None, None)
+
+def _flash_bwd(causal, scale, blocks, interpret, q_offset, res, do):
+    """Blockwise Pallas backward: O(T) memory (VERDICT r2 weak #1 — the dense
+    [B,H,T,T] reconstruction is gone; each prob block is recomputed in VMEM
+    from the saved LSE). Two kernels, each with the block the table gives it."""
+    q, k, v, qseg, kseg, out, lse = res
+    # delta_i = rowsum(dO_i * O_i) — one cheap fused elementwise+reduce
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    common = (q, k, v, qseg, kseg, do, lse, delta, causal, scale)
+    dk, dv = _flash_bwd_dkv(*common, *blocks[1], interpret, q_offset)
+    dq = _flash_bwd_dq(*common, *blocks[2], interpret, q_offset)
+    return dq, dk, dv, None, None
 
 
 def _flash_bwd_dense(causal, scale, res, do):
@@ -432,10 +464,13 @@ def flash_attention(q, k, v, mask=None, *, segment_ids=None, causal: bool = Fals
     to id -1. Sequence lengths need NOT be multiples of the block size — a
     pad shim rounds them up and masks the padding out (VERDICT r4 weak #2:
     no more silent fallback for masked or odd-length batches). Block sizes
-    come from the persistent autotune table when it holds a measured entry
-    for this (shape-bucket, dtype), else from the hand-measured static
-    table (128² default, (512, 1024) at T ≥ 4096 — the measured long-T
-    sweet spot on v5e; see ``kernels.autotune``, ISSUE 12).
+    left to the call come from ``kernels.autotune``: a persisted measured
+    entry for this (shape-bucket, dtype) where ``TDL_AUTOTUNE_DIR`` holds
+    one, else the table in source, which answers by what the call shows —
+    T_q, T_k, D, causal or not, and which of the three kernels (under a
+    causal mask the backward kernels want smaller blocks than the forward,
+    so that there are dead ones to skip). An explicit ``block_q`` /
+    ``block_k`` goes to all three.
 
     Differentiable via custom_vjp: the forward kernel emits the per-row
     logsumexp; the backward kernels recompute each [bq,bk] prob block in VMEM
@@ -452,17 +487,15 @@ def flash_attention(q, k, v, mask=None, *, segment_ids=None, causal: bool = Fals
         scale = 1.0 / math.sqrt(D)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    blocks = [(block_q, block_k)] * 3
     if block_q is None or block_k is None:
-        # ISSUE 12: a measured per-(op, shape-bucket, dtype) winner from the
-        # persistent autotune table wins; the hand-measured static table
-        # (128² default, coarse (512, 1024) tiles at long T — the grid runs
-        # sequentially per core) answers when nothing was measured yet
-        from .autotune import resolve_blocks
+        from .autotune import FLASH_KERNELS, resolve_blocks
 
-        abq, abk = resolve_blocks("flash_attention", B=B, H=H, Tq=Tq, Tk=Tk,
-                                  D=D, dtype=jnp.dtype(q.dtype).name)
-        block_q = block_q or abq
-        block_k = block_k or abk
+        answers = [resolve_blocks(
+            "flash_attention", B=B, H=H, Tq=Tq, Tk=Tk, D=D,
+            dtype=jnp.dtype(q.dtype).name, causal=causal, kernel=kernel)
+            for kernel in FLASH_KERNELS]
+        blocks = [(block_q or bq, block_k or bk) for bq, bk in answers]
 
     qseg = kseg = None
     if segment_ids is not None:
@@ -482,11 +515,16 @@ def flash_attention(q, k, v, mask=None, *, segment_ids=None, causal: bool = Fals
             qseg = jnp.zeros((B, Tq), jnp.int32)
 
     # ---- pad shim: round Tq/Tk up to block multiples, mask padding out.
-    # In interpret mode blocks may shrink to the sequence length (cheap CPU
-    # tests); on real TPU full 128-blocks keep Mosaic tiling aligned.
-    bq = min(block_q, Tq) if interpret else block_q
-    bk = min(block_k, Tk) if interpret else block_k
-    pad_q, pad_k = (-Tq) % bq, (-Tk) % bk
+    # On real TPU whole 128-blocks keep Mosaic tiling aligned, and the
+    # table's blocks all divide T rounded up to 128, so they pad no further.
+    # In interpret mode an axis that no kernel splits shrinks to the sequence
+    # length (cheap CPU tests).
+    bqs, bks = zip(*blocks)
+    if interpret:
+        bqs = (Tq,) * 3 if min(bqs) >= Tq else bqs
+        bks = (Tk,) * 3 if min(bks) >= Tk else bks
+    blocks = tuple(zip(bqs, bks))
+    pad_q, pad_k = (-Tq) % math.lcm(*bqs), (-Tk) % math.lcm(*bks)
     q_offset = Tk - Tq  # causal alignment in ORIGINAL coordinates
     if pad_k and kseg is None:
         qseg = jnp.zeros((B, Tq), jnp.int32)
@@ -503,18 +541,20 @@ def flash_attention(q, k, v, mask=None, *, segment_ids=None, causal: bool = Fals
         qseg = jnp.pad(qseg, ((0, 0), (0, q.shape[2] - qseg.shape[1])),
                        constant_values=-2)
 
-    out = _flash_attention(q, k, v, qseg, kseg, causal, scale, bq, bk,
+    out = _flash_attention(q, k, v, qseg, kseg, causal, scale, blocks,
                            interpret, q_offset)
     if pad_q:
         out = out[:, :, :Tq]
 
     # Degenerate-row parity (r5 review): a row with ZERO live keys degrades
-    # to a uniform softmax — which must span the ORIGINAL keys, not the shim
-    # padding, to match mha_reference bit-for-bit. Only the padded-keys case
-    # can diverge; correct it for key-padding masks (±causal). Segment-id
-    # batches keep the padded-uniform convention for such rows (documented:
-    # their values are meaningless under either convention).
-    if pad_k and segment_ids is None:
+    # to a uniform softmax — which must span the ORIGINAL keys, all of them,
+    # to match mha_reference bit-for-bit. The kernel's answer for such a row
+    # spans the shim's padding too, and under a causal mask only the key
+    # blocks it did not skip; correct it for key-padding masks (±causal).
+    # Segment-id batches keep the kernel's convention for such rows
+    # (documented: their values are meaningless under either convention).
+    dead_rows = pad_k or (causal and (key_mask is not None or q_offset < 0))
+    if dead_rows and segment_ids is None:
         keep_i = (key_mask.astype(jnp.int32) if key_mask is not None
                   else jnp.ones((B, Tk), jnp.int32))
         v_orig = v[:, :, :Tk]
@@ -666,9 +706,10 @@ def dot_product_attention(q, k, v, mask=None, *, causal=False, scale=None, impl:
     """Front door used by nn layers / the transformer. impl: auto|xla|flash.
 
     auto = flash on TPU for unmasked AND key-padding-masked batches once the
-    sequence reaches one 128-block (the pad shim handles non-multiples
-    above that; below it, padding tiny T up to 128² blocks would cost more
-    than the dense softmax it replaces). Only a full per-query
+    sequence reaches 128 (the pad shim handles non-multiples above that,
+    and the blocks come from ``kernels.autotune``, by T, D, causal or not
+    and kernel; below it, padding a tiny T up to a 128-wide block would cost
+    more than the dense softmax it replaces). Only a full per-query
     [B,1,Tq,Tk] score mask falls back to the dense XLA path. Under an
     ambient mesh the kernel runs per shard (:func:`_flash_per_shard`).
     """

@@ -2,9 +2,10 @@
 
 Acceptance pins:
 - the interpret-mode search is DETERMINISTIC and lands exactly on the
-  hand-measured static table at every BASELINE.md long-context grid point
-  (exact-match acceptable; regression forbidden — on hardware the
-  regression guard keeps a noisy winner from displacing the static entry);
+  static table (measured on a v5e in PR 32, one answer a kernel) at every
+  BASELINE.md long-context grid point (exact-match acceptable; regression
+  forbidden — on hardware the regression guard keeps a noisy winner from
+  displacing the static table);
 - ``flash_attention`` consults a persisted measured entry before the
   static defaults, and the result stays numerically correct;
 - the table round-trips to disk (atomic write, corruption-tolerant read,
@@ -23,7 +24,8 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.kernels import autotune
-from deeplearning4j_tpu.kernels.autotune import (AutotuneTable,
+from deeplearning4j_tpu.kernels.autotune import (FLASH_CANDIDATES,
+                                                 FLASH_KERNELS, AutotuneTable,
                                                  autotune_flash_attention,
                                                  resolve_blocks, shape_key,
                                                  static_flash_blocks)
@@ -34,15 +36,42 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent / "deeplearning4j_tpu"
 # -------------------------------------------------------------- static table
 
 
+def _table(Tq, Tk, **kw):
+    return [static_flash_blocks(Tq, Tk, kernel=kn, **kw) for kn in FLASH_KERNELS]
+
+
 def test_static_table_matches_baseline_grid():
-    """BASELINE.md r5: 128² below T=4096, (512, 1024) at and beyond."""
-    assert static_flash_blocks(128, 128) == (128, 128)
-    assert static_flash_blocks(2048, 2048) == (128, 128)
-    assert static_flash_blocks(4096, 4096) == (512, 1024)
-    assert static_flash_blocks(8192, 8192) == (512, 1024)
-    assert static_flash_blocks(16384, 16384) == (512, 1024)
-    # mixed: the SHORTER side decides (decode-with-prefix shapes)
-    assert static_flash_blocks(128, 8192) == (128, 128)
+    """The table as measured on a v5e in PR 32 (PERF.md section 6), one
+    answer for each of flash_fwd, flash_bwd_dkv, flash_bwd_dq."""
+    # bert-large.mlm-t512: the whole sequence in one block, all three kernels
+    assert _table(512, 512) == [(512, 512)] * 3
+    # gpt2-large.train-dp2tp2: the forward whole, the backward with dead
+    # blocks to skip
+    assert _table(1024, 1024, causal=True) == [(1024, 1024), (512, 512), (512, 512)]
+    assert _table(1024, 1024) == [(1024, 1024)] * 3
+    # long T: 1024 x 1024, but dKV under a causal mask
+    for T in (2048, 4096, 8192, 16384):
+        assert _table(T, T) == [(1024, 1024)] * 3
+        assert _table(T, T, causal=True) == [(1024, 1024), (512, 512), (1024, 1024)]
+    # a block never pads past T rounded up to 128: a 128-token prefill bucket
+    # runs 128-wide blocks, and a block divides that length: 640 tokens
+    # (5 x 128) run whole or in five
+    assert _table(128, 128, causal=True) == [(128, 128)] * 3
+    assert _table(100, 100) == [(128, 128)] * 3
+    assert _table(640, 640) == [(640, 640)] * 3
+    assert _table(640, 640, causal=True) == [(640, 640), (128, 128), (128, 128)]
+    assert _table(768, 768) == [(768, 768)] * 3
+    assert _table(768, 768, causal=True) == [(768, 768), (384, 384), (384, 384)]
+    # mixed: each side by its own length (decode-with-prefix shapes)
+    assert _table(128, 8192, causal=True) == [(128, 1024), (128, 512), (128, 1024)]
+    # wide heads shrink a block until a grid step fits VMEM
+    assert _table(4096, 4096, D=512) == [(1024, 512)] * 3
+    # every block the table answers is a candidate of the search
+    for T in (512, 1024, 2048, 4096):
+        for causal in (False, True):
+            assert set(_table(T, T, causal=causal)) <= set(FLASH_CANDIDATES)
+    with pytest.raises(ValueError, match="kernel must be one of"):
+        static_flash_blocks(512, 512, kernel="bwd")
 
 
 def test_shape_key_buckets_nearby_shapes_together():
@@ -61,8 +90,8 @@ def test_shape_key_buckets_nearby_shapes_together():
 
 def test_interpret_search_is_deterministic_static_fallback(tmp_path):
     """ISSUE 12 acceptance (CPU leg): at every BASELINE.md long-context
-    grid point the interpret-mode search returns EXACTLY the hand-picked
-    table (timing the Pallas interpreter would persist noise), twice in a
+    grid point the interpret-mode search records that the static table
+    stands (timing the Pallas interpreter would persist noise), twice in a
     row, and persists the entry."""
     table = AutotuneTable(str(tmp_path / "autotune_cpu.json"))
     for T in (2048, 4096, 8192, 16384):
@@ -71,21 +100,26 @@ def test_interpret_search_is_deterministic_static_fallback(tmp_path):
         e2 = autotune_flash_attention(1, 12, T, 64, np.float32, table=table,
                                       interpret=True)
         assert e1 == e2
-        assert (e1["block_q"], e1["block_k"]) == static_flash_blocks(T, T)
-        assert e1["measured"] is False
-    # resolve_blocks now answers from the table at every grid point —
-    # tuned >= hand-picked holds by exact match
+        assert e1["measured"] is False and "block_q" not in e1
+    # resolve_blocks now finds a row at every grid point and answers what
+    # the static table answers, kernel by kernel — tuned >= static holds by
+    # exact match
+    reloaded = AutotuneTable(str(tmp_path / "autotune_cpu.json"))
+    assert len(reloaded) == 4
     for T in (2048, 4096, 8192, 16384):
-        assert resolve_blocks(
-            "flash_attention", B=1, H=12, Tq=T, Tk=T, D=64, dtype="float32",
-            table=table) == static_flash_blocks(T, T)
+        for causal in (False, True):
+            for kn in FLASH_KERNELS:
+                assert resolve_blocks(
+                    "flash_attention", B=1, H=12, Tq=T, Tk=T, D=64,
+                    dtype="float32", causal=causal, kernel=kn, table=reloaded
+                ) == static_flash_blocks(T, T, causal=causal, kernel=kn)
 
 
 def test_regression_guard_keeps_static_winner(monkeypatch):
     """A 'winner' measured slower than the static choice must not displace
-    it — tuned >= hand-picked at every point, by construction. Driven by a
-    fake timer keyed on the deterministic candidate order ([(128, 256),
-    (256, 256)] then the appended static (128, 128))."""
+    it — tuned >= static at every point, by construction. Driven by a fake
+    timer keyed on the deterministic order: the candidates [(128, 256),
+    (256, 256)], then the call with no block argument (the static table)."""
     import deeplearning4j_tpu.kernels.autotune as mod
 
     def timer_from(times):
@@ -97,22 +131,30 @@ def test_regression_guard_keeps_static_winner(monkeypatch):
         return fake
 
     table = AutotuneTable(None)
-    # static (last) measures FASTEST → static stays the winner
+    # static (last) measures FASTEST → the row carries no block, and lookups
+    # keep falling through to the static table
     monkeypatch.setattr(mod, "_time_best_of", timer_from([0.5, 0.5, 0.1]))
     e = autotune_flash_attention(
         1, 2, 256, 64, np.float32, table=table, interpret=False,
         candidates=[(128, 256), (256, 256)], trials=1,
         include_backward=False, persist=False)
-    assert (e["block_q"], e["block_k"]) == (128, 128)
-    assert e["measured"] is True
+    assert "block_q" not in e and e["measured"] is True
+    assert e["best_us"] == e["static_us"] == 100000.0
+    assert resolve_blocks("flash_attention", B=1, H=2, Tq=256, Tk=256, D=64,
+                          dtype="float32", kernel="dq", table=table
+                          ) == static_flash_blocks(256, 256, kernel="dq")
 
-    # a candidate beats static → it displaces the static entry
+    # a candidate beats static → it displaces the static table, for all three
     monkeypatch.setattr(mod, "_time_best_of", timer_from([0.5, 0.1, 0.5]))
     e = autotune_flash_attention(
         1, 2, 256, 64, np.float32, table=table, interpret=False,
         candidates=[(128, 256), (256, 256)], trials=1,
         include_backward=False, persist=False)
     assert (e["block_q"], e["block_k"]) == (256, 256)
+    for kn in FLASH_KERNELS:
+        assert resolve_blocks("flash_attention", B=1, H=2, Tq=256, Tk=256,
+                              D=64, dtype="float32", kernel=kn,
+                              table=table) == (256, 256)
 
 
 def test_all_failed_candidates_raise(tmp_path, monkeypatch):
@@ -142,6 +184,13 @@ def test_candidate_validity_filters():
     assert not autotune.candidate_valid(1024, 1024, 256, 256, 64)  # > T
     # VMEM blowout: giant probs block
     assert not autotune.candidate_valid(2048, 2048, 4096, 4096, 256)
+    # what a backward step holds, as Mosaic accepts it ahead of time for a
+    # v5e (PR 32): 1024 x 1024 compiles up to D 256 and not at D 512
+    assert autotune.candidate_valid(1024, 1024, 4096, 4096, 64)
+    assert autotune.candidate_valid(1024, 1024, 4096, 4096, 256)
+    assert not autotune.candidate_valid(1024, 1024, 4096, 4096, 512)
+    assert autotune.block_vmem_bytes(512, 512, 64, backward=True) > \
+        autotune.block_vmem_bytes(512, 512, 64, backward=False)
 
 
 # --------------------------------------------------------- flash consults
@@ -170,14 +219,14 @@ def test_flash_attention_consults_table_and_stays_correct(tmp_path,
         q = jnp.asarray(rs.randn(2, 2, 64, 16), jnp.float32)
         k = jnp.asarray(rs.randn(2, 2, 64, 16), jnp.float32)
         v = jnp.asarray(rs.randn(2, 2, 64, 16), jnp.float32)
-        out = flash_attention(q, k, v)
-        assert _lookup_count("table") == before + 1
+        out = flash_attention(q, k, v)  # one lookup a kernel
+        assert _lookup_count("table") == before + len(FLASH_KERNELS)
         np.testing.assert_allclose(np.asarray(out),
                                    np.asarray(mha_reference(q, k, v)),
                                    atol=2e-5)
         # an explicit argument bypasses the table (no new lookup)
         flash_attention(q, k, v, block_q=16, block_k=16)
-        assert _lookup_count("table") == before + 1
+        assert _lookup_count("table") == before + len(FLASH_KERNELS)
     finally:
         autotune.reset_table()
 

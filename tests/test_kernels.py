@@ -191,8 +191,9 @@ def test_flash_pallas_backward_matches_dense_oracle(causal, masked):
         qseg = jnp.zeros((2, 256), jnp.int32)
         kseg = jnp.asarray(np.where(rs.rand(2, 256) > 0.3, 0, -1), jnp.int32)
     do = jax.random.normal(jax.random.key(11), q.shape, jnp.float32)
-    out, res = _flash_fwd(q, k, v, qseg, kseg, causal, scale, 128, 128, True, 0)
-    dq, dk, dv, _, _ = _flash_bwd(causal, scale, 128, 128, True, 0, res, do)
+    blocks = ((128, 128),) * 3
+    out, res = _flash_fwd(q, k, v, qseg, kseg, causal, scale, blocks, True, 0)
+    dq, dk, dv, _, _ = _flash_bwd(causal, scale, blocks, True, 0, res, do)
     dq0, dk0, dv0 = _flash_bwd_dense(causal, scale, res, do)
     np.testing.assert_allclose(np.asarray(dq), np.asarray(dq0), atol=3e-5)
     np.testing.assert_allclose(np.asarray(dk), np.asarray(dk0), atol=3e-5)
@@ -265,8 +266,8 @@ def test_ulysses_heads_divisibility_error():
 
 
 def test_flash_long_t_auto_blocks_match_reference():
-    """T >= 4096 auto-selects (512, 1024) blocks (the measured long-T sweet
-    spot); numerics must match the dense reference under a mask."""
+    """T 4096 takes the table's long-T blocks (1024 x 1024, four a side);
+    numerics must match the dense reference under a mask."""
     q, k, v = _qkv((1, 2, 4096, 16))
     mask = jnp.ones((1, 4096)).at[:, 3700:].set(0.0)
     out = flash_attention(q, k, v, mask, interpret=True)
@@ -311,3 +312,125 @@ def test_flash_front_door_runs_per_shard_under_an_ambient_mesh():
             np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-5)
     # no ambient mesh: a plain call, no shard_map
     assert "shard_map" not in str(jax.make_jaxpr(flash)(q, k, v))
+
+
+# -- the table's tiles and the operands' dtype (ISSUE 32) ----------------------
+#
+# the shapes the static table answers differently since PR 32: each kernel
+# runs with the tile the table gives it, in float32 (tight) and in bf16 (bf16
+# operands into the MXU, float32 statistics: the tolerance of the paged
+# kernels' bf16 cases)
+
+_TABLE_CASES = {
+    # B, H, Tq, Tk, D, causal, mask
+    "masked_ragged_t512": (2, 2, 512, 512, 32, False, "ragged"),
+    "causal_t1024": (1, 2, 1024, 1024, 32, True, None),
+    "causal_tq256_tk640_offset": (1, 2, 256, 640, 32, True, None),
+    "masked_t700_pads_to_768": (2, 1, 700, 700, 32, False, "ragged"),
+    "causal_masked_dead_rows_t1024": (2, 1, 1024, 1024, 16, True, "dead_rows"),
+}
+
+
+def _table_case(name, dtype):
+    B, H, Tq, Tk, D, causal, kind = _TABLE_CASES[name]
+    kk = jax.random.key(len(name))
+    q = jax.random.normal(jax.random.fold_in(kk, 0), (B, H, Tq, D), jnp.float32)
+    k, v = (jax.random.normal(jax.random.fold_in(kk, i), (B, H, Tk, D), jnp.float32)
+            for i in (1, 2))
+    do = jax.random.normal(jax.random.fold_in(kk, 3), (B, H, Tq, D), jnp.float32)
+    mask = None
+    if kind is not None:
+        lens = np.linspace(0.6, 1.0, B) * Tk  # key padding, as the BERT cell's
+        keep = np.arange(Tk)[None, :] < lens[:, None]
+        if kind == "dead_rows":
+            keep[0, :5] = False  # causal: rows 0-4 of example 0 see no key
+            keep[1, :] = False   # example 1: no row sees any
+        mask = jnp.asarray(keep.astype(np.float32))
+    q, k, v, do = (t.astype(dtype) for t in (q, k, v, do))
+    return q, k, v, do, mask, causal
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("name", sorted(_TABLE_CASES))
+def test_flash_table_tiles_match_reference_and_dense_backward(name, dtype):
+    from deeplearning4j_tpu.kernels.attention import _NEG_INF, _flash_bwd_dense
+
+    q, k, v, do, mask, causal = _table_case(name, dtype)
+    tol = dict(atol=3e-5, rtol=3e-5) if dtype == jnp.float32 else dict(atol=4e-2, rtol=4e-2)
+    out, vjp = jax.vjp(lambda *a: flash_attention(*a, mask, causal=causal,
+                                                  interpret=True), q, k, v)
+    grads = vjp(do)
+    assert out.dtype == dtype and all(g.dtype == dtype for g in grads)
+
+    # the oracle works in float32 on the same (rounded) inputs
+    qf, kf, vf, dof = (t.astype(jnp.float32) for t in (q, k, v, do))
+    ref, ref_vjp = jax.vjp(lambda *a: mha_reference(*a, mask, causal=causal),
+                           qf, kf, vf)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref), **tol)
+    if name.startswith("causal_masked_dead_rows"):
+        # a row with no live key: the dense oracle rebuilds P = 1 a key there,
+        # the reference's uniform softmax is what flash_attention promises
+        want = ref_vjp(dof)
+    else:
+        scale = 1.0 / np.sqrt(q.shape[-1])
+        s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * scale
+        Tq, Tk = s.shape[-2:]
+        qseg = kseg = None
+        if causal:
+            s = jnp.where(jnp.arange(Tq)[:, None] + (Tk - Tq) >= jnp.arange(Tk), s, _NEG_INF)
+        if mask is not None:
+            s = jnp.where(mask[:, None, None, :] > 0, s, _NEG_INF)
+            qseg = jnp.zeros(mask.shape[:1] + (Tq,), jnp.int32)
+            kseg = jnp.where(mask > 0, 0, -1)
+        lse = jax.nn.logsumexp(s, axis=-1, keepdims=True)
+        want = _flash_bwd_dense(causal, scale, (qf, kf, vf, qseg, kseg, ref, lse), dof)
+    for g, w in zip(grads, want):
+        assert np.isfinite(np.asarray(g, np.float32)).all()
+        np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(w), **tol)
+
+
+def _kernel_eqns(jaxpr):
+    """Every equation inside the Pallas kernels of a jaxpr."""
+    def walk(jp, inside):
+        for eqn in jp.eqns:
+            if inside:
+                yield eqn
+            for val in eqn.params.values():
+                for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from walk(sub, inside or eqn.primitive.name == "pallas_call")
+    return list(walk(jaxpr.jaxpr, False))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_flash_kernels_feed_the_mxu_the_dtype_they_are_given(dtype):
+    """float32 inputs: no value changes dtype anywhere in the three kernels.
+    bf16 inputs: the matmuls of two loaded blocks (QK^T, dO V^T) take them as
+    bf16; a matmul with P or dS takes that float32 block as it is and the
+    loaded [T, D] block cast up (the MXU path rounds a float32 operand to
+    bf16 itself: kernels/attention.py:_dot_f32); everything accumulates in
+    float32, and no score-shaped block is ever cast."""
+    T, D = 256, 32
+    q, k, v = (t.astype(dtype) for t in _qkv((1, 2, T, D)))
+    mask = jnp.ones((1, T)).at[:, 200:].set(0.0)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(flash_attention(*a, mask, causal=True, block_q=128, block_k=128,
+                                           interpret=True).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(q, k, v)
+    eqns = _kernel_eqns(jaxpr)
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 2 + 4 + 3  # forward, dKV, dQ
+    loaded = [e for e in dots if e.outvars[0].aval.shape == (128, 128)]
+    assert len(loaded) == 1 + 2 + 2  # S; S^T, dP^T; S, dP
+    for e in dots:
+        want = jnp.dtype(dtype if any(e is x for x in loaded) else jnp.float32)
+        assert {jnp.dtype(x.aval.dtype) for x in e.invars} == {want}
+        assert e.outvars[0].aval.dtype == jnp.float32
+    casts = [e for e in eqns if e.primitive.name == "convert_element_type"
+             and jnp.issubdtype(e.invars[0].aval.dtype, jnp.floating)
+             and e.invars[0].aval.dtype != e.params["new_dtype"]]
+    if dtype == jnp.float32:
+        assert not casts
+    else:
+        assert casts and all(e.invars[0].aval.shape == (128, D) for e in casts)

@@ -239,3 +239,56 @@ def test_latent_prefill_program_compiles_for_the_chip_with_the_arena_in_place(
     compiled = lowered.compile()
     _assert_latent_arena_in_place(lowered, compiled)
     assert "flash_fwd" in compiled.as_text()
+
+
+# -- the flash kernels with the blocks the static table answers (ISSUE 32) -----
+#
+# the training cells' attention shapes (benchmark/traffic/mlm-t512.json: 16
+# sequences x 16 heads x 512 with key padding; train-dp2tp2.json: 4 x 10 heads
+# a chip x 1024, causal) through ``jax.grad``, and kimi's prefill call with
+# its explicit 1024 block: Mosaic accepts the blocks and a grid step fits VMEM
+
+
+@pytest.mark.parametrize("shape,causal,masked", [
+    ((16, 16, 512, 64), False, True),
+    ((4, 10, 1024, 64), True, False),
+], ids=["bert_t512_masked", "gpt2_t1024_causal"])
+def test_flash_grad_compiles_for_the_chip_with_the_tables_blocks(
+        one_chip, on_chip_path, shape, causal, masked):
+    from deeplearning4j_tpu.kernels import flash_attention
+    from deeplearning4j_tpu.kernels.autotune import FLASH_KERNELS, static_flash_blocks
+
+    B, H, T, D = shape
+
+    def loss(q, k, v, mask):
+        out = flash_attention(q, k, v, mask if masked else None, causal=causal)
+        return jnp.sum(out.astype(jnp.float32))
+
+    qkv = _shape(one_chip, shape, jnp.bfloat16)
+    mask = _shape(one_chip, (B, T), jnp.float32)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv, mask).compile()  # a Mosaic error would be raised here
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert name in text
+    # the kernels write what benchmark/work.py:classify_flash_call reads
+    assert f"(bf16[{B * H},{T},{D}]" in text and f"f32[{B * H},{T},1]" in text
+    # and the grids are the table's: one step a head where a block is whole
+    blocks = [static_flash_blocks(T, T, D=D, causal=causal, kernel=kn)
+              for kn in FLASH_KERNELS]
+    assert blocks == ([(512, 512)] * 3 if masked
+                      else [(1024, 1024), (512, 512), (512, 512)])
+
+
+def test_flash_forward_compiles_for_the_chip_at_kimis_prefill_block(
+        one_chip, on_chip_path):
+    """models/kimi_k2.py asks for 1024 x 1024 at its call site (q and k 192
+    wide, v padded to them): an explicit block wins over the table."""
+    from deeplearning4j_tpu.kernels import flash_attention
+
+    qkv = _shape(one_chip, (1, 64, 2048, 192), jnp.bfloat16)
+    compiled = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, scale=0.1, block_q=1024, block_k=1024)).lower(
+            qkv, qkv, qkv).compile()
+    assert "flash_fwd" in compiled.as_text()
