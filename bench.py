@@ -1392,7 +1392,7 @@ def _serving_pool_replica():
     p = json.loads(os.environ["TDL_BENCH_POOL_CFG"])
     cfg = _pool_transformer_cfg(p)
     params = tfm.init_params(jax.random.key(0), cfg)
-    pool = tfm.DecodeSlotPool(params, cfg, slots=p["slots"])
+    pool = tfm.PagedDecodeSlotPool(params, cfg, slots=p["slots"])
     return JsonModelServer(
         None, port=0, generative_session=pool,
         default_max_new_tokens=p["max_new"], max_queue=p["queue"],
@@ -1491,7 +1491,7 @@ def bench_serving_pool(p):
                      bursts=((0.5 * dur, 0.15 * dur, p["burst_mult"]),))
     phase1 = {}
     for mode, continuous in (("continuous", True), ("static", False)):
-        pool = tfm.DecodeSlotPool(params, cfg, slots=p["slots"])
+        pool = tfm.PagedDecodeSlotPool(params, cfg, slots=p["slots"])
         ex = GenerativeInferenceExecutor(
             pool, continuous=continuous, max_queue=p["queue"],
             default_max_new_tokens=max(mix),
@@ -1646,9 +1646,9 @@ def bench_paged_decode(p):
               for i in range(n_try)]
 
     cap_new = p["cap_max_new"]
-    dense_pool = tfm.DecodeSlotPool(params, cfg, slots=p["slots_dense"])
-    dense_short = _count_admissions(dense_pool, short, cap_new)
-    dense_long = _count_admissions(dense_pool, shared, cap_new)
+    # a dense cache of ``slots_dense`` rows holds that many sequences, whatever
+    # their length or shared prefix
+    dense_short = dense_long = p["slots_dense"]
 
     # slots = usable blocks so BLOCKS (HBM), not slot-table rows, bind
     paged_pool = tfm.PagedDecodeSlotPool(

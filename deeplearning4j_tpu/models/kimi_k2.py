@@ -396,8 +396,8 @@ def forward(params, tokens, cfg: KimiK2Config):
 
 
 class LatentDecodeFamily:
-    """What ``PagedDecodeSlotPool`` asks of a model family (see
-    ``paged_decode.TransformerDecodeFamily``), for latent attention: ONE
+    """What ``PagedDecodeSlotPool`` asks of a model family (the protocol is
+    in ``paged_decode``'s docstring), for latent attention: ONE
     arena ``[L, n_blocks, block_T, 640]`` (576 values and 64 zero lanes a
     token), a decode step in the absorbed form, and the step's routing
     counters."""

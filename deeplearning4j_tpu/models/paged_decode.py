@@ -1,9 +1,13 @@
-"""Block-paged KV cache with CoW prefix sharing and speculative decoding.
+"""Block-paged KV cache with CoW prefix sharing and speculative decoding:
+the slot pool every served model decodes through, and the arena's format.
 
-PR 12's :class:`~.transformer.DecodeSlotPool` provisions a dense
-``[L, slots, maxT, H, hd]`` cache — every slot pays worst-case HBM whether
-its sequence is 12 tokens or 500.  This module replaces that storage with a
-vLLM-shape paged arena behind the SAME one-signature decode step:
+A dense ``[L, slots, maxT, ...]`` cache makes every slot pay worst-case HBM
+whether its sequence is 12 tokens or 500.  Here the cache is a vLLM-shape
+paged arena behind ONE decode-step signature: the step always runs over the
+whole slot pool, whatever subset of slots is live, so membership churn
+(continuous batching) never mints a new executable, and prompt lengths pad
+to the common power-of-two bucket ladder (``common.bucketing``), so they
+share a handful of prefill executables.
 
 - **arena** — K/V live in ``[L, n_blocks, block_T, H*hd]`` (one block is a
   contiguous, lane-dense tile holding every head); block 0 is a scratch
@@ -18,9 +22,9 @@ vLLM-shape paged arena behind the SAME one-signature decode step:
   reaches its keys through the tables in
   :func:`~..kernels.paged_attention.paged_decode_attention`, which copies
   only the blocks a slot's live length reaches: bytes moved follow live
-  tokens, not ``slots x max_len``.  Scale, mask and softmax are the dense
-  pool's.  Tables and lengths change every step; shapes never do, so
-  ``decode_traces`` still pins to 1 under admit/retire/alloc churn;
+  tokens, not ``slots x max_len``.  Tables and lengths change every step;
+  shapes never do, so ``decode_traces`` pins to 1 under admit/retire/alloc
+  churn;
 - **copy-on-write prefix sharing** — an exact-match index (keyed on the
   literal prompt token bytes — no hash-collision wrongness) maps full
   prompt-prefix blocks and partial prompt tails to physical blocks.  An
@@ -42,40 +46,54 @@ vLLM-shape paged arena behind the SAME one-signature decode step:
   positions hold stale K/V that the sequential write-before-read discipline
   overwrites before it is ever attended.
 
-**Model families.** The pool does not know a layer. The config it is given
-answers ``decode_family()`` with a small object that says what one token
-stores in a block (``cache_widths``: one arena a width, ``[L, n_blocks,
-block_T, width]``) and runs the layers: ``prefill`` (a padded prompt ->
-its last hidden state and the rows to store), ``decode_window`` (a step of
-every slot over the arenas, through the tables) and ``head``. The GPT-2 /
-BERT family (:class:`TransformerDecodeFamily`: K and V arenas of ``H*hd``)
-and the latent family of ``models/kimi_k2.py`` (one arena of 576) share the
+**Model families.** The pool does not know a layer, and this module imports
+no model and no kernel. The config it is given answers ``decode_family()``
+with an object of its model's file (``transformer.TransformerDecodeFamily``:
+K and V arenas of ``H*hd``; ``kimi_k2.LatentDecodeFamily``: one latent arena),
+which writes its rows with :func:`_write_window` and attends through the
+tables with a kernel of ``kernels/paged_attention.py``. The families share the
 allocator, the tables, the prefix index, copy-on-write, admit / step /
-release, the counters and the donated in-place programs below. A family
-with ``stat_names`` returns that many int32 counters from a step; they come
-back in the one fetch that brings the tokens.
+release, the counters and the donated in-place programs below. What a family
+answers, stated here once (no base class: two implementations and this list):
 
-Single-owner object like the dense pool: the decode loop thread (or the
-offline ``generate`` driver) is the only caller — no internal locking.
+- ``name``; ``speculative`` (whether ``decode_window`` takes W > 1 tokens a
+  slot, which a verify window needs); ``n_layers``; ``cache_widths`` (what one
+  token stores in a block: one arena ``[L, n_blocks, block_T, width]`` a
+  width) and ``cache_dtype``; ``stat_names`` (the int32 counters a step
+  returns, which come back in the one fetch that brings the tokens);
+- ``prefill(params, tokens [1, Tb], length) -> (hidden state at length - 1
+  [D], rows: one [L, Tb, width] an arena)``;
+- ``decode_window(params, tokens [S, W], positions [S, W], arenas, tables)
+  -> (logits [S, W, V], arenas written in place, stats or None)``: a slot is
+  live iff its logical block 0 is mapped;
+- ``head(params, h [N, D]) -> logits [N, V]``;
+- ``cumulative_stats(sums, steps) -> dict``: what ``block_stats()`` shows of
+  the running sums of ``stat_names``.
+
+Single-owner object: the decode loop thread (or the offline ``generate``
+driver) is the only caller — no internal locking.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.paged_attention import paged_decode_attention
 from ..monitoring.trace import span
-from .transformer import (
-    TransformerConfig,
-    KvCacheLostError,
-    _layer_norm,
-    mlm_head,
-    prefill_forward,
-)
+
+
+class KvCacheLostError(RuntimeError):
+    """A donated prefill/decode call failed after its KV buffers were
+    consumed: every in-flight sequence is lost. The pool has already reset
+    itself (fresh zero cache, all slots free), so the NEXT admission works —
+    one transient device fault must not poison the pool forever.
+    ``all_sequences_lost`` is the duck-typed marker the serving executor
+    keys on (sessions are duck-typed; it cannot import this class)."""
+
+    all_sequences_lost = True
 
 
 class NoFreeBlocksError(RuntimeError):
@@ -144,15 +162,6 @@ class BlockAllocator:
         return self._index.get(key)
 
 
-def _embed_window(params, cfg: TransformerConfig, tokens, positions):
-    """Decode-step embedding at explicit positions: [S,W] -> [S,W,D]."""
-    e = params["embed"]
-    h = e["tok"][tokens] + e["pos"][positions]
-    if cfg.type_vocab > 0:
-        h = h + e["seg"][0]
-    return _layer_norm(h, e["ln_scale"], e["ln_bias"]).astype(cfg.compute_dtype)
-
-
 def _write_window(arena, layer: int, tables, limits, x):
     """``arena[layer, block, cell] = x[s, w]`` at position ``limits[s, w] - 1``
     of every live slot s, through its table, in place; nothing of a dead slot
@@ -163,101 +172,6 @@ def _write_window(arena, layer: int, tables, limits, x):
     block = jnp.where(pos >= 0, block, n_blocks)  # out of range: dropped
     return arena.at[layer, block.reshape(-1), (pos % block_T).reshape(-1)].set(
         x.reshape(-1, x.shape[-1]).astype(arena.dtype), mode="drop")
-
-
-def _paged_window_block(cfg: TransformerConfig, p, h, kc, vc, layer: int,
-                        tables, limits):
-    """One transformer block over a W-token decode window with paged K/V.
-
-    h [S,W,D]; kc/vc [L, n_blocks, block_T, H*hd] (the WHOLE arenas, updated
-    in place at ``layer``); tables [S, max_blocks] logical->physical; limits
-    [S,W] — token w sits at position ``limits[s, w] - 1`` and attends its
-    slot's first ``limits[s, w]`` keys (0: dead slot).  Scale, mask
-    constant, softmax and dtype discipline mirror the dense
-    ``_decode_block``.  Returns (h, kc, vc)."""
-    cd = cfg.compute_dtype
-    arenas = {}
-
-    def attn_sub(x):
-        qkv = x @ p["qkv_w"].astype(cd) + p["qkv_b"].astype(cd)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        # write-before-read: this window's K/V land in their cells first, so
-        # stale/garbage cells at <= attended positions never survive a step
-        arenas["k"] = _write_window(kc, layer, tables, limits, k)
-        arenas["v"] = _write_window(vc, layer, tables, limits, v)
-        o = paged_decode_attention(q, arenas["k"], arenas["v"], tables, limits,
-                                   layer=layer, n_heads=cfg.n_heads)
-        return o @ p["out_w"].astype(cd) + p["out_b"].astype(cd)
-
-    def ffn_sub(x):
-        x = jax.nn.gelu(x @ p["ffn_w1"].astype(cd) + p["ffn_b1"].astype(cd),
-                        approximate=cfg.gelu_approximate)
-        return x @ p["ffn_w2"].astype(cd) + p["ffn_b2"].astype(cd)
-
-    if cfg.norm_position == "pre":
-        h = h + attn_sub(_layer_norm(h, p["ln1_scale"], p["ln1_bias"]).astype(cd)).astype(h.dtype)
-        h = h + ffn_sub(_layer_norm(h, p["ln2_scale"], p["ln2_bias"]).astype(cd)).astype(h.dtype)
-    else:
-        h = _layer_norm(h + attn_sub(h.astype(cd)).astype(h.dtype),
-                        p["ln1_scale"], p["ln1_bias"]).astype(h.dtype)
-        h = _layer_norm(h + ffn_sub(h.astype(cd)).astype(h.dtype),
-                        p["ln2_scale"], p["ln2_bias"]).astype(h.dtype)
-    return h, arenas["k"], arenas["v"]
-
-
-def _paged_forward(params, cfg: TransformerConfig, tokens, positions, kc, vc,
-                   tables):
-    """Full-model W-token decode window over the paged arenas.
-
-    tokens/positions [S,W]; kc/vc [L, n_blocks, block_T, H*hd], written in
-    place layer by layer.  A slot is live iff its logical block 0 is mapped
-    (a released slot's table row is all trash).  Returns
-    (logits [S,W,V] fp32, kc, vc)."""
-    h = _embed_window(params, cfg, tokens, positions)
-    limits = jnp.where(tables[:, :1] > 0, positions + 1, 0)
-    for l in range(cfg.n_layers):
-        h, kc, vc = _paged_window_block(
-            cfg, params["blocks"][l], h, kc, vc, l, tables, limits)
-    return mlm_head(params, h, cfg), kc, vc
-
-
-class TransformerDecodeFamily:
-    """``models/transformer.py``'s layers for the slot pool: every head has
-    its own K and V, so a token stores ``H*hd`` values in each of two
-    arenas. ``TransformerConfig.decode_family()`` returns one."""
-
-    speculative = True   # ``decode_window`` takes W = spec_tokens + 1 tokens
-    stat_names = ()      # a step counts nothing of its own
-    name = "transformer"
-
-    def __init__(self, cfg: TransformerConfig):
-        self.cfg = cfg
-        self.n_layers = cfg.n_layers
-        self.cache_widths = (cfg.n_heads * cfg.head_dim,) * 2
-        self.cache_dtype = cfg.compute_dtype
-
-    def prefill(self, params, tokens, length):
-        """tokens [1, Tb] -> (hidden state at ``length - 1`` [D], the rows to
-        store: K and V, each [L, Tb, H*hd])."""
-        h, ks, vs = prefill_forward(params, tokens, self.cfg)
-
-        def rows(x):  # [L, 1, H, Tb, hd] -> [L, Tb, H*hd]
-            x = jnp.transpose(x[:, 0], (0, 2, 1, 3))
-            return x.reshape(*x.shape[:2], -1)
-
-        return h[0, length - 1], (rows(ks), rows(vs))
-
-    def head(self, params, h):
-        return mlm_head(params, h, self.cfg)
-
-    def decode_window(self, params, tokens, positions, arenas, tables):
-        """tokens / positions [S, W] -> (logits [S, W, V], arenas, None)."""
-        logits, kc, vc = _paged_forward(params, self.cfg, tokens, positions,
-                                        *arenas, tables)
-        return logits, (kc, vc), None
-
-    def cumulative_stats(self, sums, steps) -> Dict[str, int]:
-        return {}
 
 
 def _write_blocks(arena, dest_blocks, x):
@@ -271,11 +185,12 @@ def _write_blocks(arena, dest_blocks, x):
 
 
 class PagedDecodeSlotPool:
-    """Drop-in paged replacement for the dense ``DecodeSlotPool``.
-
-    Same duck interface (``admit``/``step``/``release``, ``free_slots``,
-    ``prompt_bucket``, trace counters, ``KvCacheLostError`` reset) with
-    three additions the serving executor discovers by ``getattr``:
+    """``slots`` concurrent sequences over one paged arena: ``admit``
+    prefills a prompt into a free slot, ``step`` advances every live
+    sequence, ``release`` frees a slot; ``free_slots``, ``prompt_bucket``,
+    the trace counters and the :class:`KvCacheLostError` reset are what any
+    session of the serving executor has. It discovers three more by
+    ``getattr``:
 
     - ``can_admit``/``request_blocks``/``total_blocks`` — block-priced
       admission control (queue-head gating and at-the-door 400s);
@@ -483,23 +398,6 @@ class PagedDecodeSlotPool:
         fam = cfg.decode_family()
         return tuple(jnp.zeros((fam.n_layers, self.n_blocks, self.block_T, w),
                                fam.cache_dtype) for w in fam.cache_widths)
-
-    # the K and V arenas of a family that has them, by their old names
-    @property
-    def _kc(self):
-        return self._arenas[0]
-
-    @_kc.setter
-    def _kc(self, value):
-        self._arenas = (value, *self._arenas[1:])
-
-    @property
-    def _vc(self):
-        return self._arenas[1]
-
-    @_vc.setter
-    def _vc(self, value):
-        self._arenas = (self._arenas[0], value, *self._arenas[2:])
 
     def _set_arenas(self, arenas) -> None:
         n = len(self._arenas)

@@ -18,15 +18,21 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..kernels.attention import dot_product_attention, ring_attention, ulysses_attention
+from ..kernels.paged_attention import paged_decode_attention
+from .paged_decode import (  # the pool's names are this module's too
+    BlockAllocator,
+    KvCacheLostError,
+    NoFreeBlocksError,
+    PagedDecodeSlotPool,
+    _write_window,
+)
 
 
 @dataclasses.dataclass
@@ -54,8 +60,6 @@ class TransformerConfig:
 
     def decode_family(self):
         """What the paged slot pool runs for this config's layers."""
-        from .paged_decode import TransformerDecodeFamily  # imports this module
-
         return TransformerDecodeFamily(self)
 
     @staticmethod
@@ -205,43 +209,81 @@ def _attention(cfg: TransformerConfig, q, k, v, pad_mask):
     return dot_product_attention(q, k, v, pad_mask, causal=cfg.causal, impl=impl)
 
 
-def _block(cfg: TransformerConfig, p, h, pad_mask, rng, train, return_kv=False):
-    B, T, D = h.shape
-    H, hd = cfg.n_heads, cfg.head_dim
-    cd = cfg.compute_dtype
-    pre = cfg.norm_position == "pre"
-    kv: Dict[str, Any] = {}
+def _layer(cfg: TransformerConfig, p, h, attend, rng=None, train=False):
+    """The GPT-2 / BERT block, written once: h [..., D] -> (h, kept).
 
-    def gelu(x):
-        return jax.nn.gelu(x, approximate=cfg.gelu_approximate)
+    Owns the pre/post-norm residual wiring, the qkv projection and split, the
+    out projection, the feed-forward, the dropout and the dtype discipline
+    (float32 masters cast to the compute dtype at each use, float32 norms).
+    Attention is the caller's: ``attend(q, k, v) -> (o, kept)`` on tensors
+    shaped like ``h``; what it wants kept (a prefill's K and V, a decode
+    step's arenas) comes back beside the new ``h``."""
+    cd = cfg.compute_dtype
 
     def attn_sub(x):
         qkv = x @ p["qkv_w"].astype(cd) + p["qkv_b"].astype(cd)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        # [B,T,D] -> [B,H,T,hd]
-        q, k, v = (t.reshape(B, T, H, hd).transpose(0, 2, 1, 3) for t in (q, k, v))
-        if return_kv:  # KV-cache prefill (decode path) captures per-layer K/V
-            kv["k"], kv["v"] = k, v
-        o = _attention(cfg, q, k, v, pad_mask)
-        o = o.transpose(0, 2, 1, 3).reshape(B, T, D)
+        o, kept = attend(*jnp.split(qkv, 3, axis=-1))
         o = o @ p["out_w"].astype(cd) + p["out_b"].astype(cd)
-        return _dropout(o, cfg, rng, 0, train)
+        return _dropout(o, cfg, rng, 0, train), kept
 
     def ffn_sub(x):
-        x = gelu(x @ p["ffn_w1"].astype(cd) + p["ffn_b1"].astype(cd))
+        x = jax.nn.gelu(x @ p["ffn_w1"].astype(cd) + p["ffn_b1"].astype(cd),
+                        approximate=cfg.gelu_approximate)
         x = x @ p["ffn_w2"].astype(cd) + p["ffn_b2"].astype(cd)
         return _dropout(x, cfg, rng, 1, train)
 
-    if pre:  # GPT-style pre-LN: h + f(LN(h))
-        h = h + attn_sub(_layer_norm(h, p["ln1_scale"], p["ln1_bias"]).astype(cd)).astype(h.dtype)
+    if cfg.norm_position == "pre":  # GPT-style pre-LN: h + f(LN(h))
+        a, kept = attn_sub(_layer_norm(h, p["ln1_scale"], p["ln1_bias"]).astype(cd))
+        h = h + a.astype(h.dtype)
         h = h + ffn_sub(_layer_norm(h, p["ln2_scale"], p["ln2_bias"]).astype(cd)).astype(h.dtype)
-        return (h, kv["k"], kv["v"]) if return_kv else h
+        return h, kept
     # original-BERT post-LN: LN(h + f(h))  (required for faithful HF import)
-    h = _layer_norm(h + attn_sub(h.astype(cd)).astype(h.dtype),
-                    p["ln1_scale"], p["ln1_bias"]).astype(h.dtype)
+    a, kept = attn_sub(h.astype(cd))
+    h = _layer_norm(h + a.astype(h.dtype), p["ln1_scale"], p["ln1_bias"]).astype(h.dtype)
     h = _layer_norm(h + ffn_sub(h.astype(cd)).astype(h.dtype),
                     p["ln2_scale"], p["ln2_bias"]).astype(h.dtype)
-    return (h, kv["k"], kv["v"]) if return_kv else h
+    return h, kept
+
+
+def _whole_sequences(cfg: TransformerConfig, pad_mask):
+    """``attend`` of :func:`_layer` for whole sequences [B,T,D] (training,
+    ``encode``, prefill): every position attends its own sequence through
+    :func:`_attention`; keeps this layer's K and V, each [B,H,T,hd]."""
+    H, hd = cfg.n_heads, cfg.head_dim
+
+    def attend(q, k, v):
+        B, T, D = q.shape
+        # [B,T,D] -> [B,H,T,hd]
+        q, k, v = (t.reshape(B, T, H, hd).transpose(0, 2, 1, 3) for t in (q, k, v))
+        o = _attention(cfg, q, k, v, pad_mask)
+        return o.transpose(0, 2, 1, 3).reshape(B, T, D), (k, v)
+
+    return attend
+
+
+def _paged_window(cfg: TransformerConfig, kc, vc, layer: int, tables, limits):
+    """``attend`` of :func:`_layer` for a W-token decode window [S,W,D] over
+    paged K/V: kc / vc [L, n_blocks, block_T, H*hd] are the WHOLE arenas,
+    updated in place at ``layer`` and kept; tables [S, max_blocks] map
+    logical to physical blocks; token w of slot s sits at position
+    ``limits[s, w] - 1`` and attends its slot's first ``limits[s, w]`` keys
+    (0: a dead slot)."""
+
+    def attend(q, k, v):
+        # write-before-read: this window's K/V land in their cells first, so
+        # stale/garbage cells at <= attended positions never survive a step
+        nkc = _write_window(kc, layer, tables, limits, k)
+        nvc = _write_window(vc, layer, tables, limits, v)
+        o = paged_decode_attention(q, nkc, nvc, tables, limits,
+                                   layer=layer, n_heads=cfg.n_heads)
+        return o, (nkc, nvc)
+
+    return attend
+
+
+def _block(cfg: TransformerConfig, p, h, pad_mask, rng, train):
+    """One block over whole sequences (the name ``parallel/pipeline.py`` scans)."""
+    return _layer(cfg, p, h, _whole_sequences(cfg, pad_mask), rng, train)[0]
 
 
 def _dropout(x, cfg, rng, salt, train):
@@ -252,11 +294,14 @@ def _dropout(x, cfg, rng, salt, train):
     return jnp.where(mask, x / keep, 0.0)
 
 
-def embed(params, tokens, cfg: TransformerConfig, *, segments=None):
-    """Embedding front-end: tokens [B,T] → block input [B,T,D] (compute dtype)."""
-    T = tokens.shape[-1]
+def embed(params, tokens, cfg: TransformerConfig, *, segments=None,
+          positions=None):
+    """Embedding front-end: tokens [B,T] → block input [B,T,D] (compute dtype).
+    ``positions`` (shaped like ``tokens``; a decode window's) default to
+    0..T-1 of every row."""
     e = params["embed"]
-    h = e["tok"][tokens] + e["pos"][:T][None]
+    h = e["tok"][tokens] + (e["pos"][:tokens.shape[-1]][None] if positions is None
+                            else e["pos"][positions])
     if segments is not None:
         h = h + e["seg"][segments]
     elif cfg.type_vocab > 0:
@@ -406,7 +451,7 @@ def encode(params, tokens, cfg: TransformerConfig, *, segments=None,
     h = embed(params, tokens, cfg, segments=segments)
     block = functools.partial(_block, cfg)
     if cfg.remat:
-        block = jax.checkpoint(block, static_argnums=())
+        block = jax.checkpoint(block, static_argnums=(4,))  # train: a Python bool
     for i, p in enumerate(params["blocks"]):
         sub = jax.random.fold_in(rng, i) if rng is not None else None
         h = block(p, h, pad_mask, sub, train)
@@ -449,39 +494,9 @@ def qa_loss_fn(params, qa_params, batch, cfg: TransformerConfig, rng=None,
                   + ce(e_logits, batch["end_positions"]))
 
 
-# ------------------------------------------- autoregressive decode (ISSUE 13)
-# KV-cache generation for causal configs. Design constraints (the tentpole's
-# perf contract):
-#
-# - ONE decode-step XLA signature: the step always runs over the WHOLE slot
-#   pool ([S] tokens/positions, [L,S,maxT,H,hd] cache) whatever subset of
-#   slots is live — membership churn (continuous batching) never mints a new
-#   executable. Inactive slots compute garbage into their own (free) cache
-#   rows, which the next prefill overwrites.
-# - bounded prefill signatures: prompt lengths pad to the common power-of-2
-#   bucket ladder (``common.bucketing``), so arbitrary prompt lengths share
-#   a handful of prefill executables.
-# - decode math mirrors ``_block``/``mha_reference`` exactly (same dtypes,
-#   same -1e30 masking, same softmax), so incremental generation is
-#   token-identical to repeated full forwards — pinned by
-#   tests/test_generate.py.
-
-_NEG_INF = -1e30  # matches kernels.attention masking
-
-
-def init_kv_cache(cfg: TransformerConfig, slots: int,
-                  max_len: Optional[int] = None):
-    """Preallocated per-slot KV cache: ``{'k','v'}`` of
-    ``[n_layers, slots, max_len, n_heads, head_dim]`` in compute dtype.
-    Fixed ``max_len`` = fixed decode-step signature; a running decode batch
-    keeps this shape while its membership changes."""
-    T = max_len or cfg.max_len
-    if T > cfg.max_len:
-        raise ValueError(f"kv cache max_len {T} exceeds the model's "
-                         f"positional range max_len={cfg.max_len}")
-    shape = (cfg.n_layers, slots, T, cfg.n_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.compute_dtype),
-            "v": jnp.zeros(shape, cfg.compute_dtype)}
+# ------------------------------------------------------- autoregressive decode
+# Served through ``paged_decode.PagedDecodeSlotPool``; this file holds what the
+# pool runs for a causal config: the family below, over the one ``_layer``.
 
 
 def prefill_forward(params, tokens, cfg: TransformerConfig, *, segments=None,
@@ -492,256 +507,60 @@ def prefill_forward(params, tokens, cfg: TransformerConfig, *, segments=None,
     same math as :func:`encode` (inference, no dropout), with each block's
     projected keys/values captured for the KV cache."""
     h = embed(params, tokens, cfg, segments=segments)
+    attend = _whole_sequences(cfg, pad_mask)
     ks, vs = [], []
     for p in params["blocks"]:
-        h, k, v = _block(cfg, p, h, pad_mask, None, False, return_kv=True)
+        h, (k, v) = _layer(cfg, p, h, attend)
         ks.append(k)
         vs.append(v)
     return h, jnp.stack(ks), jnp.stack(vs)
 
 
-def _decode_block(cfg: TransformerConfig, p, h, kc, vc, positions, kv_mask):
-    """One transformer block for a single-token step over the slot pool.
+class TransformerDecodeFamily:
+    """This file's layers for the slot pool (the protocol is in
+    ``paged_decode``'s docstring): every head has its own K and V, so a token
+    stores ``H*hd`` values in each of two arenas."""
 
-    h [S,D]; kc/vc [S,maxT,H,hd] (this layer's cache rows); positions [S] =
-    where this step's K/V land; kv_mask [S,maxT] = attendable keys
-    (j <= position). Returns (h, new_kc, new_vc). Mirrors ``_block`` at
-    T=1 — same dtype discipline, same masking constant, same softmax."""
-    S, D = h.shape
-    H, hd = cfg.n_heads, cfg.head_dim
-    cd = cfg.compute_dtype
-    scale = 1.0 / math.sqrt(hd)
-    written = {}
+    speculative = True   # ``decode_window`` takes W = spec_tokens + 1 tokens
+    stat_names = ()      # a step counts nothing of its own
+    name = "transformer"
 
-    def attn_sub(x):
-        qkv = x @ p["qkv_w"].astype(cd) + p["qkv_b"].astype(cd)
-        q, k, v = (t.reshape(S, H, hd) for t in jnp.split(qkv, 3, axis=-1))
-        nkc = kc.at[jnp.arange(S), positions].set(k.astype(kc.dtype))
-        nvc = vc.at[jnp.arange(S), positions].set(v.astype(vc.dtype))
-        written["k"], written["v"] = nkc, nvc
-        scores = jnp.einsum("shd,sthd->sht", q, nkc.astype(cd)) * scale
-        scores = jnp.where(kv_mask[:, None, :], scores, _NEG_INF)
-        w = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("sht,sthd->shd", w, nvc.astype(cd)).reshape(S, D)
-        return o @ p["out_w"].astype(cd) + p["out_b"].astype(cd)
-
-    def ffn_sub(x):
-        x = jax.nn.gelu(x @ p["ffn_w1"].astype(cd) + p["ffn_b1"].astype(cd),
-                        approximate=cfg.gelu_approximate)
-        return x @ p["ffn_w2"].astype(cd) + p["ffn_b2"].astype(cd)
-
-    if cfg.norm_position == "pre":
-        h = h + attn_sub(_layer_norm(h, p["ln1_scale"], p["ln1_bias"]).astype(cd)).astype(h.dtype)
-        h = h + ffn_sub(_layer_norm(h, p["ln2_scale"], p["ln2_bias"]).astype(cd)).astype(h.dtype)
-    else:
-        h = _layer_norm(h + attn_sub(h.astype(cd)).astype(h.dtype),
-                        p["ln1_scale"], p["ln1_bias"]).astype(h.dtype)
-        h = _layer_norm(h + ffn_sub(h.astype(cd)).astype(h.dtype),
-                        p["ln2_scale"], p["ln2_bias"]).astype(h.dtype)
-    return h, written["k"], written["v"]
-
-
-class KvCacheLostError(RuntimeError):
-    """A donated prefill/decode call failed after its KV buffers were
-    consumed: every in-flight sequence is lost. The pool has already reset
-    itself (fresh zero cache, all slots free), so the NEXT admission works —
-    one transient device fault must not poison the pool forever.
-    ``all_sequences_lost`` is the duck-typed marker the serving executor
-    keys on (sessions are duck-typed; it cannot import this class)."""
-
-    all_sequences_lost = True
-
-
-class DecodeSlotPool:
-    """Fixed-shape KV-cache slot pool — the continuous-batching substrate.
-
-    ``slots`` concurrent sequences share one preallocated cache; ``admit``
-    prefills a prompt into a free slot (prompt padded to the common bucket
-    ladder — bounded prefill signatures), ``step`` advances EVERY live
-    sequence one greedy token through ONE jitted executable whose signature
-    never depends on which slots are live, and ``release`` frees a slot for
-    the next admission. Membership can change every step; shapes never do.
-
-    Single-owner object: the decode loop thread (or the offline
-    :func:`generate` driver) is the only caller — no internal locking.
-    """
-
-    def __init__(self, params, cfg: TransformerConfig, *, slots: int = 8,
-                 max_len: Optional[int] = None, eos_id: Optional[int] = None,
-                 min_prompt_bucket: int = 16):
-        if not cfg.causal:
-            raise ValueError(
-                "autoregressive decode needs a causal config "
-                "(TransformerConfig(causal=True)) — a bidirectional encoder "
-                "cannot extend a sequence incrementally")
-        if slots < 1:
-            raise ValueError(f"slots must be >= 1, got {slots}")
-        self.params = params
+    def __init__(self, cfg: TransformerConfig):
         self.cfg = cfg
-        self.slots = slots
-        self.max_len = max_len or cfg.max_len
-        self.eos_id = eos_id
-        self.min_prompt_bucket = max(1, min_prompt_bucket)
-        cache = init_kv_cache(cfg, slots, self.max_len)
-        self._kc, self._vc = cache["k"], cache["v"]
-        self._positions = np.zeros(slots, np.int32)
-        self._tokens = np.zeros(slots, np.int32)
-        self._active = np.zeros(slots, bool)
-        # python-side trace counters: incremented when jax TRACES (not runs)
-        # the fns — tests pin "one decode signature under membership churn"
-        self.decode_traces = 0
-        self.prefill_traces = 0
+        self.n_layers = cfg.n_layers
+        self.cache_widths = (cfg.n_heads * cfg.head_dim,) * 2
+        self.cache_dtype = cfg.compute_dtype
 
-        def _decode(params, kc, vc, tokens, positions):
-            self.decode_traces += 1
-            e = params["embed"]
-            h = e["tok"][tokens] + e["pos"][positions]
-            if cfg.type_vocab > 0:
-                h = h + e["seg"][0]
-            h = _layer_norm(h, e["ln_scale"], e["ln_bias"]).astype(cfg.compute_dtype)
-            kv_mask = jnp.arange(kc.shape[2])[None, :] <= positions[:, None]
-            nk, nv = [], []
-            for l in range(cfg.n_layers):
-                h, k_l, v_l = _decode_block(cfg, params["blocks"][l], h,
-                                            kc[l], vc[l], positions, kv_mask)
-                nk.append(k_l)
-                nv.append(v_l)
-            logits = mlm_head(params, h, cfg)  # [S, V] fp32 (tied decoder)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return jnp.stack(nk), jnp.stack(nv), nxt
+    def prefill(self, params, tokens, length):
+        """tokens [1, Tb] -> (hidden state at ``length - 1`` [D], the rows to
+        store: K and V, each [L, Tb, H*hd])."""
+        h, ks, vs = prefill_forward(params, tokens, self.cfg)
 
-        def _prefill(params, kc, vc, slot, tokens, length):
-            self.prefill_traces += 1
-            h, ks, vs = prefill_forward(params, tokens, cfg)
-            # [L, 1, H, Tb, hd] -> [L, 1, Tb, H, hd] for the cache layout
-            ks = jnp.transpose(ks[:, 0], (0, 2, 1, 3))[:, None]
-            vs = jnp.transpose(vs[:, 0], (0, 2, 1, 3))[:, None]
-            kc = jax.lax.dynamic_update_slice(
-                kc, ks.astype(kc.dtype), (0, slot, 0, 0, 0))
-            vc = jax.lax.dynamic_update_slice(
-                vc, vs.astype(vc.dtype), (0, slot, 0, 0, 0))
-            last = h[0, length - 1]  # hidden at the LAST REAL prompt position
-            logits = mlm_head(params, last[None], cfg)[0]
-            return kc, vc, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        def rows(x):  # [L, 1, H, Tb, hd] -> [L, Tb, H*hd]
+            x = jnp.transpose(x[:, 0], (0, 2, 1, 3))
+            return x.reshape(*x.shape[:2], -1)
 
-        # cache buffers are donated: the step updates them in place instead
-        # of holding two live copies of the pool's largest allocation
-        self._decode_fn = jax.jit(_decode, donate_argnums=(1, 2))
-        self._prefill_fn = jax.jit(_prefill, donate_argnums=(1, 2))
+        return h[0, length - 1], (rows(ks), rows(vs))
 
-    # -- capacity ----------------------------------------------------------
+    def head(self, params, h):
+        return mlm_head(params, h, self.cfg)
 
-    @property
-    def vocab_size(self) -> int:
-        """Token-id upper bound (exclusive) — the serving executor rejects
-        out-of-range ids at admission instead of letting the embedding
-        gather clamp them into silently wrong generations."""
-        return self.cfg.vocab_size
+    def decode_window(self, params, tokens, positions, arenas, tables):
+        """tokens / positions [S, W] -> (logits [S, W, V] fp32, arenas, None).
+        The K and V arenas are written in place layer by layer. A slot is
+        live iff its logical block 0 is mapped (a released slot's table row
+        is all trash)."""
+        cfg = self.cfg
+        kc, vc = arenas
+        h = embed(params, tokens, cfg, positions=positions)
+        limits = jnp.where(tables[:, :1] > 0, positions + 1, 0)
+        for l, p in enumerate(params["blocks"]):
+            h, (kc, vc) = _layer(cfg, p, h,
+                                 _paged_window(cfg, kc, vc, l, tables, limits))
+        return mlm_head(params, h, cfg), (kc, vc), None
 
-    @property
-    def free_slots(self) -> int:
-        return int(self.slots - self._active.sum())
-
-    @property
-    def occupancy(self) -> int:
-        return int(self._active.sum())
-
-    def prompt_bucket(self, n: int) -> int:
-        from ..common.bucketing import bucket_size
-
-        return min(self.max_len, bucket_size(n, min_bucket=self.min_prompt_bucket))
-
-    # -- lifecycle ---------------------------------------------------------
-
-    def admit(self, prompt, max_new_tokens: int = 1):
-        """Prefill ``prompt`` (1-D int tokens) into a free slot. Returns
-        ``(slot, first_token)`` — the first greedy continuation token, so a
-        ``max_new_tokens=1`` request never needs a decode step. Raises
-        ``RuntimeError`` when no slot is free and ``ValueError`` when the
-        prompt (plus its token budget) cannot fit the cache."""
-        toks = np.asarray(prompt, np.int32).reshape(-1)
-        n = toks.shape[0]
-        if n < 1:
-            raise ValueError("prompt must contain at least one token")
-        if max_new_tokens < 1:
-            raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
-        if n + max_new_tokens > self.max_len:
-            raise ValueError(
-                f"prompt of {n} tokens + {max_new_tokens} new tokens exceeds "
-                f"the {self.max_len}-position KV cache")
-        free = np.flatnonzero(~self._active)
-        if free.size == 0:
-            raise RuntimeError("no free decode slot")
-        slot = int(free[0])
-        bucket = self.prompt_bucket(n)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n] = toks
-        try:
-            self._kc, self._vc, first = self._prefill_fn(
-                self.params, self._kc, self._vc, np.int32(slot), padded,
-                np.int32(n))
-        except Exception as e:
-            self._reset_after_failure()
-            raise KvCacheLostError(
-                f"prefill failed after its KV buffers were donated "
-                f"({type(e).__name__}: {e}); cache reset, in-flight "
-                f"sequences lost") from e
-        self._active[slot] = True
-        self._positions[slot] = n  # where the first generated token lands
-        self._tokens[slot] = int(first)
-        return slot, int(first)
-
-    def step(self):
-        """One decode step for EVERY live slot (one fixed-signature XLA
-        call). Returns ``{slot: next_token}`` for the live slots. The
-        caller decides retirement (EOS / budget / deadline) and calls
-        :meth:`release`."""
-        if not self._active.any():
-            return {}
-        if (self._positions[self._active] >= self.max_len).any():
-            raise RuntimeError(
-                "a live slot is at the end of its KV cache — the caller "
-                "must retire sequences before position reaches max_len")
-        try:
-            self._kc, self._vc, nxt = self._decode_fn(
-                self.params, self._kc, self._vc, jnp.asarray(self._tokens),
-                jnp.asarray(self._positions))
-        except Exception as e:
-            self._reset_after_failure()
-            raise KvCacheLostError(
-                f"decode step failed after its KV buffers were donated "
-                f"({type(e).__name__}: {e}); cache reset, in-flight "
-                f"sequences lost") from e
-        nxt = np.asarray(nxt)
-        out = {}
-        for slot in np.flatnonzero(self._active):
-            slot = int(slot)
-            out[slot] = int(nxt[slot])
-            self._positions[slot] += 1
-            self._tokens[slot] = nxt[slot]
-        return out
-
-    def _reset_after_failure(self) -> None:
-        """Recover from a failed donated call: the old K/V buffers may
-        already be consumed (donation invalidates inputs at dispatch), so
-        keeping them would poison every later admit/step with 'Array has
-        been deleted'. Reallocate a zero cache and free every slot — the
-        in-flight sequences are lost (the caller tells their riders), the
-        pool itself keeps serving."""
-        cache = init_kv_cache(self.cfg, self.slots, self.max_len)
-        self._kc, self._vc = cache["k"], cache["v"]
-        self._active[:] = False
-        self._positions[:] = 0
-        self._tokens[:] = 0
-
-    def release(self, slot: int) -> None:
-        """Free a slot for the next admission (its cache rows become junk a
-        future prefill overwrites)."""
-        if not self._active[slot]:
-            raise ValueError(f"slot {slot} is not active")
-        self._active[slot] = False
-        self._positions[slot] = 0
-        self._tokens[slot] = 0
+    def cumulative_stats(self, sums, steps) -> Dict[str, int]:
+        return {}
 
 
 def generate(params, prompts, max_new_tokens: int,
@@ -757,13 +576,11 @@ def generate(params, prompts, max_new_tokens: int,
     a finished sequence's slot is refilled immediately, so a batch of
     mixed-length generations never pads to its slowest member.
 
-    There is ONE decode implementation: when no ``pool`` is passed the
-    driver builds a block-paged :class:`PagedDecodeSlotPool` (sized to the
-    dense pool's HBM footprint, so existing ``slots=N`` semantics hold);
-    pass ``draft_params``/``draft_cfg`` to decode speculatively — the
-    output is token-identical to plain greedy by construction. A dense
-    ``DecodeSlotPool`` still works via ``pool=``; both step protocols
-    (``{slot: tok}`` and ``{slot: [toks...]}``) are understood."""
+    When no ``pool`` is passed the driver builds a block-paged
+    :class:`PagedDecodeSlotPool` with room for ``slots`` sequences of
+    ``max_len``; pass ``draft_params``/``draft_cfg`` to decode speculatively
+    — the output is token-identical to plain greedy by construction. A
+    ``pool=`` may answer a step with ``{slot: tok}`` or ``{slot: [toks...]}``."""
     from collections import deque
 
     if max_new_tokens < 1:
@@ -778,14 +595,11 @@ def generate(params, prompts, max_new_tokens: int,
         block_T = 16
         while T % block_T:
             block_T //= 2
-        pool_cls = globals().get("PagedDecodeSlotPool")
-        if pool_cls is None:
-            pool_cls = __getattr__("PagedDecodeSlotPool")
-        pool = pool_cls(params, cfg,
-                        slots=slots or min(8, len(prompts)),
-                        eos_id=eos_id, max_len=max_len,
-                        block_T=block_T, draft_params=draft_params,
-                        draft_cfg=draft_cfg, spec_tokens=spec_tokens)
+        pool = PagedDecodeSlotPool(params, cfg,
+                                   slots=slots or min(8, len(prompts)),
+                                   eos_id=eos_id, max_len=max_len,
+                                   block_T=block_T, draft_params=draft_params,
+                                   draft_cfg=draft_cfg, spec_tokens=spec_tokens)
     eos = eos_id if eos_id is not None else pool.eos_id
     pending = deque(enumerate(prompts))
     live: Dict[int, list] = {}  # slot -> [prompt index, generated tokens]
@@ -824,22 +638,6 @@ def generate(params, prompts, max_new_tokens: int,
                     del live[slot]
                     break
     return [results[i] for i in range(len(prompts))]
-
-
-_PAGED_EXPORTS = ("BlockAllocator", "NoFreeBlocksError", "PagedDecodeSlotPool")
-
-
-def __getattr__(name):
-    # Lazy re-export of the paged pool (PEP 562): paged_decode imports THIS
-    # module's building blocks, so an eager import here would be cyclic
-    # whenever paged_decode lands in sys.modules first.  generate() looks
-    # the class up through module globals before falling back here, which
-    # keeps `transformer.PagedDecodeSlotPool = Fake` patching working.
-    if name in _PAGED_EXPORTS:
-        from . import paged_decode
-
-        return getattr(paged_decode, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def make_qa_train_step(cfg: TransformerConfig, updater):

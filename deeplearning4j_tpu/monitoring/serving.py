@@ -35,7 +35,8 @@ per-step truth)::
                                             (deadline, shutdown)
 
 Paged-decode families (ISSUE 17 — block-paged KV arena, CoW prefix sharing
-and speculative decoding; all zero/absent when a dense slot pool serves)::
+and speculative decoding; all zero/absent when the session has no
+``block_stats``)::
 
     tdl_decode_blocks_total                 usable KV arena blocks (gauge;
                                             trash block excluded)

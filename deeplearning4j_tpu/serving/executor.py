@@ -541,12 +541,12 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
     traffic. Deadlines shed mid-decode through the existing 504 path
     (the sequence is EVICTED, its slot freed the same step).
 
-    ``session`` is duck-typed (``models.transformer.DecodeSlotPool`` and the
-    block-paged ``models.paged_decode.PagedDecodeSlotPool`` are the real
-    ones): ``slots``, ``free_slots``, ``admit(prompt, max_new_tokens) ->
-    (slot, first_token)``, ``step() -> {slot: token | [tokens...]}``,
+    ``session`` is duck-typed (the block-paged
+    ``models.paged_decode.PagedDecodeSlotPool`` is the real one): ``slots``,
+    ``free_slots``, ``admit(prompt, max_new_tokens) -> (slot,
+    first_token)``, ``step() -> {slot: token | [tokens...]}``,
     ``release(slot)``, plus optional ``eos_id`` / ``max_len`` attributes.
-    Paged sessions additionally expose ``can_admit``/``request_blocks``/
+    The paged pool additionally exposes ``can_admit``/``request_blocks``/
     ``total_blocks`` (block-priced admission control), ``block_stats()``
     (occupancy/CoW/speculation telemetry), ``admit_overhead_tokens``
     (speculative lookahead slack priced at the door), and an admission
@@ -600,7 +600,7 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
     def _sync_session_metrics(self) -> None:
         """Mirror the paged pool's block/speculation counters into the
         ``tdl_decode_blocks_*`` / ``tdl_decode_cow_*`` / ``tdl_decode_spec_*``
-        families (no-op for dense slot-pool sessions)."""
+        families (no-op for a session without ``block_stats``)."""
         block_stats = getattr(self.session, "block_stats", None)
         if block_stats is None:
             return
@@ -814,7 +814,7 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
             fut._resolve(error=e)
             if active and getattr(e, "all_sequences_lost", False):
                 # the session's KV cache was lost mid-prefill (duck-typed
-                # marker, see transformer.KvCacheLostError): every rider's
+                # marker, see paged_decode.KvCacheLostError): every rider's
                 # sequence died with it — fail them now rather than let the
                 # next decode step hand them tokens from a zeroed cache
                 log.warning("KV cache lost: failing %d in-flight "
@@ -892,7 +892,7 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
         emitted_total = 0
         for slot in list(active):
             fut = active[slot]
-            # dense sessions emit one int per slot; paged sessions a list
+            # a minimal session emits one int per slot; the paged pool a list
             # (1 token plain, up to spec_tokens+1 speculative) — accept
             # both, clamped to the request's budget and truncated at EOS
             step_out = out[slot]

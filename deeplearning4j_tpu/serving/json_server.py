@@ -122,7 +122,7 @@ class JsonModelServer:
         compile_cache.enable()
         self.warmup_all_buckets = warmup_all_buckets
         self.model = model
-        #: ISSUE 13: a decode slot pool (``models.transformer.DecodeSlotPool``
+        #: ISSUE 13: a decode slot pool (``models.paged_decode.PagedDecodeSlotPool``
         #: or duck-equivalent) flips the server into GENERATIVE mode — the
         #: executor underneath becomes a continuous-batching decode loop and
         #: payloads are token sequences, not feature rows
@@ -205,9 +205,9 @@ class JsonModelServer:
 
         def generative(self, session):
             """Serve autoregressive GENERATION (ISSUE 13): ``session`` is a
-            decode slot pool (``models.transformer.DecodeSlotPool``, the
-            block-paged ``models.paged_decode.PagedDecodeSlotPool``, or
-            duck-equivalent) and the executor underneath becomes the
+            decode slot pool (the block-paged
+            ``models.paged_decode.PagedDecodeSlotPool`` or duck-equivalent)
+            and the executor underneath becomes the
             continuous-batching decode loop. Payloads are 1-D token
             sequences; responses carry the generated token ids; the
             ``X-Max-New-Tokens`` header bounds one request's budget.

@@ -1,10 +1,10 @@
 """Autoregressive KV-cache decode (ISSUE 13 tentpole piece 1).
 
-The correctness contract: incremental decode through the preallocated
-slot-pool KV cache is TOKEN-IDENTICAL to naive generation by repeated full
-forwards, and membership churn in the slot pool (continuous batching's
-admit/retire at step boundaries) never changes results OR mints a new
-decode-step XLA signature.
+The correctness contract: incremental decode through the slot pool's paged
+KV cache is TOKEN-IDENTICAL to naive generation by repeated full forwards,
+and membership churn in the slot pool (continuous batching's admit/retire
+at step boundaries) never changes results OR mints a new decode-step XLA
+signature.
 """
 
 import numpy as np
@@ -77,12 +77,13 @@ def test_incremental_decode_matches_naive_full_forward():
 def test_decode_requires_causal_config():
     cfg = _cfg(causal=False)
     with pytest.raises(ValueError, match="causal"):
-        tfm.DecodeSlotPool(_params(cfg), cfg, slots=2)
+        tfm.PagedDecodeSlotPool(_params(cfg), cfg, slots=2, block_T=8)
 
 
 def test_slot_pool_bounds_and_validation():
     cfg = _cfg()
-    pool = tfm.DecodeSlotPool(_params(cfg), cfg, slots=1, max_len=16)
+    pool = tfm.PagedDecodeSlotPool(_params(cfg), cfg, slots=1, max_len=16,
+                                   block_T=8)
     with pytest.raises(ValueError, match="exceeds"):
         pool.admit(list(range(1, 15)), max_new_tokens=8)
     with pytest.raises(ValueError, match="at least one token"):
@@ -108,29 +109,29 @@ def test_membership_churn_single_decode_signature_and_parity():
     short_a = rs.randint(1, 97, 6).tolist()
     short_b = rs.randint(1, 97, 2).tolist()
 
-    pool = tfm.DecodeSlotPool(params, cfg, slots=2)
+    pool = tfm.PagedDecodeSlotPool(params, cfg, slots=2, block_T=8)
     slot_l, first_l = pool.admit(long_p, max_new_tokens=10)
     toks_l = [first_l]
     # run the long sequence alone for 3 steps
     for _ in range(3):
-        toks_l.append(pool.step()[slot_l])
+        toks_l.extend(pool.step()[slot_l])
     traces_mid = pool.decode_traces
     # admit a short rider mid-flight (membership 1 -> 2)
     slot_a, first_a = pool.admit(short_a, max_new_tokens=3)
     toks_a = [first_a]
     while len(toks_a) < 3:
         out = pool.step()
-        toks_l.append(out[slot_l])
-        toks_a.append(out[slot_a])
+        toks_l.extend(out[slot_l])
+        toks_a.extend(out[slot_a])
     pool.release(slot_a)  # retire the rider (membership 2 -> 1)
     # refill the freed slot with a different sequence
     slot_b, first_b = pool.admit(short_b, max_new_tokens=2)
     toks_b = [first_b]
     while len(toks_l) < 10:
         out = pool.step()
-        toks_l.append(out[slot_l])
+        toks_l.extend(out[slot_l])
         if slot_b in out and len(toks_b) < 2:
-            toks_b.append(out[slot_b])
+            toks_b.extend(out[slot_b])
             if len(toks_b) == 2:
                 pool.release(slot_b)
     pool.release(slot_l)
@@ -146,7 +147,8 @@ def test_membership_churn_single_decode_signature_and_parity():
 def test_prompt_bucketing_bounds_prefill_signatures():
     cfg = _cfg()
     params = _params(cfg)
-    pool = tfm.DecodeSlotPool(params, cfg, slots=4, min_prompt_bucket=8)
+    pool = tfm.PagedDecodeSlotPool(params, cfg, slots=4, block_T=8,
+                                   min_prompt_bucket=8)
     rs = np.random.RandomState(3)
     # lengths 2..8 share the 8-bucket; 9..16 the 16-bucket
     for n in (2, 5, 8, 3):
@@ -187,7 +189,7 @@ def test_failed_donated_call_resets_the_pool_not_poisons_it():
     later admit/step into 'Array has been deleted'."""
     cfg = _cfg()
     params = _params(cfg)
-    pool = tfm.DecodeSlotPool(params, cfg, slots=2)
+    pool = tfm.PagedDecodeSlotPool(params, cfg, slots=2, block_T=8)
     pool.admit([3, 1, 4], max_new_tokens=4)
 
     def boom(*a, **k):

@@ -183,18 +183,18 @@ def test_step_writes_only_the_cells_of_live_windows():
     pool = _small_pool()
     rs = np.random.RandomState(3)
     # stale K/V everywhere, as a long-running server's arena holds
-    pool._kc = jnp.asarray(rs.randn(*pool._kc.shape), pool._kc.dtype)
-    pool._vc = jnp.asarray(rs.randn(*pool._vc.shape), pool._vc.dtype)
+    pool._arenas = tuple(jnp.asarray(rs.randn(*a.shape), a.dtype)
+                         for a in pool._arenas)
     a, _ = pool.admit(rs.randint(1, 61, 11).tolist(), max_new_tokens=6)
     b, _ = pool.admit(rs.randint(1, 61, 5).tolist(), max_new_tokens=6)
     c, _ = pool.admit(rs.randint(1, 61, 20).tolist(), max_new_tokens=6)
     pool.release(b)  # a dead slot between two live ones
     for _ in range(3):
-        before = [np.asarray(pool._kc).copy(), np.asarray(pool._vc).copy()]
+        before = [np.asarray(a).copy() for a in pool._arenas]
         written = {(int(pool._tables[s, pool._positions[s] // pool.block_T]),
                     int(pool._positions[s] % pool.block_T)) for s in (a, c)}
         pool.step()
-        for old, new in zip(before, [np.asarray(pool._kc), np.asarray(pool._vc)]):
+        for old, new in zip(before, map(np.asarray, pool._arenas)):
             changed = np.argwhere((old != new).any(axis=(0, 3)))  # (block, cell)
             assert {(int(blk), int(cell)) for blk, cell in changed} == written
 

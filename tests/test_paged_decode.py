@@ -1,9 +1,9 @@
 """Block-paged KV cache, CoW prefix sharing, speculative decoding (ISSUE 17).
 
-The contracts under test: paged decode is TOKEN-IDENTICAL to the dense-era
-reference (and to naive full-forward generation) behind the same
-one-signature decode step; residency is priced in BLOCKS at admission (the
-429/400 paths fire at the door, never mid-decode); copy-on-write prefix
+The contracts under test: paged decode is TOKEN-IDENTICAL to naive
+full-forward generation behind one decode-step signature; residency is
+priced in BLOCKS at admission (the 429/400 paths fire at the door, never
+mid-decode); copy-on-write prefix
 sharing deduplicates physical blocks without changing any sequence's
 output; and speculative decoding changes wall clock, never text.
 """
@@ -15,6 +15,7 @@ import urllib.request
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.models import transformer as tfm
@@ -39,40 +40,47 @@ def _cfg(**kw):
 
 
 def _params(cfg, seed=0):
-    import jax
-
     return tfm.init_params(jax.random.key(seed), cfg)
 
 
 CFG = _cfg()
 PARAMS = _params(CFG)
-_SHARED_DENSE = []
+_NAIVE = {}  # prompt -> its greedy continuation under PARAMS, as far as asked
+_forward = jax.jit(lambda params, tokens: tfm.forward(params, tokens, CFG))
 
 
-def _dense_generate(params, cfg, prompts, max_new):
-    """The PR 12 dense-era reference path, pinned explicitly.  References
-    against the shared default model reuse ONE compiled dense pool so the
-    tier-1 suite does not pay a fresh XLA compile per test."""
-    if params is PARAMS:
-        if not _SHARED_DENSE:
-            _SHARED_DENSE.append(tfm.DecodeSlotPool(PARAMS, CFG, slots=6))
-        return tfm.generate(params, prompts, max_new, cfg,
-                            pool=_SHARED_DENSE[0])
-    pool = tfm.DecodeSlotPool(params, cfg, slots=max(2, len(prompts)))
-    return tfm.generate(params, prompts, max_new, cfg, pool=pool)
+def _naive_generate(params, cfg, prompts, max_new):
+    """The reference: greedy decoding by re-running the FULL forward for
+    every token, so it shares no cache code with the pool.  One program:
+    rows are padded to ``max_len``, which a causal model's logits at the
+    last real position cannot see (tests/test_generate.py holds the pool to
+    the unpadded forward).  Continuations under the shared default model are
+    computed once a module (greedy decoding is prefix-stable: a longer ask
+    extends a shorter one)."""
+    assert cfg is CFG
+    out = []
+    for prompt in prompts:
+        done = _NAIVE.setdefault(tuple(prompt), []) if params is PARAMS else []
+        while len(done) < max_new:
+            row = np.zeros((1, CFG.max_len), np.int32)
+            n = len(prompt) + len(done)
+            row[0, :n] = list(prompt) + done
+            done.append(int(jnp.argmax(_forward(params, row)[0, n - 1])))
+        out.append(done[:max_new])
+    return out
 
 
 # ------------------------------------------------------------------ tentpole
 
 
-def test_paged_decode_matches_dense_and_naive_under_churn():
-    """The parity pin: paged generation == dense-era generation, token for
+def test_paged_decode_matches_naive_under_churn():
+    """The parity pin: paged generation == repeated full forwards, token for
     token, over ragged prompts — and the paged decode step is traced
     exactly ONCE whatever the admission/retirement churn."""
     cfg, params = CFG, PARAMS
     rs = np.random.RandomState(1)
     prompts = [rs.randint(1, 97, n).tolist() for n in (3, 9, 17, 5, 12, 2)]
-    expected = _dense_generate(params, cfg, prompts, 8)
+    expected = _naive_generate(params, cfg, prompts, 8)
 
     pool = tfm.PagedDecodeSlotPool(params, cfg, slots=3, block_T=8)
     got = tfm.generate(params, prompts, 8, cfg, pool=pool)
@@ -85,7 +93,7 @@ def test_paged_decode_matches_dense_and_naive_under_churn():
 
 def test_generate_routes_through_paged_pool_by_default(monkeypatch):
     """Offline generate() without an explicit pool builds a paged pool (the
-    satellite routing pin) — and the output still matches the dense era."""
+    satellite routing pin) — and the output still matches the reference."""
     cfg, params = CFG, PARAMS
     built = {}
     real = tfm.PagedDecodeSlotPool
@@ -99,7 +107,7 @@ def test_generate_routes_through_paged_pool_by_default(monkeypatch):
     prompts = [[5, 9, 2], [7, 3]]
     out = tfm.generate(params, prompts, 6, cfg)
     assert built, "default generate() did not build a PagedDecodeSlotPool"
-    assert out == _dense_generate(params, cfg, prompts, 6)
+    assert out == _naive_generate(params, cfg, prompts, 6)
 
 
 def test_block_accounting_and_admission_priced_in_blocks():
@@ -135,7 +143,7 @@ def test_cow_prefix_sharing_dedups_blocks_without_changing_tokens():
     cfg, params = CFG, PARAMS
     rs = np.random.RandomState(3)
     prefix = rs.randint(1, 97, 16).tolist()  # two full 8-blocks
-    solo_a, solo_b = _dense_generate(params, cfg,
+    solo_a, solo_b = _naive_generate(params, cfg,
                                      [prefix + [11, 12],
                                       prefix + [13, 14, 15]], 6)
     a, b = prefix + [11, 12], prefix + [13, 14, 15]
@@ -196,15 +204,14 @@ def test_speculative_decode_is_token_identical(draft_kind):
     eos_prompt = [5, 9, 2]
     if draft_kind == "identity_tail":
         params, draft_params, draft_cfg = _identity_tail_draft(PARAMS, cfg, 1)
-        # one off-default dense pool serves both the parity and eos refs:
         # greedy decode is prefix-stable, so max_new=8 covers max_new=7
-        refs = _dense_generate(params, cfg, prompts + [eos_prompt], 8)
+        refs = _naive_generate(params, cfg, prompts + [eos_prompt], 8)
         expected, eos_ref = [r[:max_new] for r in refs[:3]], refs[3]
     else:
         params = PARAMS
         draft_cfg = _cfg(n_layers=1)
         draft_params = _params(draft_cfg, seed=9)  # unrelated weights
-        expected = _dense_generate(params, cfg, prompts, max_new)
+        expected = _naive_generate(params, cfg, prompts, max_new)
 
     pool = tfm.PagedDecodeSlotPool(
         params, cfg, slots=3, block_T=8,
@@ -218,7 +225,7 @@ def test_speculative_decode_is_token_identical(draft_kind):
     if draft_kind == "identity_tail":
         assert rate == pytest.approx(1.0)
         # EOS inside an accepted window retires the sequence AT the eos,
-        # not at the window edge — same truncation the dense pool applies
+        # not at the window edge
         eos = eos_ref[2]
         cut = eos_ref.index(eos) + 1
         out = tfm.generate(params, [eos_prompt], 8, cfg, pool=pool,
@@ -254,7 +261,7 @@ def test_failed_donated_step_resets_arena_and_executor_evicts_riders():
     assert pool.block_stats()["blocks_free"] == pool.total_blocks
     prompt = [5, 9, 2]
     out = tfm.generate(params, [prompt], 4, cfg, pool=pool)
-    assert out == _dense_generate(params, cfg, [prompt], 4)
+    assert out == _naive_generate(params, cfg, [prompt], 4)
 
     reg = MetricsRegistry()
     ex = GenerativeInferenceExecutor(pool, max_queue=8, registry=reg).start()
@@ -380,3 +387,31 @@ def test_trace_spec_shared_prefix_validation():
     with pytest.raises(ValueError, match="prompt_vocab"):
         TraceSpec(duration_s=1.0, base_rate=1.0, prefix_tenants=2,
                   prompt_vocab=1)
+
+
+def _imports_of(path, package):
+    """Every name a module's import statements (at any depth) reach, absolute."""
+    import ast
+
+    package = package.split(".")
+    reached = set()
+    for node in ast.walk(ast.parse(open(path).read())):
+        if isinstance(node, ast.Import):
+            reached.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            base = package[:len(package) - node.level + 1] if node.level else []
+            module = ".".join(base + ([node.module] if node.module else []))
+            reached.update(f"{module}.{a.name}" for a in node.names)
+    return reached
+
+
+def test_the_pool_module_imports_no_model_and_no_kernel():
+    """Arrows one way: ``models/transformer.py`` and ``models/kimi_k2.py``
+    import the pool's module (for the arena writers); it reaches a model
+    only through ``cfg.decode_family()``."""
+    from deeplearning4j_tpu.models import paged_decode
+
+    reached = _imports_of(paged_decode.__file__, paged_decode.__package__)
+    assert "deeplearning4j_tpu.monitoring.trace.span" in reached  # the walk sees
+    assert not [m for m in reached if m.startswith(
+        ("deeplearning4j_tpu.models", "deeplearning4j_tpu.kernels"))], reached

@@ -75,6 +75,13 @@ def _counter_values(reg, name):
     return {tuple(s["labels"].values()): s["value"] for s in snap["series"]}
 
 
+def _wait_handlers_done(server):
+    """A handler counts its request AFTER it has written the response: wait
+    until none is in flight before reading ``tdl_inference_requests_total``."""
+    with server._inflight_cv:
+        assert server._inflight_cv.wait_for(lambda: server._inflight == 0, 10.0)
+
+
 def _post(port, body, headers=None, timeout=15):
     req = urllib.request.Request(
         f"http://127.0.0.1:{port}/predict", data=body,
@@ -234,6 +241,7 @@ def test_server_deadline_header_yields_504_not_hang():
         assert ei.value.code == 504
         assert elapsed < 0.45  # answered at the deadline, not after the model
         assert "deadline" in json.loads(ei.value.read())["error"]
+        _wait_handlers_done(server)
         codes = _counter_values(reg, "tdl_inference_requests_total")
         assert codes[("504",)] == 1
     finally:

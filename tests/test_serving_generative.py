@@ -387,7 +387,7 @@ def test_server_generative_with_real_transformer_pool():
         compute_dtype=jnp.float32, attn_impl="xla", vocab_size=64,
         max_len=32, d_model=32, n_heads=2, n_layers=2, d_ff=64)
     params = tfm.init_params(jax.random.key(0), cfg)
-    pool = tfm.DecodeSlotPool(params, cfg, slots=2)
+    pool = tfm.PagedDecodeSlotPool(params, cfg, slots=2, block_T=8)
     prompt = [3, 11, 7]
     expected = tfm.generate(params, [prompt], 5, cfg)[0]
 
