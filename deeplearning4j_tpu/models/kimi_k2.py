@@ -414,6 +414,11 @@ class LatentDecodeFamily:
         self.n_sparse_layers = sum(is_sparse(cfg, l) for l in range(self.n_layers))
         self.n_resident_experts = cfg.n_resident_experts
 
+    def resident(self, params):
+        """This family's weights are served in the dtype they come in
+        (``param_dtype``, no masters): the resident tree IS the caller's."""
+        return params
+
     def prefill(self, params, tokens, length):
         """tokens [1, Tb], length scalar -> (last live hidden [D], rows: one
         [L, Tb, width] an arena)."""

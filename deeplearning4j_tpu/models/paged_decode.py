@@ -61,6 +61,13 @@ answers, stated here once (no base class: two implementations and this list):
   token stores in a block: one arena ``[L, n_blocks, block_T, width]`` a
   width) and ``cache_dtype``; ``stat_names`` (the int32 counters a step
   returns, which come back in the one fetch that brings the tokens);
+- ``resident(params) -> params``: what the family's programs read, made ONCE
+  from what the caller holds when the pool is constructed, and kept as
+  ``pool.params``: every leaf that a step would cast before use is cast here
+  (a transformer's float32 master matmul weights into the compute dtype), a
+  leaf already in its dtype is the SAME array, shapes map to shapes. A pool
+  serves from this resident copy, so a caller who only serves may drop its
+  masters after constructing the pool; ``params`` below is this tree;
 - ``prefill(params, tokens [1, Tb], length) -> (hidden state at length - 1
   [D], rows: one [L, Tb, width] an arena)``;
 - ``decode_window(params, tokens [S, W], positions [S, W], arenas, tables)
@@ -76,6 +83,7 @@ driver) is the only caller — no internal locking.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -236,7 +244,9 @@ class PagedDecodeSlotPool:
         if (draft_params is None) != (draft_cfg is None):
             raise ValueError("speculative decoding needs BOTH draft_params "
                              "and draft_cfg (or neither)")
-        self.params = params
+        # the programs read a resident copy made once, in the dtypes a step
+        # computes in: never the caller's masters, cast again every token
+        self.params = fam.resident(params)
         self.cfg = cfg
         self.slots = slots
         self.block_T = block_T
@@ -248,7 +258,8 @@ class PagedDecodeSlotPool:
         # bucket sizes must stay block-aligned so prefill scatter is whole blocks
         self.min_prompt_bucket = max(1, min_prompt_bucket, block_T)
 
-        self.draft_params = draft_params
+        self.draft_params = (dfam.resident(draft_params)
+                             if draft_cfg is not None else None)
         self.draft_cfg = draft_cfg
         self.spec_tokens = int(spec_tokens) if draft_cfg is not None else 0
         if draft_cfg is not None:
@@ -266,6 +277,10 @@ class PagedDecodeSlotPool:
                     f"max_len {self.max_len}")
 
         self.family = fam
+        # bytes of the distinct leaves the decode program is handed
+        self._resident_weight_bytes = sum({
+            id(x): math.prod(x.shape) * jnp.dtype(x.dtype).itemsize
+            for x in jax.tree.leaves((self.params, self.draft_params))}.values())
         self._alloc = BlockAllocator(self.n_blocks)
         self._arenas = tuple(self._new_arena(cfg))
         self._draft_arenas = (tuple(self._new_arena(draft_cfg))
@@ -458,9 +473,12 @@ class PagedDecodeSlotPool:
         window's last position) and ``kv_blocks_mapped`` (``slots x
         max_blocks``: what a dense gather through the tables would visit).
         ``kv_cache_bytes_per_token`` is what one token stores over all layers
-        and arenas. A family whose steps count (``stat_names``) adds what it
-        makes of the running sums (``cumulative_stats``: the latent family's
-        ``moe_*`` counters); for another family they are absent."""
+        and arenas; ``resident_weight_bytes`` the bytes of every leaf of the
+        resident tree(s) the decode program is handed (the draft's too; a leaf
+        two views share counts once). A family whose steps count
+        (``stat_names``) adds what it makes of the running sums
+        (``cumulative_stats``: the latent family's ``moe_*`` counters); for
+        another family they are absent."""
         rc = self._alloc.refcount[1:]  # trash block is bookkeeping, not capacity
         fam = self.family
         return {
@@ -468,6 +486,7 @@ class PagedDecodeSlotPool:
             "kv_cache_bytes_per_token": int(
                 fam.n_layers * sum(fam.cache_widths)
                 * jnp.dtype(fam.cache_dtype).itemsize),
+            "resident_weight_bytes": self._resident_weight_bytes,
             "blocks_total": self.total_blocks,
             "blocks_free": self._alloc.free_blocks,
             "cow_shared_blocks": int((rc > 1).sum()),

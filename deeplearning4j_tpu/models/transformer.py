@@ -516,6 +516,20 @@ def prefill_forward(params, tokens, cfg: TransformerConfig, *, segments=None,
     return h, jnp.stack(ks), jnp.stack(vs)
 
 
+# a block's leaves that ``_layer`` casts to the compute dtype at every use
+_BLOCK_MATMUL_LEAVES = ("qkv_w", "qkv_b", "out_w", "out_b",
+                        "ffn_w1", "ffn_b1", "ffn_w2", "ffn_b2")
+
+
+def _in_dtype(x, dtype):
+    """``x`` in ``dtype``: itself when it already is, a shape for a shape."""
+    if x.dtype == dtype:
+        return x
+    if isinstance(x, jax.ShapeDtypeStruct):
+        return x.update(dtype=dtype)
+    return jnp.asarray(x, dtype)
+
+
 class TransformerDecodeFamily:
     """This file's layers for the slot pool (the protocol is in
     ``paged_decode``'s docstring): every head has its own K and V, so a token
@@ -531,6 +545,23 @@ class TransformerDecodeFamily:
         self.cache_widths = (cfg.n_heads * cfg.head_dim,) * 2
         self.cache_dtype = cfg.compute_dtype
 
+    def resident(self, params):
+        """The tree this family's programs read, made once from the caller's
+        (float32 master) params: every leaf that each use site casts to the
+        compute dtype before use, cast now, so a step reads half the bytes and
+        converts nothing. ``embed`` passes through (the lookup reads float32
+        rows); the tied table as the HEAD reads it is a second, compute-dtype
+        leaf under ``head``. A leaf already in its dtype is the same array."""
+        cd = self.cfg.compute_dtype
+
+        def cast(p, names):
+            return {k: _in_dtype(v, cd) if k in names else v for k, v in p.items()}
+
+        return {**params,
+                "blocks": [cast(p, _BLOCK_MATMUL_LEAVES) for p in params["blocks"]],
+                "mlm": cast(params["mlm"], ("w", "b")),
+                "head": {"tok": _in_dtype(params["embed"]["tok"], cd)}}
+
     def prefill(self, params, tokens, length):
         """tokens [1, Tb] -> (hidden state at ``length - 1`` [D], the rows to
         store: K and V, each [L, Tb, H*hd])."""
@@ -543,7 +574,9 @@ class TransformerDecodeFamily:
         return h[0, length - 1], (rows(ks), rows(vs))
 
     def head(self, params, h):
-        return mlm_head(params, h, self.cfg)
+        # the head's view of the resident tree: the table in the compute dtype
+        return mlm_head({"mlm": params["mlm"], "embed": params["head"]}, h,
+                        self.cfg)
 
     def decode_window(self, params, tokens, positions, arenas, tables):
         """tokens / positions [S, W] -> (logits [S, W, V] fp32, arenas, None).
@@ -557,7 +590,7 @@ class TransformerDecodeFamily:
         for l, p in enumerate(params["blocks"]):
             h, (kc, vc) = _layer(cfg, p, h,
                                  _paged_window(cfg, kc, vc, l, tables, limits))
-        return mlm_head(params, h, cfg), (kc, vc), None
+        return self.head(params, h), (kc, vc), None
 
     def cumulative_stats(self, sums, steps) -> Dict[str, int]:
         return {}

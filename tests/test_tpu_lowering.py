@@ -60,16 +60,18 @@ def pool_and_params(one_chip):
         n_layers=LAYERS, d_ff=4 * D_MODEL, causal=True, dropout=0.0,
         compute_dtype=jnp.bfloat16, norm_position="pre")
     shapes = jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg))
-    params = jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), shapes)
+    masters = jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), shapes)
     mp = pytest.MonkeyPatch()
     mp.setattr(PagedDecodeSlotPool, "_new_arena", lambda self, cfg: (None, None))
     try:
-        pool = PagedDecodeSlotPool(shapes, cfg, slots=SLOTS, block_T=BLOCK_T,
+        pool = PagedDecodeSlotPool(masters, cfg, slots=SLOTS, block_T=BLOCK_T,
                                    max_len=MAX_LEN)
     finally:
         mp.undo()
     assert pool.n_blocks == N_BLOCKS and pool.max_blocks == MAX_BLOCKS
-    return pool, params
+    # the programs are lowered from what the pool holds: the resident shapes
+    # its family made of the float32 masters' shapes
+    return pool, pool.params
 
 
 def _arena_ops(hlo_text):
@@ -87,6 +89,26 @@ def _arena_ops(hlo_text):
             if m.group(2) not in ("parameter", "get-tuple-element", "bitcast",
                                   "tuple", "custom-call"):
                 found.append((m.group(2), dims))
+    return found
+
+
+# float32 tensors shaped like a matmul weight of the family: [D,3D], [D,D],
+# [D,4D], [4D,D]; the tied table [V,D] is one too where the HEAD reads it
+WEIGHT_SHAPES = [(D_MODEL, 3 * D_MODEL), (D_MODEL, D_MODEL),
+                 (D_MODEL, 4 * D_MODEL), (4 * D_MODEL, D_MODEL)]
+TABLE_SHAPE = (50257, D_MODEL)
+
+
+def _f32_weight_parameters(lowered):
+    """Shapes of the lowered program's float32 arguments that are shaped like
+    a matmul weight (the table counts once: the lookup's float32 copy)."""
+    main = lowered.as_text()
+    main = main[main.index("func.func public @main("):]
+    main = main[:main.index("\n")]
+    found = []
+    for shape in WEIGHT_SHAPES + [TABLE_SHAPE]:
+        found += [shape] * len(re.findall(
+            r"tensor<%dx%dxf32>" % shape, main))
     return found
 
 
@@ -124,6 +146,17 @@ def test_decode_program_compiles_for_the_chip_with_arenas_in_place(
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == LAYERS
     assert "paged_decode_attn" in text
+    # served from weights cast once: the only float32 argument shaped like a
+    # matmul weight is the table the embedding LOOKUP gathers rows of; the
+    # compiled step holds no float32 tensor of a block weight's shape and no
+    # convert INTO a weight's shape (operands print without their shapes, so
+    # a convert of a float32 weight shows as its compute-dtype result)
+    assert _f32_weight_parameters(lowered) == [TABLE_SHAPE]
+    for shape in WEIGHT_SHAPES:
+        assert "f32[%d,%d]" % shape not in text, shape
+    for shape in WEIGHT_SHAPES + [TABLE_SHAPE]:
+        converts = re.findall(r"= bf16\[%d,%d\]\S* convert\(" % shape, text)
+        assert not converts, converts
 
 
 @pytest.mark.parametrize("bucket", [128, 1024])
@@ -135,6 +168,7 @@ def test_prefill_program_compiles_for_the_chip_with_arenas_in_place(
         params, arena, arena, _shape(one_chip, (bucket // BLOCK_T,), jnp.int32),
         _shape(one_chip, (1, bucket), jnp.int32), _shape(one_chip, (), jnp.int32))
     _assert_in_place(lowered, lowered.compile(), n_arenas=2)
+    assert _f32_weight_parameters(lowered) == [TABLE_SHAPE]
 
 
 def test_copy_on_write_program_compiles_for_the_chip_in_place(
