@@ -25,12 +25,14 @@ from .vgg import VGG16, VGG19
 from .text_lstm import TextGenerationLSTM
 from .zoo_ext import AlexNet, Darknet19, SqueezeNet, UNet, Xception
 from .kimi_k2 import KimiK2Config
+from .keye_vl import KeyeVLConfig  # served only, as kimi_k2: no loss, no backward
 from .vae import VariationalAutoencoder
 from .yolo import TinyYOLO, Yolo2OutputLayer
 
 __all__ = [
     "AlexNet", "Darknet19", "SqueezeNet", "UNet", "Xception",
     "KimiK2Config",
+    "KeyeVLConfig",
     "VariationalAutoencoder", "TinyYOLO", "Yolo2OutputLayer",
     "TransformerConfig",
     "transformer_forward",

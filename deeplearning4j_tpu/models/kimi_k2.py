@@ -85,6 +85,9 @@ class KimiK2Config:
 
     # what the slot pool asks of any config
     causal = True
+    # what the expert layer (``route``) asks of any config: this family scores
+    # a logit with a sigmoid
+    scoring_func = "sigmoid"
 
     @property
     def max_len(self) -> int:
@@ -229,27 +232,35 @@ def _swiglu(p, u):
     return _mm(a.astype(u.dtype), p["wd"])
 
 
-def _scores(p, u):
-    """``sigmoid(u Wr)`` in float32: u [N, D] -> [N, n_routed]."""
-    return jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32), p["router"],
-                                  precision=jax.lax.Precision.HIGHEST))
+def _scores(cfg, p, u):
+    """The router's scores of u [N, D] in float32 -> [N, n_routed]:
+    ``sigmoid(u Wr)`` a logit, or ``softmax(u Wr)`` over the router's width,
+    by ``cfg.scoring_func``."""
+    logits = jnp.dot(u.astype(jnp.float32), p["router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    if cfg.scoring_func == "softmax":
+        return jax.nn.softmax(logits, axis=-1)
+    return jax.nn.sigmoid(logits)
 
 
-def route(cfg: KimiK2Config, p, u):
+def route(cfg, p, u):
     """u [N, D] -> (chosen experts [N, k] int32, weights [N, k] float32).
 
-    Scores are ``sigmoid(u Wr)`` in float32; the choice is by score + bias,
-    the weights are the UNBIASED scores of the chosen, normalised over all of
-    them (resident or not) and scaled. ``n_group = topk_group = 1``: the
-    group step of ``noaux_tc`` is the identity."""
-    sc = _scores(p, u)
-    _, idx = jax.lax.top_k(sc + p["router_bias"], cfg.num_experts_per_tok)
+    Scores are float32 (``_scores``); the choice is by score + the correction
+    bias where the layer has one (``router_bias``), the weights are the
+    UNBIASED scores of the chosen, normalised over all of them (resident or
+    not), and scaled. ``n_group = topk_group = 1``: the group step of
+    ``noaux_tc`` is the identity. A config of another family (``keye_vl``:
+    softmax scores, no bias, scale 1) answers the same attributes."""
+    sc = _scores(cfg, p, u)
+    biased = sc + p["router_bias"] if "router_bias" in p else sc
+    _, idx = jax.lax.top_k(biased, cfg.num_experts_per_tok)
     chosen = jnp.take_along_axis(sc, idx, axis=-1)
     w = chosen / (jnp.sum(chosen, -1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), w * cfg.routed_scaling_factor
 
 
-def expert_tile(cfg: KimiK2Config, tokens: int) -> int:
+def expert_tile(cfg, tokens: int) -> int:
     """Rows of one trip through an expert's weights, for a call of
     ``tokens`` rows: a quarter of them (an expert sees ``tokens * k /
     n_routed`` on average, so one trip holds all but the most uneven
@@ -258,12 +269,20 @@ def expert_tile(cfg: KimiK2Config, tokens: int) -> int:
     return min(cfg.moe_tile, max(16, tokens // 4))
 
 
-def resident_experts(cfg: KimiK2Config, p, u, idx, w, live):
+def resident_experts(cfg, p, u, idx, w, live):
     """The resident experts' part of ``sum_e w_e E_e(u)``: u [N, D] (weights'
     dtype), idx / w [N, k], live [N] bool (a dead slot or a padded position
     routes nowhere). Returns (out [N, D] float32, stats int32 [4] in
     ``MOE_STATS`` order). Every live assignment to a resident expert is
-    computed: nothing is dropped, whatever the imbalance."""
+    computed: nothing is dropped, whatever the imbalance.
+
+    ``p["experts"]`` is ONE tree whose leaves stack the experts on a leading
+    axis (``keye_vl``: one loop over all the trips, the expert's index data;
+    a loop an expert was 25 s a layer to compile at 128 experts, most of them
+    loops that never run), or a list, one tree of buffers an expert and a
+    loop each (``kimi_k2``, whose accepted check and reference index the
+    list, and whose 6.3 GB of experts cannot be stacked beside it on the
+    chip: PERF.md, PR 35). The rows' arithmetic (``rows``) is one."""
     N, k = idx.shape
     E, A = cfg.n_resident_experts, N * k
     tile = expert_tile(cfg, N)
@@ -277,23 +296,43 @@ def resident_experts(cfg: KimiK2Config, p, u, idx, w, live):
     starts = jnp.cumsum(counts) - counts
     token_ids = jnp.arange(N, dtype=jnp.int32)[:, None]
 
+    def rows(expert, e, t, out):
+        """Trip ``t`` through expert ``e``'s sorted rows, added to ``out``."""
+        pos = starts[e] + t * tile + jnp.arange(tile, dtype=jnp.int32)
+        valid = pos < starts[e] + counts[e]
+        pos = jnp.minimum(pos, A - 1)
+        who = tok[pos]
+        y = _swiglu(expert, u[who]) * jnp.where(valid, w_sorted[pos], 0.0)[:, None]
+        # back to token order as a matmul: rows of one expert are
+        # distinct tokens, so this is a permutation, not a sum
+        back = ((who[None, :] == token_ids) & valid[None, :]).astype(u.dtype)
+        return out + _mm(back, y.astype(u.dtype))
+
     out = jnp.zeros((N, u.shape[-1]), jnp.float32)
-    for e in range(E):
-        expert = p["experts"][e]
+    trips = -(-counts // tile)
+    if isinstance(p["experts"], dict):
+        stacked, done = p["experts"], jnp.cumsum(trips)
+        # every trip's expert and its place among that expert's trips, worked
+        # out for all trips at once: inside the loop a search and a handful
+        # of scalar reads a trip cost as much as the trip's matmuls
+        # (PERF.md, PR 35). No more trips than full tiles + one an expert.
+        i = jnp.arange(A // tile + E, dtype=jnp.int32)
+        of = jnp.minimum(jnp.sum(i[:, None] >= done[None, :], axis=1), E - 1).astype(jnp.int32)
+        plan = jnp.stack([of, i - (done - trips)[of]], axis=1)
 
-        def rows(t, out, e=e, expert=expert):
-            pos = starts[e] + t * tile + jnp.arange(tile, dtype=jnp.int32)
-            valid = pos < starts[e] + counts[e]
-            pos = jnp.minimum(pos, A - 1)
-            who = tok[pos]
-            y = _swiglu(expert, u[who]) * jnp.where(valid, w_sorted[pos], 0.0)[:, None]
-            # back to token order as a matmul: rows of one expert are
-            # distinct tokens, so this is a permutation, not a sum
-            back = ((who[None, :] == token_ids) & valid[None, :]).astype(u.dtype)
-            return out + _mm(back, y.astype(u.dtype))
+        def trip(i, out):
+            # the expert's weights are read where they lie: the index goes
+            # into the matmul, no slice is made
+            e, t = plan[i, 0], plan[i, 1]
+            expert = {name: x[e] for name, x in stacked.items()}
+            return rows(expert, e, t, out)
 
-        # zero trips for an expert nobody chose: its weights are not read
-        out = jax.lax.fori_loop(0, -(-counts[e] // tile), rows, out)
+        out = jax.lax.fori_loop(0, done[-1], trip, out)
+    else:
+        for e, expert in enumerate(p["experts"]):
+            # zero trips for an expert nobody chose: its weights are not read
+            out = jax.lax.fori_loop(
+                0, trips[e], lambda t, out, e=e, expert=expert: rows(expert, e, t, out), out)
 
     stats = jnp.stack([jnp.sum(live), jnp.sum(counts), jnp.sum(counts > 0),
                        jnp.max(counts)]).astype(jnp.int32)

@@ -49,12 +49,14 @@ share a handful of prefill executables.
 **Model families.** The pool does not know a layer, and this module imports
 no model and no kernel. The config it is given answers ``decode_family()``
 with an object of its model's file (``transformer.TransformerDecodeFamily``:
-K and V arenas of ``H*hd``; ``kimi_k2.LatentDecodeFamily``: one latent arena),
+K and V arenas of ``H*hd``; ``kimi_k2.LatentDecodeFamily``: one latent arena;
+``keye_vl.SparseGQADecodeFamily``: K, V and an index-key arena, and XLA's
+gather of the rows its indexer selected in place of a kernel),
 which writes its rows with :func:`_write_window` and attends through the
 tables with a kernel of ``kernels/paged_attention.py``. The families share the
 allocator, the tables, the prefix index, copy-on-write, admit / step /
 release, the counters and the donated in-place programs below. What a family
-answers, stated here once (no base class: two implementations and this list):
+answers, stated here once (no base class: three implementations and this list):
 
 - ``name``; ``speculative`` (whether ``decode_window`` takes W > 1 tokens a
   slot, which a verify window needs); ``n_layers``; ``cache_widths`` (what one
@@ -496,6 +498,16 @@ class PagedDecodeSlotPool:
             "kv_blocks_read": self.kv_blocks_read,
             "kv_blocks_mapped": self.kv_blocks_mapped,
         }
+
+    def cached_rows(self, slot: int, n: int):
+        """What the arenas hold of ``slot``'s first ``n`` positions, through
+        its block table: one [L, n, width] array an arena. For checks and
+        tests (a copy; the arenas stay where they are)."""
+        if not self._active[slot]:
+            raise ValueError(f"slot {slot} is not active")
+        pos = np.arange(n)
+        block = self._tables[slot, pos // self.block_T]
+        return tuple(a[:, block, pos % self.block_T] for a in self._arenas)
 
     # -- admission planning ------------------------------------------------
 

@@ -326,3 +326,88 @@ def test_flash_forward_compiles_for_the_chip_at_kimis_prefill_block(
         q, k, v, causal=True, scale=0.1, block_q=1024, block_k=1024)).lower(
             qkv, qkv, qkv).compile()
     assert "flash_fwd" in compiled.as_text()
+
+
+# -- the keye_vl family at Keye-VL-2.0-30B-A3B's published widths (ISSUE 35) ---
+#
+# benchmark/configs/keye-vl-2-30b-a3b.json and benchmark/traffic/longdoc-qa.json:
+# 16 slots x 17,408 positions in blocks of 32, THREE arenas (K and V of 4 x 128
+# lanes, the index key's 64 values in 128 lanes), all 128 experts stacked, the
+# whole vocabulary; the depth is cut to 2 for the test's time.
+
+KV_SLOTS, KV_MAX_LEN, KV_LAYERS = 16, 17408, 2
+KV_MAX_BLOCKS = KV_MAX_LEN // BLOCK_T
+KV_N_BLOCKS = 1 + KV_SLOTS * KV_MAX_BLOCKS
+KV_ARENAS = [(KV_LAYERS, KV_N_BLOCKS, BLOCK_T, w) for w in (512, 512, 128)]
+
+
+@pytest.fixture(scope="module")
+def sparse_pool_and_params(one_chip):
+    from deeplearning4j_tpu.models import keye_vl as kv
+
+    cfg = kv.KeyeVLConfig(num_hidden_layers=KV_LAYERS, max_position_embeddings=KV_MAX_LEN)
+    shapes = jax.eval_shape(lambda: kv.init_params(jax.random.key(0), cfg))
+    params = jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), shapes)
+    mp = pytest.MonkeyPatch()
+    mp.setattr(PagedDecodeSlotPool, "_new_arena", lambda self, cfg: (None,) * 3)
+    try:
+        pool = PagedDecodeSlotPool(shapes, cfg, slots=KV_SLOTS, block_T=BLOCK_T,
+                                   max_len=KV_MAX_LEN)
+    finally:
+        mp.undo()
+    assert pool.n_blocks == KV_N_BLOCKS and pool.family.cache_widths == (512, 512, 128)
+    return pool, params
+
+
+def _assert_three_arenas_in_place(lowered, compiled):
+    text = lowered.as_text()
+    tied = []
+    for arena in KV_ARENAS[1:]:   # K and V share a type
+        arena_type = "tensor<" + "x".join(str(d) for d in arena) + "xbf16>"
+        tied += re.findall(re.escape(arena_type) + r" \{[^%]*?tf\.aliasing_output = (\d+)",
+                           text)
+    assert len(tied) == len(set(tied)) == 3, tied
+    arena_bytes = [2 * KV_LAYERS * KV_N_BLOCKS * BLOCK_T * w for w in (512, 512, 128)]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(arena_bytes)
+    entry = compiled.as_text()
+    entry = entry[entry.index("\nENTRY "):]
+    copies = re.findall(r"= bf16\[(?:%d,)?%d,%d,(?:512|128)\]\S* copy\(" % (
+        KV_LAYERS, KV_N_BLOCKS, BLOCK_T), entry)
+    assert not copies, copies
+    return mem
+
+
+def test_sparse_decode_program_compiles_for_the_chip_with_three_arenas_in_place(
+        one_chip, on_chip_path, sparse_pool_and_params):
+    pool, params = sparse_pool_and_params
+    lowered = pool._decode_fn.lower(
+        params, *(_shape(one_chip, a, jnp.bfloat16) for a in KV_ARENAS),
+        _shape(one_chip, (KV_SLOTS, KV_MAX_BLOCKS), jnp.int32),
+        _shape(one_chip, (KV_SLOTS,), jnp.int32),
+        _shape(one_chip, (KV_SLOTS,), jnp.int32))
+    compiled = lowered.compile()
+    mem = _assert_three_arenas_in_place(lowered, compiled)
+    # nothing of a K arena's layer is made beside it: a step gathers the
+    # index keys of the mapped blocks (71 MB a layer) and 2,048 rows a slot
+    assert mem.temp_size_in_bytes < 2 * KV_N_BLOCKS * BLOCK_T * 512
+    # ONE loop a layer runs the trips of all 128 experts
+    assert len(re.findall(r"\n\s*\S+ = \([^\n]*f32\[16,2048\][^\n]* while\(",
+                          compiled.as_text())) == KV_LAYERS
+
+
+def test_sparse_prefill_program_compiles_for_the_chip_with_three_arenas_in_place(
+        one_chip, on_chip_path, sparse_pool_and_params):
+    """The 8,192 bucket (the cell's median prompt): the exact k-th score and
+    the attention under the selection's mask are Mosaic kernels, one each in
+    the loop over query chunks of a layer."""
+    pool, params = sparse_pool_and_params
+    lowered = pool._prefill_fn.lower(
+        params, *(_shape(one_chip, a, jnp.bfloat16) for a in KV_ARENAS),
+        _shape(one_chip, (8192 // BLOCK_T,), jnp.int32),
+        _shape(one_chip, (1, 8192), jnp.int32), _shape(one_chip, (), jnp.int32))
+    compiled = lowered.compile()  # a Mosaic error would be raised here
+    _assert_three_arenas_in_place(lowered, compiled)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * KV_LAYERS
+    assert "dsa_kth_score" in text and "dsa_selected_attn" in text
