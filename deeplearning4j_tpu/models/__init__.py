@@ -15,7 +15,6 @@ from .transformer import (
     forward as transformer_forward,
     init_params as transformer_init,
     loss_fn as transformer_loss,
-    partition_specs as transformer_partition_specs,
 )
 from .zoo import LeNet, SimpleCNN, ZooModel
 from .resnet import ResNet50
@@ -38,7 +37,6 @@ __all__ = [
     "transformer_forward",
     "transformer_init",
     "transformer_loss",
-    "transformer_partition_specs",
     "ZooModel",
     "LeNet",
     "SimpleCNN",

@@ -10,7 +10,9 @@ parallelism is declared with a PartitionSpec tree over a
 
 Mesh axes (any subset may be present):
 - ``dp`` — data parallel (batch sharding; gradient allreduce over ICI)
-- ``tp`` — tensor parallel (Megatron column/row splits on attention + MLP)
+- ``tp`` — tensor parallel (Megatron column/row splits on attention + MLP;
+  which matrix splits which way is stated once, by role, in
+  ``parallel.partition.SpecLayout``: ``Partitioner.spec_tree(params)``)
 - ``sp`` — sequence/context parallel (ring attention over the ICI ring)
 """
 
@@ -128,33 +130,6 @@ def init_params(key, cfg: TransformerConfig) -> Dict[str, Any]:
             "ln2_scale": jnp.ones((D,), dt), "ln2_bias": jnp.zeros((D,), dt),
         })
     return params
-
-
-def partition_specs(cfg: TransformerConfig) -> Dict[str, Any]:
-    """PartitionSpec tree matching init_params: Megatron-style tp splits.
-
-    qkv/ffn_w1 column-split (output dim on tp), out_w/ffn_w2 row-split
-    (input dim on tp) — GSPMD inserts the ICI all-reduces at the row-split
-    outputs, exactly the Megatron comm pattern.
-    """
-    block = {
-        "qkv_w": P(None, "tp"), "qkv_b": P("tp"),
-        "out_w": P("tp", None), "out_b": P(),
-        "ln1_scale": P(), "ln1_bias": P(),
-        "ffn_w1": P(None, "tp"), "ffn_b1": P("tp"),
-        "ffn_w2": P("tp", None), "ffn_b2": P(),
-        "ln2_scale": P(), "ln2_bias": P(),
-    }
-    return {
-        "embed": {
-            "tok": P("tp", None),  # vocab-sharded embedding (SURVEY §2.10 EP row)
-            "pos": P(), "seg": P(),
-            "ln_scale": P(), "ln_bias": P(),
-        },
-        "blocks": [dict(block) for _ in range(cfg.n_layers)],
-        "mlm": {"w": P(), "b": P(), "ln_scale": P(), "ln_bias": P(),
-                "out_bias": P("tp")},
-    }
 
 
 def batch_specs(cfg: TransformerConfig) -> Dict[str, Any]:
@@ -675,8 +650,9 @@ def generate(params, prompts, max_new_tokens: int,
 
 def make_qa_train_step(cfg: TransformerConfig, updater):
     """Fine-tune step over (encoder params, qa head) jointly — the
-    configs[4] workload. Shard with the same partition_specs; the head is
-    replicated (2 columns shard nothing)."""
+    configs[4] workload. Shard the encoder as for pretraining
+    (``Partitioner.spec_tree``); the head is replicated (2 columns shard
+    nothing)."""
 
     def step(params, qa_params, opt_state, qa_opt_state, batch, iteration, rng):
         def lf(p, q):

@@ -119,6 +119,13 @@ ROLE_EMBEDDING = "embedding"   # lookup tables: vocab/class dim shards fsdp×tp
 ROLE_KERNEL = "kernel"         # dense/conv/recurrent projection matrices
 ROLE_NORM = "norm"             # per-feature scales (gamma/beta/alpha/ln_*)
 ROLE_BIAS = "bias"             # per-unit offsets (and scalar margins)
+# Under tensor parallelism a plain ``kernel`` is the FIRST of a pair (its
+# output features split over tp). The other sides of a pair say so:
+ROLE_KERNEL_ROW = "kernel_row"      # the SECOND of a pair: its input is the
+#                                     split activation the first one left
+ROLE_KERNEL_WHOLE = "kernel_whole"  # whole activation in, whole out: no tp
+ROLE_BIAS_COLUMN = "bias_column"    # a first-of-pair kernel's bias: split
+#                                     with that kernel's columns
 
 # Canonical param-name → role table covering every name produced by the
 # bundled layers and functional models. Partitioning treats an unknown name
@@ -141,20 +148,34 @@ _PARAM_NAME_ROLES = {
     "Q": ROLE_EMBEDDING,                   # learned query table [n_queries, proj]
     # functional transformer (models/transformer.py)
     "tok": ROLE_EMBEDDING, "pos": ROLE_EMBEDDING, "seg": ROLE_EMBEDDING,
-    "qkv_w": ROLE_KERNEL, "out_w": ROLE_KERNEL,
-    "ffn_w1": ROLE_KERNEL, "ffn_w2": ROLE_KERNEL,
-    "qkv_b": ROLE_BIAS, "out_b": ROLE_BIAS,
-    "ffn_b1": ROLE_BIAS, "ffn_b2": ROLE_BIAS, "out_bias": ROLE_BIAS,
+    # (the two Megatron pairs of a block: qkv_w -> out_w, ffn_w1 -> ffn_w2)
+    "qkv_w": ROLE_KERNEL, "out_w": ROLE_KERNEL_ROW,
+    "ffn_w1": ROLE_KERNEL, "ffn_w2": ROLE_KERNEL_ROW,
+    "qkv_b": ROLE_BIAS_COLUMN, "out_b": ROLE_BIAS,
+    "ffn_b1": ROLE_BIAS_COLUMN, "ffn_b2": ROLE_BIAS, "out_bias": ROLE_BIAS,
     "ln_scale": ROLE_NORM, "ln_bias": ROLE_NORM,
     "ln1_scale": ROLE_NORM, "ln1_bias": ROLE_NORM,
     "ln2_scale": ROLE_NORM, "ln2_bias": ROLE_NORM,
 }
 
 
-def param_role(name: str, leaf=None) -> Optional[str]:
-    """Role for one param leaf by name (None = uncovered). Falls back to
-    suffix patterns so new functional-model names with conventional suffixes
+# A name that means different things in different containers is tagged by the
+# last two components of its path, which win over the bare name: the
+# functional transformer's head transform ``mlm/w`` (its input the whole
+# residual stream, its output normalised whole) shares ``w`` with OCNN's.
+_PARAM_PATH_ROLES = {
+    "mlm/w": ROLE_KERNEL_WHOLE,
+}
+
+
+def param_role(name: str, leaf=None, parent: Optional[str] = None) -> Optional[str]:
+    """Role for one param leaf by name (None = uncovered), ``parent`` being
+    the key of the dict that holds it. Falls back to suffix patterns so new
+    functional-model names with conventional suffixes
     (``*_w``/``*_b``/``*_scale``/``*_bias``/``*embed*``) stay covered."""
+    by_path = _PARAM_PATH_ROLES.get(f"{parent}/{name}")
+    if by_path is not None:
+        return by_path
     if name in _PARAM_NAME_ROLES:
         return _PARAM_NAME_ROLES[name]
     ln = name.lower()
@@ -169,16 +190,17 @@ def param_role(name: str, leaf=None) -> Optional[str]:
     return None
 
 
-def classify_param_tree(params) -> Any:
+def classify_param_tree(params, _parent: Optional[str] = None) -> Any:
     """Mirror a params (sub)tree with role strings / None per leaf. Nested
     containers (Bidirectional fwd/bwd, graph node dicts, transformer block
-    lists) recurse; leaf role comes from the leaf's own key name."""
+    lists) recurse; leaf role comes from the leaf's own key name, read with
+    the key of the dict that holds it (``_PARAM_PATH_ROLES``)."""
     if isinstance(params, dict):
-        return {k: (classify_param_tree(v) if isinstance(v, (dict, list, tuple))
-                    else param_role(k, v))
+        return {k: (classify_param_tree(v, k) if isinstance(v, (dict, list, tuple))
+                    else param_role(k, v, _parent))
                 for k, v in params.items()}
     if isinstance(params, (list, tuple)):
-        return type(params)(classify_param_tree(v) for v in params)
+        return type(params)(classify_param_tree(v, _parent) for v in params)
     return None  # bare leaf with no name context
 
 

@@ -10,7 +10,14 @@ chip's HBM. This module is the partitioner that lifts that cap:
   vocabulary lives in ``nn.conf``; layers tag their own params via
   ``Layer.param_roles``). ``fsdp`` shards parameter/optimizer STORAGE
   (ZeRO-3: GSPMD all-gathers shards for compute and reduce-scatters the
-  gradients); ``tp`` shards a single layer's math (Megatron).
+  gradients); ``tp`` shards a single layer's math (Megatron), and WHICH dim
+  of a kernel it takes follows from the side of the pair the kernel stands
+  on: the first of a pair (``kernel``: ``qkv_w``, ``ffn_w1``) splits its
+  output features, the second (``kernel_row``: ``out_w``, ``ffn_w2``) its
+  input features, so the activation between them stays split and the only
+  collective of the pair is one sum of its output. A kernel between two
+  whole activations (``kernel_whole``: the transformer head's ``mlm/w``)
+  is not split over ``tp`` at all.
 - :class:`Partitioner` — applies a layout to a network: places the param
   pytree per-spec, shards optimizer state identically to its params,
   replicates batch-norm state, and publishes ``tdl_param_bytes_per_rank`` /
@@ -38,11 +45,13 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..nn.conf import (ROLE_BIAS, ROLE_EMBEDDING, ROLE_KERNEL, ROLE_NORM,
-                       classify_param_tree)
+from ..nn.conf import (ROLE_BIAS, ROLE_BIAS_COLUMN, ROLE_EMBEDDING,
+                       ROLE_KERNEL, ROLE_KERNEL_ROW, ROLE_KERNEL_WHOLE,
+                       ROLE_NORM, classify_param_tree)
 from .mesh import AXIS_DATA, AXIS_FSDP, AXIS_PIPE, AXIS_TP, mesh_from_shape
 
-ROLES = (ROLE_EMBEDDING, ROLE_KERNEL, ROLE_NORM, ROLE_BIAS)
+ROLES = (ROLE_EMBEDDING, ROLE_KERNEL, ROLE_KERNEL_ROW, ROLE_KERNEL_WHOLE,
+         ROLE_NORM, ROLE_BIAS, ROLE_BIAS_COLUMN)
 
 
 @dataclass(frozen=True)
@@ -56,9 +65,18 @@ class SpecLayout:
     - ``embedding`` tables: leading (vocab/class) dim over ``fsdp×tp``
       combined — the widest dim of the widest tables.
     - ``kernel`` matrices: dim 0 (input features / out-channels) over
-      ``fsdp``, dim 1 over ``tp``.
+      ``fsdp``, dim 1 over ``tp`` — the first of a tensor-parallel pair,
+      whose output leaves split over its features.
+    - ``kernel_row`` matrices, the second of a pair (their input IS that
+      split activation): dim 0, the contraction, over ``tp``; ``fsdp`` keeps
+      the dim ``tp`` does not take. The product is a partial sum, added up
+      once over ``tp``; nothing is gathered in between.
+    - ``kernel_whole`` matrices (whole activation in, whole out): dim 0 over
+      ``fsdp``, nothing over ``tp``.
     - ``norm`` / ``bias`` vectors: over ``fsdp`` (ZeRO-3 shards everything;
-      GSPMD all-gathers them for compute).
+      GSPMD all-gathers them for compute), never over ``tp``.
+    - ``bias_column`` vectors (a first-of-pair kernel's bias): over ``tp``
+      like that kernel's columns, then ``fsdp``.
 
     A dim that an axis does not divide falls back per-axis (see
     :meth:`Partitioner.spec_tree`) — same "shard what fits" behavior GSPMD
@@ -105,11 +123,21 @@ class SpecLayout:
             return self.bias() if ndim == 1 else P()
         return P(self.fsdp_axis, self.tp_axis, *([None] * (ndim - 2)))
 
+    def kernel_row(self, ndim: int = 2) -> P:
+        if ndim < 2:
+            return self.kernel(ndim)
+        return P(self.tp_axis, self.fsdp_axis, *([None] * (ndim - 2)))
+
     def norm(self, ndim: int = 1) -> P:
         return P(self.fsdp_axis, *([None] * (ndim - 1))) if ndim else P()
 
     def bias(self, ndim: int = 1) -> P:
         return self.norm(ndim)
+
+    def bias_column(self, ndim: int = 1) -> P:
+        if ndim != 1:
+            return self.bias(ndim)
+        return P((self.tp_axis, self.fsdp_axis))
 
     def spec_for(self, role: Optional[str], ndim: int) -> Optional[P]:
         """Untrimmed spec for one leaf; None = uncovered role (the caller
@@ -121,7 +149,11 @@ class SpecLayout:
             return self.embedding(ndim)
         if role == ROLE_KERNEL:
             return self.kernel(ndim)
-        if role in (ROLE_NORM, ROLE_BIAS):
+        if role == ROLE_KERNEL_ROW:
+            return self.kernel_row(ndim)
+        if role == ROLE_BIAS_COLUMN:
+            return self.bias_column(ndim)
+        if role in (ROLE_NORM, ROLE_BIAS, ROLE_KERNEL_WHOLE):
             return self.norm(ndim)
         return None
 

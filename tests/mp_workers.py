@@ -630,53 +630,46 @@ def tp_train():
     cross it. Each rank writes the loss sequence; the parent asserts
     rank-identical losses AND parity with a single-process dp×tp run."""
     import jax
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from deeplearning4j_tpu.models.transformer import (
-        TransformerConfig,
-        batch_specs,
-        init_params,
-        make_train_step,
-        partition_specs,
-    )
-    from deeplearning4j_tpu.nn.updaters import Adam
     from deeplearning4j_tpu.parallel.launcher import ProcessCollectives
 
     col = ProcessCollectives()
     rank = col.rank
-    losses = tp_step_losses(Mesh(np.array(jax.devices()).reshape(2, 2),
-                                 ("dp", "tp")))
+    losses = tp_step_losses(jax.devices())
     col.barrier("tp-done")
     _write(rank, {"losses": losses, "global_devices": jax.device_count()})
 
 
-def tp_step_losses(mesh, steps=3):
+def tp_step_losses(devices, steps=3):
     """Shared by the worker and the parent's single-process reference:
-    deterministic dp×tp transformer training losses on the given mesh."""
+    deterministic dp×tp transformer training losses on a 2 x 2 mesh of the
+    given four devices, the weights split by the role policy."""
     import jax
 
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
-    from jax.tree_util import tree_map
 
     from deeplearning4j_tpu.models.transformer import (
         TransformerConfig,
         batch_specs,
         init_params,
         make_train_step,
-        partition_specs,
     )
     from deeplearning4j_tpu.nn.updaters import Adam
+    from deeplearning4j_tpu.parallel.partition import Partitioner, SpecLayout
 
+    layout = SpecLayout(data=2, fsdp=1, tp=2, data_axis="dp")
+    part = Partitioner(layout, mesh=layout.build_mesh(devices))
+    mesh = part.mesh
     cfg = TransformerConfig.tiny(dropout=0.0)
     params = init_params(jax.random.key(0), cfg)
-    pspecs = partition_specs(cfg)
+    params = part.place(params, part.spec_tree(params))
+
     def _place(a, spec):
         arr = np.asarray(a)
         sh = NamedSharding(mesh, spec)
         return jax.make_array_from_callback(arr.shape, sh, lambda idx: arr[idx])
 
-    params = tree_map(_place, params, pspecs, is_leaf=lambda x: x is None)
     updater = Adam(1e-3)
     opt = updater.init(params)
     step = jax.jit(make_train_step(cfg, updater), donate_argnums=(0, 1))

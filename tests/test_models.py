@@ -16,10 +16,10 @@ from deeplearning4j_tpu.models import (
     TransformerConfig,
     transformer_init,
     transformer_loss,
-    transformer_partition_specs,
 )
 from deeplearning4j_tpu.models.transformer import forward, make_train_step
 from deeplearning4j_tpu.nn.updaters import Adam
+from deeplearning4j_tpu.parallel import Partitioner, SpecLayout
 
 
 def test_lenet_trains():
@@ -70,11 +70,9 @@ def test_char_lstm_tbptt_trains():
 def test_transformer_dp_tp_train_step():
     cfg = TransformerConfig.tiny()
     params = transformer_init(jax.random.key(0), cfg)
-    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("dp", "tp"))
-    specs = transformer_partition_specs(cfg)
-    pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                          is_leaf=lambda x: isinstance(x, P))
-    params = jax.device_put(params, pshard)
+    part = Partitioner(SpecLayout(data=2, fsdp=1, tp=4, data_axis="dp"))
+    mesh = part.mesh
+    params = part.place(params, part.spec_tree(params))
     upd = Adam(1e-3)
     opt = upd.init(params)
     rs = np.random.RandomState(0)
@@ -100,11 +98,10 @@ def test_transformer_ring_loss_matches_xla():
     batch = {"tokens": toks, "labels": toks, "weights": jnp.ones((4, 128), jnp.float32)}
     l_ref = float(transformer_loss(params, batch, cfg_x, None, False))
 
-    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 2, 2), ("dp", "tp", "sp"))
-    specs = transformer_partition_specs(cfg_r)
-    pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                          is_leaf=lambda x: isinstance(x, P))
-    params_s = jax.device_put(params, pshard)
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 1, 2, 2),
+                ("dp", "fsdp", "tp", "sp"))
+    part = Partitioner(SpecLayout(data=2, fsdp=1, tp=2, data_axis="dp"), mesh=mesh)
+    params_s = part.place(params, part.spec_tree(params))
     batch_s = {k: jax.device_put(v, NamedSharding(mesh, P("dp", "sp")))
                for k, v in batch.items()}
     with jax.sharding.set_mesh(mesh):

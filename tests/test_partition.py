@@ -9,6 +9,7 @@ test_multiprocess.py (slow-marked)."""
 
 import ast
 import pathlib
+import re
 
 import jax
 import numpy as np
@@ -92,6 +93,199 @@ def test_divisibility_fallback_is_per_axis_and_reported():
     assert specs["3"]["b"] == P()
     assert "3/b" in rep["replicated_fallback"]
     assert rep["uncovered"] == []
+
+
+# ------------------------------------------- the tensor-parallel pairing
+
+
+def _transformer_shapes(**kw):
+    """The functional transformer's tree as shapes (a spec needs no more)."""
+    from deeplearning4j_tpu.models import transformer as tfm
+
+    cfg = tfm.TransformerConfig.tiny(**kw)
+    return cfg, jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg))
+
+
+def _dp2tp2():
+    """The four-chip cell's layout on four of the suite's host devices."""
+    layout = SpecLayout(data=2, fsdp=1, tp=2)
+    return Partitioner(layout, mesh=layout.build_mesh(jax.devices()[:4]))
+
+
+def _axes(spec, dim):
+    """The mesh axes a spec puts on one dim, as a tuple."""
+    ax = tuple(spec)[dim] if dim < len(tuple(spec)) else None
+    return () if ax is None else ax if isinstance(ax, tuple) else (ax,)
+
+
+def _over_tp(spec):
+    return [d for d in range(len(tuple(spec))) if "tp" in _axes(spec, d)]
+
+
+# leaf of a block -> the dims tp takes under the pairing (Megatron's): the
+# first matrix of a pair by columns with its bias, the second by rows, and
+# nothing else of a block at all
+_BLOCK_TP_DIMS = {
+    "qkv_w": [1], "qkv_b": [0], "out_w": [0], "out_b": [],
+    "ffn_w1": [1], "ffn_b1": [0], "ffn_w2": [0], "ffn_b2": [],
+    "ln1_scale": [], "ln1_bias": [], "ln2_scale": [], "ln2_bias": [],
+}
+
+
+@pytest.mark.parametrize("leaf", sorted(_BLOCK_TP_DIMS))
+def test_transformer_block_pairs_its_tp_splits(leaf):
+    """ISSUE 36: which dim of a kernel goes over tp follows from the side of
+    the pair it stands on, so the activation between the two stays split."""
+    _, shapes = _transformer_shapes()
+    specs = _dp2tp2().spec_tree(shapes)
+    for block in specs["blocks"]:
+        assert _over_tp(block[leaf]) == _BLOCK_TP_DIMS[leaf], block[leaf]
+
+
+def test_transformer_head_and_norms_are_whole_over_tp():
+    """The head's ``mlm/w`` is classified by its PATH (OCNN's ``w`` stays a
+    plain kernel): its input is the whole residual stream and its output is
+    normalised whole, so splitting it would only buy a gather."""
+    from deeplearning4j_tpu.nn.conf import (ROLE_KERNEL, ROLE_KERNEL_WHOLE,
+                                            classify_param_tree)
+
+    _, shapes = _transformer_shapes(vocab_size=1001)   # odd, as GPT-2's
+    roles = classify_param_tree(shapes)
+    assert roles["mlm"]["w"] == ROLE_KERNEL_WHOLE
+    assert classify_param_tree({"w": np.zeros((4, 4))})["w"] == ROLE_KERNEL
+    specs = _dp2tp2().spec_tree(shapes)
+    for name, spec in specs["mlm"].items():
+        assert _over_tp(spec) == [], (name, spec)
+    # an odd vocabulary divides nothing: the tied table is whole too
+    assert _over_tp(specs["embed"]["tok"]) == []
+    for name in ("ln_scale", "ln_bias"):
+        assert _over_tp(specs["embed"][name]) == []
+
+
+def test_fsdp_keeps_the_dim_tp_does_not_take():
+    _, shapes = _transformer_shapes()
+    specs = Partitioner(SpecLayout(data=2, fsdp=2, tp=2)).spec_tree(shapes)
+    block = specs["blocks"][0]
+    assert block["qkv_w"] == P("fsdp", "tp") == block["ffn_w1"]
+    assert block["out_w"] == P("tp", "fsdp") == block["ffn_w2"]
+    assert block["qkv_b"] == P(("tp", "fsdp")) == block["ffn_b1"]
+    assert block["out_b"] == P("fsdp") == block["ln1_scale"]
+    assert specs["mlm"]["w"] == P("fsdp")
+    # an nn/ Dense kernel is no side of a tagged pair: today's spec
+    dense = Partitioner(SpecLayout(data=2, fsdp=2, tp=2)).spec_tree(
+        {"0": {"W": np.zeros((8, 16), np.float32)}})
+    assert dense["0"]["W"] == P("fsdp", "tp")
+
+
+def test_the_role_policy_is_the_only_statement_of_the_transformers_layout():
+    """``models.transformer.partition_specs`` said the pairing while no cell
+    ran it (ISSUE 36); its callers place through ``Partitioner.spec_tree``."""
+    from deeplearning4j_tpu import models
+    from deeplearning4j_tpu.models import transformer as tfm
+
+    assert not hasattr(tfm, "partition_specs")
+    assert not hasattr(models, "transformer_partition_specs")
+    # and the layout identity a checkpoint records knows axes only
+    assert SpecLayout(data=2, fsdp=1, tp=2).describe() == {
+        "axes": {"data": 2, "fsdp": 1, "tp": 2},
+        "axis_names": ["data", "fsdp", "tp"]}
+
+
+# -- the compiled step's census of collectives (what says the layout engaged)
+
+_CENSUS = dict(B=4, T=32, D=64, F=256, V=1001, L=2)
+_COLLECTIVE = re.compile(
+    r"= (\(?[a-z]\w*\[.*?) "
+    r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+    r"(?:-start)?\(")
+
+
+def _collectives(hlo_text):
+    """[(opcode, dims)] for every array a collective of the compiled module
+    returns (a combined collective returns a tuple: one entry an array)."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _COLLECTIVE.search(line)
+        if m:
+            out += [(m.group(2), tuple(int(d) for d in dims.split(",") if d))
+                    for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1))]
+    return out
+
+
+@pytest.fixture(scope="module")
+def dp2tp2_step():
+    """A 2-layer causal transformer with an odd vocabulary under data 2 x
+    tp 2, placed by the four-chip cell's own sequence (``spec_tree`` ->
+    ``state_spec_tree`` -> ``out_shardings``: benchmark/runners/train.py):
+    the compiled step's collectives, its first loss, and the loss of the
+    same step on one device."""
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models import transformer as tfm
+
+    c = _CENSUS
+    cfg = tfm.TransformerConfig(
+        vocab_size=c["V"], max_len=c["T"], d_model=c["D"], n_heads=4,
+        n_layers=c["L"], d_ff=c["F"], causal=True, dropout=0.0,
+        attn_impl="xla", compute_dtype=jnp.float32)
+    updater = Adam(1e-4)
+    part = _dp2tp2()
+    init = lambda key: tfm.init_params(key, cfg)  # noqa: E731
+    p_shapes = jax.eval_shape(init, jax.random.key(0))
+    p_specs = part.spec_tree(p_shapes)
+    s_specs = Partitioner.state_spec_tree(
+        jax.eval_shape(updater.init, p_shapes), p_specs)
+    keep = jax.tree.map(part.sharding_for, (p_specs, s_specs),
+                        is_leaf=lambda x: isinstance(x, P))
+    rs = np.random.RandomState(0)
+    tokens = rs.randint(0, c["V"], (c["B"], c["T"])).astype(np.int32)
+    batch = {"tokens": tokens, "labels": np.roll(tokens, -1, axis=1),
+             "weights": np.ones(tokens.shape, np.float32)}
+    args = (jnp.asarray(0, jnp.int32), jax.random.key(1))
+    with jax.sharding.set_mesh(part.mesh):
+        params = jax.jit(init, out_shardings=keep[0])(jax.random.key(3))  # donate-ok: makes the state
+        opt = jax.jit(updater.init, out_shardings=keep[1])(params)  # donate-ok: makes the state
+        whole = jax.tree.map(np.asarray, params)
+        step = jax.jit(tfm.make_train_step(cfg, updater), donate_argnums=(0, 1),
+                       out_shardings=(*keep, None))
+        placed = jax.device_put(batch, batch_sharding(part.mesh))
+        compiled = step.lower(params, opt, placed, *args).compile()
+        loss = float(compiled(params, opt, placed, *args)[2])
+    one = jax.jit(tfm.make_train_step(cfg, updater), donate_argnums=(0, 1))
+    loss_one = float(one(whole, updater.init(whole), batch, *args)[2])
+    return _collectives(compiled.as_text()), loss, loss_one
+
+
+def _dims_end(dims, *tail):
+    return dims[-len(tail):] == tail
+
+
+@pytest.mark.parametrize("case", ["no_gather_of_the_hidden",
+                                  "no_sum_of_the_logits",
+                                  "no_gather_of_the_table",
+                                  "four_sums_a_block_at_most",
+                                  "same_first_loss"])
+def test_dp2tp2_step_census(dp2tp2_step, case):
+    """ISSUE 36: with the splits paired no matmul of a block contracts over a
+    dim that is split while its input is not, so the activation between the
+    two matrices of a pair is never gathered, the head sums no logits, the
+    tied table's gradient is never gathered back, and a block's only
+    ``[B,T,D]`` sums are its two pairs', forward and backward."""
+    found, loss, loss_one = dp2tp2_step
+    c = _CENSUS
+    assert found, "the census parsed no collective at all"
+    of = lambda op, *tail: [d for o, d in found  # noqa: E731
+                            if o == op and _dims_end(d, *tail)]
+    if case == "no_gather_of_the_hidden":
+        assert of("all-gather", c["T"], c["F"]) == []
+    elif case == "no_sum_of_the_logits":
+        assert of("all-reduce", c["T"], c["V"]) == []
+    elif case == "no_gather_of_the_table":
+        assert of("all-gather", c["V"], c["D"]) == []
+    elif case == "four_sums_a_block_at_most":
+        assert len(of("all-reduce", c["T"], c["D"])) <= 4 * c["L"]
+    else:
+        assert abs(loss - loss_one) <= 1e-5 * abs(loss_one), (loss, loss_one)
 
 
 # ------------------------------------------------- acceptance: loss parity
