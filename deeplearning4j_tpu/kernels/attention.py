@@ -35,17 +35,24 @@ from jax.sharding import PartitionSpec as P
 _NEG_INF = -1e30
 
 
-def mha_reference(q, k, v, mask=None, *, causal: bool = False, scale: Optional[float] = None):
+def mha_reference(q, k, v, mask=None, *, causal: bool = False, scale: Optional[float] = None,
+                  window: Optional[int] = None):
     """Plain-XLA multi-head attention (the 'reference path' for parity tests;
     equivalent math to libnd4j multi_head_dot_product_attention: softmax(QK^T
-    / sqrt(d)) V with full score materialization, O(T^2) memory)."""
+    / sqrt(d)) V with full score materialization, O(T^2) memory). ``window``
+    (with ``causal``): a query sees its own key and the ``window - 1`` before
+    it. K/V of fewer heads than q are repeated (grouped queries)."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if k.shape[1] != q.shape[1]:
+        k, v = (jnp.repeat(x, q.shape[1] // k.shape[1], axis=1) for x in (k, v))
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
         Tq, Tk = scores.shape[-2], scores.shape[-1]
         qpos = jnp.arange(Tq)[:, None] + (Tk - Tq)
         cmask = qpos >= jnp.arange(Tk)[None, :]
+        if window is not None:
+            cmask &= qpos - jnp.arange(Tk)[None, :] < window
         scores = jnp.where(cmask, scores, _NEG_INF)
     if mask is not None:
         # mask: [B, Tk] or [B, 1, Tq, Tk]; 1 = attend, 0 = ignore
@@ -437,6 +444,140 @@ def _flash_bwd_dense(causal, scale, res, do):
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
+# ------------------------------------------- forward only: grouped, windowed
+#
+# Serving's prefill for a grouped-query model, with or without a sliding
+# window. Forward only (no custom_vjp: training through a window is not
+# built), causal only. The operands are [B, T, heads, D], a head a block of D
+# lanes of a row, and K and V keep their own (fewer) heads in HBM: the
+# BlockSpec's lane-block index sends query head h to K/V head h // (H / G). The key
+# axis of the grid is RELATIVE: step j of q-block i visits key block
+# ``first(i) + j``, where ``first`` is the block of the earliest key any
+# query of the block still sees, so a block wholly behind the window (or
+# past the causal edge) is never a grid step and never a DMA.
+
+
+def _live_key_blocks(qb, block_q, block_k, q_offset, window, num_k):
+    """(first, last) key block some query of q-block ``qb`` sees: causal, and
+    no further back than ``window`` keys (the query's own among them)."""
+    last = jnp.minimum((q_offset + (qb + 1) * block_q - 1) // block_k, num_k - 1)
+    if window is None:
+        return 0, last
+    return jnp.maximum(q_offset + qb * block_q - (window - 1), 0) // block_k, last
+
+
+def _flash_gqa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                      scale, window, block_q, block_k, num_j, num_k, q_offset):
+    qb, j = pl.program_id(1), pl.program_id(2)
+    first, last = _live_key_blocks(qb, block_q, block_k, q_offset, window, num_k)
+    kb = first + j
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    @pl.when(kb <= last)
+    def _accumulate():
+        s = _dot(q_ref[0], k_ref[0], _NT) * scale                  # [bq, bk]
+        qpos = q_offset + qb * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        kpos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        seen = qpos >= kpos
+        if window is not None:
+            seen &= qpos - kpos < window
+        s = jnp.where(seen, s, _NEG_INF)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * corr + _dot_f32(p, v_ref[0], _NN)
+        m_ref[:] = m_new
+
+    @pl.when(j == num_j - 1)
+    def _fin():
+        o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+
+
+def _flash_forward_gqa(q, k, v, *, window, scale, block_q, block_k, interpret,
+                       q_offset):
+    """q [B, Tq, H, D], k / v [B, Tk, G, D] (G divides H; heads side by side
+    in a row, as the projections write them: no transpose is made), Tq / Tk
+    whole blocks, ``q_offset >= 0``: query i sits at key position ``q_offset
+    + i`` and sees keys ``j <= q_offset + i`` with ``q_offset + i - j <
+    window``. Returns [B, Tq, H, D]."""
+    B, Tq, H, D = q.shape
+    Tk, G = k.shape[1], k.shape[2]
+    r, num_k = H // G, Tk // block_k
+    # the most key blocks one q-block sees: every block under the causal
+    # edge, or the window's span laid over block boundaries
+    num_j = num_k if window is None else min(
+        num_k, (block_q + window - 2) // block_k + 2)
+
+    # a head is a block of D lanes of a row: the block index along the last
+    # axis IS the head
+    def q_index(b, i, j):
+        return b // H, i, b % H
+
+    def kv_index(b, i, j):
+        first, last = _live_key_blocks(i, block_q, block_k, q_offset, window, num_k)
+        return b // H, jnp.minimum(first + j, last), (b % H) // r
+
+    kernel = functools.partial(
+        _flash_gqa_kernel, scale=scale, window=window, block_q=block_q,
+        block_k=block_k, num_j=num_j, num_k=num_k, q_offset=q_offset)
+    q_spec = pl.BlockSpec((1, block_q, D), q_index)
+    out = pl.pallas_call(
+        kernel,
+        grid=(B * H, Tq // block_q, num_j),
+        in_specs=[q_spec, pl.BlockSpec((1, block_k, D), kv_index),
+                  pl.BlockSpec((1, block_k, D), kv_index)],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Tq, H * D), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
+        ],
+        interpret=interpret,
+        # what a device trace calls the kernel: one name with a window, one
+        # without, so a reader can tell a sliding layer's calls from a full one's
+        name="flash_fwd_gqa" if window is None else "flash_fwd_swa",
+    )(q.reshape(B, Tq, H * D), k.reshape(B, Tk, G * D), v.reshape(B, Tk, G * D))
+    return out.reshape(B, Tq, H, D)
+
+
+#: rows and keys of a block of the grouped / windowed forward on the chip
+_GQA_BLOCK = 1024
+
+
+def _flash_gqa(q, k, v, *, window, scale, block_q, block_k, interpret):
+    """The pad shim of the grouped / windowed forward, q [B, Tq, H, D], k / v
+    [B, Tk, G, D]: sequence lengths are rounded up to whole blocks with
+    zeros (a padded key lies past every real query's causal edge, a padded
+    query's row is cut), and on the chip a head's lanes up to whole 128-lane
+    tiles (zero lanes add nothing to a score; the output's are cut)."""
+    Tq, Tk, D = q.shape[1], k.shape[1], q.shape[3]
+    if Tq > Tk:
+        raise ValueError(f"grouped / windowed flash attention needs Tq <= Tk "
+                         f"(queries are the LAST Tq positions), got {Tq} > {Tk}")
+
+    def block(given, T):
+        return given or min(_GQA_BLOCK, T if interpret else -(-T // 128) * 128)
+
+    bq, bk = block(block_q, Tq), block(block_k, Tk)
+    pad_q, pad_k, pad_d = -Tq % bq, -Tk % bk, 0 if interpret else -D % 128
+    if pad_q or pad_d:
+        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, pad_d)))
+    if pad_k or pad_d:
+        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, pad_d)))
+        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, pad_d)))
+    out = _flash_forward_gqa(q, k, v, window=window, scale=scale, block_q=bq,
+                             block_k=bk, interpret=interpret, q_offset=Tk - Tq)
+    return out[:, :Tq, :, :D] if pad_q or pad_d else out
+
+
 def _as_key_mask(mask):
     """Coerce a mask to key-padding form [B, Tk], or None if it isn't one.
 
@@ -453,7 +594,8 @@ def _as_key_mask(mask):
 
 def flash_attention(q, k, v, mask=None, *, segment_ids=None, causal: bool = False,
                     scale: Optional[float] = None, block_q: Optional[int] = None,
-                    block_k: Optional[int] = None, interpret: Optional[bool] = None):
+                    block_k: Optional[int] = None, interpret: Optional[bool] = None,
+                    window: Optional[int] = None, layout: str = "bhtd"):
     """Pallas flash attention, O(T) memory in BOTH directions (blockwise
     online softmax forward; FlashAttention-2 blockwise backward).
 
@@ -480,7 +622,38 @@ def flash_attention(q, k, v, mask=None, *, segment_ids=None, causal: bool = Fals
 
     Falls back to interpret mode off-TPU so the same code path is testable on
     the CPU mesh (SURVEY §4.6 #4: fast-path vs reference-path parity harness).
+
+    ``window`` (static): query i sees key j only while ``i - j < window``
+    (with the causal mask: its own position and the ``window - 1`` before
+    it). K and V may have FEWER heads than q (grouped queries: head h reads
+    K/V head ``h // (H / G)``, through the BlockSpec's head index: no
+    repeated copy in HBM). Either one takes the FORWARD-ONLY kernel
+    (``_flash_forward_gqa``): causal only, no mask or segment ids, not
+    differentiable; a key block that no query of a q-block sees is never
+    visited. That kernel reads a head as a block of lanes of a row, so with
+    ``layout="bthd"`` (q [B, T, H, D], k / v [B, T, G, D]: heads side by
+    side, as a projection writes them; only the forward-only kernel takes
+    it) no transpose is made on the way in or out. With ``window=None``,
+    equal heads and the default layout nothing above changes.
     """
+    if layout not in ("bhtd", "bthd"):
+        raise ValueError(f"layout must be 'bhtd' or 'bthd', got {layout!r}")
+    heads = 1 if layout == "bhtd" else 2
+    if window is not None or k.shape[heads] != q.shape[heads] or layout == "bthd":
+        if not causal or mask is not None or segment_ids is not None:
+            raise ValueError("a window, grouped K/V heads or layout='bthd' take the "
+                             "forward-only kernel: causal=True, no mask, no segment ids")
+        if (q.shape[heads] % k.shape[heads] or v.shape != k.shape
+                or (window is not None and window < 1)):
+            raise ValueError(f"q {q.shape} / k {k.shape} / v {v.shape} / window "
+                             f"{window}: K/V heads must divide the query heads")
+        if layout == "bhtd":
+            q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+        out = _flash_gqa(
+            q, k, v, window=window, block_q=block_q, block_k=block_k,
+            scale=1.0 / math.sqrt(q.shape[-1]) if scale is None else scale,
+            interpret=jax.default_backend() != "tpu" if interpret is None else interpret)
+        return out.transpose(0, 2, 1, 3) if layout == "bhtd" else out
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     if scale is None:

@@ -27,6 +27,18 @@ reshaping the ``H*hd`` lanes, and the per-head output is the matching
 diagonal block of ``p @ V``. Operands stay in the arena's dtype (bf16 on the
 chip) with float32 accumulation; scale, mask and softmax are float32.
 
+**Grouped heads** (``kv_heads``): a model whose ``n_heads`` query heads share
+``kv_heads`` K/V heads keeps arenas of ``kv_heads * hd`` lanes, and query head
+``h`` reads the lanes of K/V head ``h // (n_heads / kv_heads)``: the same
+block-diagonal layout over those lanes (a query's heads come in as rows
+``[Hp, hd]`` and are laid into their K/V head's lanes in VMEM; the output is
+read back from there). **A first visible key** (``starts [S, W]``, a sliding
+window's): a slot's work list begins at the block of its earliest start, the
+chunks mask below each query's own start, and a block before it is never
+looked up in the table, so the pool may hand it back and leave the entry
+unmapped. With ``kv_heads == n_heads`` and no ``starts`` the kernel is what it
+was: seven scalar lists, ``[S*W, H*hd]`` query rows.
+
 Off the TPU the same kernel runs in interpret mode (as
 ``kernels/attention.py:flash_attention`` does), so the CPU tests exercise
 the path the chip runs.
@@ -36,6 +48,8 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -45,18 +59,31 @@ _NEG_INF = -1e30   # matches kernels.attention masking
 _CHUNK_T = 128     # keys per work item: one lane tile of scores
 
 
-def _kernel(layer_ref, tables_ref, limits_ref, nblk_ref, wslot_ref, wchunk_ref,
-            nwork_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, q2_ref,
-            m_ref, l_ref, acc_ref, *, W, H, hd, Hp, bT, C, MB, scale):
+def _kernel(*refs, W, H, G, hd, Hp, bT, C, MB, scale, windowed):
     """Walk the (slot, chunk) work list; see the module docstring.
 
-    q_ref/o_ref [S*W, D] float32 (rows of a slot are consecutive);
-    k_hbm/v_hbm [L, NB, bT, D], left in HBM; kbuf/vbuf [2, C*bT, D]; q2_ref
-    [W*Hp, D]; m/l [W*Hp, 1]; acc [W*Hp, D]."""
-    D = H * hd
+    k_hbm/v_hbm [L, NB, bT, D], left in HBM, D = G*hd lanes; kbuf/vbuf
+    [2, C*bT, D]; q2_ref [W*Hp, D]; m/l [W*Hp, 1]; acc [W*Hp, D]. With as
+    many K/V heads as query heads (G == H) q_ref/o_ref are [S*W, D] float32
+    (rows of a slot are consecutive); grouped, they are [S*W*Hp, hd]: a
+    query's heads are rows. ``windowed``: two more scalar lists, the first
+    visible key of every query and the slot's first block, from which its
+    chunks count."""
+    if windowed:
+        (layer_ref, tables_ref, limits_ref, nblk_ref, wslot_ref, wchunk_ref,
+         nwork_ref, starts_ref, fblk_ref, *refs) = refs
+    else:
+        (layer_ref, tables_ref, limits_ref, nblk_ref, wslot_ref, wchunk_ref,
+         nwork_ref, *refs) = refs
+    (q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, q2_ref, m_ref, l_ref,
+     acc_ref) = refs
+    D = G * hd
     T = C * bT
     layer = layer_ref[0]
     n_work = nwork_ref[0]
+
+    def first_block(s):
+        return fblk_ref[s] if windowed else 0
 
     # dead slots are never visited: their rows must still be defined. Blocks
     # of a chunk past a slot's live length are not copied either, so the
@@ -71,7 +98,7 @@ def _kernel(layer_ref, tables_ref, limits_ref, nblk_ref, wslot_ref, wchunk_ref,
         and V, at most C each) into half ``buf`` of the buffers."""
         s, c = wslot_ref[i], wchunk_ref[i]
         for j in range(C):
-            lb = c * C + j
+            lb = first_block(s) + c * C + j
             phys = tables_ref[s * MB + jnp.minimum(lb, MB - 1)]
             dst = pl.ds(j * bT, bT)
 
@@ -82,10 +109,16 @@ def _kernel(layer_ref, tables_ref, limits_ref, nblk_ref, wslot_ref, wchunk_ref,
                         hbm.at[layer, phys], vmem.at[buf, dst],
                         sems.at[n, buf]), op)()
 
-    # row h of a query's Hp rows owns lanes [h*hd, (h+1)*hd); rows >= H own none
-    row = jax.lax.broadcasted_iota(jnp.int32, (Hp, D), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (Hp, D), 1)
-    own = (lane >= row * hd) & (lane < (row + 1) * hd)
+    if G == H:
+        # row h of a query's Hp rows owns lanes [h*hd, (h+1)*hd); rows >= H
+        # own none
+        row = jax.lax.broadcasted_iota(jnp.int32, (Hp, D), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (Hp, D), 1)
+        own = (lane >= row * hd) & (lane < (row + 1) * hd)
+    else:
+        # grouped: row h reads the lanes of K/V head h // (H / G), so its
+        # head's hd values go there and its output comes back from there
+        group = jax.lax.broadcasted_iota(jnp.int32, (Hp, hd), 0) // (H // G)
 
     @pl.when(n_work > 0)
     def _():
@@ -105,20 +138,30 @@ def _kernel(layer_ref, tables_ref, limits_ref, nblk_ref, wslot_ref, wchunk_ref,
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
             for w in range(W):
-                qw = q_ref[pl.ds(s * W + w, 1), :]                    # [1, D]
-                q2_ref[w * Hp:(w + 1) * Hp, :] = jnp.where(
-                    own, qw, 0.0).astype(q2_ref.dtype)
+                if G == H:
+                    qw = q_ref[pl.ds(s * W + w, 1), :]                # [1, D]
+                    q2 = jnp.where(own, qw, 0.0)
+                else:
+                    qh = q_ref[pl.ds(pl.multiple_of((s * W + w) * Hp, Hp), Hp), :]
+                    q2 = jnp.concatenate(
+                        [jnp.where(group == g, qh, 0.0) for g in range(G)], axis=1)
+                q2_ref[w * Hp:(w + 1) * Hp, :] = q2.astype(q2_ref.dtype)
 
         block_copies(i, buf, "wait")
         k = kbuf[buf]                                                  # [T, D]
         v = vbuf[buf]
         sc = jax.lax.dot_general(q2_ref[...], k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
-        kpos = c * T + jax.lax.broadcasted_iota(jnp.int32, (Hp, T), 1)
+        kpos = (first_block(s) * bT + c * T
+                + jax.lax.broadcasted_iota(jnp.int32, (Hp, T), 1))
+
+        def seen(w):
+            below = kpos < limits_ref[s * W + w]
+            return below & (kpos >= starts_ref[s * W + w]) if windowed else below
+
         sc = jnp.concatenate(
-            [jnp.where(kpos < limits_ref[s * W + w],
-                       sc[w * Hp:(w + 1) * Hp], _NEG_INF) for w in range(W)],
-            axis=0)                                                    # [N, T]
+            [jnp.where(seen(w), sc[w * Hp:(w + 1) * Hp], _NEG_INF)
+             for w in range(W)], axis=0)                               # [N, T]
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
@@ -129,83 +172,114 @@ def _kernel(layer_ref, tables_ref, limits_ref, nblk_ref, wslot_ref, wchunk_ref,
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-        @pl.when((c + 1) * C >= nblk_ref[s])
+        @pl.when(first_block(s) + (c + 1) * C >= nblk_ref[s])
         def _():
             o = acc_ref[...] / l_ref[...]                              # [N, D]
             for w in range(W):
-                o_ref[pl.ds(s * W + w, 1), :] = jnp.sum(
-                    jnp.where(own, o[w * Hp:(w + 1) * Hp], 0.0),
-                    axis=0, keepdims=True)
+                ow = o[w * Hp:(w + 1) * Hp]
+                if G == H:
+                    o_ref[pl.ds(s * W + w, 1), :] = jnp.sum(
+                        jnp.where(own, ow, 0.0), axis=0, keepdims=True)
+                else:
+                    o_ref[pl.ds(pl.multiple_of((s * W + w) * Hp, Hp), Hp), :] = sum(
+                        jnp.where(group == g, ow[:, g * hd:(g + 1) * hd], 0.0)
+                        for g in range(G))
 
         return carry
 
     jax.lax.fori_loop(0, n_work, item, 0)
 
 
-def _work_list(limits, block_T: int, C: int, max_items: int):
+def _work_list(limits, block_T: int, C: int, max_items: int, starts=None):
     """(nblk [S], work_slot, work_chunk [max_items], n_work [1]) from the
     per-query limits: slot s is visited ``ceil(nblk[s] / C)`` times, in slot
-    order; entries past ``n_work`` are never read."""
+    order; entries past ``n_work`` are never read. With ``starts`` (the first
+    visible key of every query) a slot's chunks count from the block of its
+    earliest one, ``fblk [S]``, which is returned as a fifth value."""
     S = limits.shape[0]
     nblk = -(-jnp.max(limits, axis=1) // block_T)
-    nchunk = -(-nblk // C)
+    fblk = (0 if starts is None
+            else jnp.minimum(jnp.min(starts, axis=1) // block_T, nblk))
+    nchunk = -(-(nblk - fblk) // C)
     ends = jnp.cumsum(nchunk)
     i = jnp.arange(max_items, dtype=jnp.int32)
     slot = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1), S - 1)
     chunk = i - (ends - nchunk)[slot]
-    return (nblk.astype(jnp.int32), slot.astype(jnp.int32),
-            chunk.astype(jnp.int32), ends[-1:].astype(jnp.int32))
+    out = (nblk.astype(jnp.int32), slot.astype(jnp.int32),
+           chunk.astype(jnp.int32), ends[-1:].astype(jnp.int32))
+    return out if starts is None else (*out, fblk.astype(jnp.int32))
 
 
 def paged_decode_attention(q, k_arena, v_arena, tables, limits, *, layer,
-                           n_heads: int):
+                           n_heads: int, kv_heads: Optional[int] = None,
+                           starts=None):
     """Attend each slot's live keys through its block table:
     softmax(q K^T / sqrt(hd)) V.
 
     q: [S, W, H*hd] — W tokens a slot. k_arena, v_arena:
-    [L, n_blocks, block_T, H*hd], the window's own K/V already in their
-    cells; ``layer`` (an int or an int32 scalar) picks the layer. tables:
+    [L, n_blocks, block_T, kv_heads*hd], the window's own K/V already in
+    their cells; ``layer`` (an int or an int32 scalar) picks the layer.
+    ``kv_heads`` (default ``n_heads``) must divide ``n_heads``: query head
+    ``h`` reads the lanes of K/V head ``h // (n_heads / kv_heads)``. tables:
     [S, max_blocks] int32, logical -> physical block. limits: [S, W] int32 —
-    query w of slot s attends keys ``0 .. limits[s, w] - 1``; 0 for every w
-    marks a dead slot. Returns out [S, W, H*hd] in q's dtype (a dead slot's
-    rows are zeros)."""
+    query w of slot s attends keys ``starts[s, w] .. limits[s, w] - 1``
+    (``starts`` [S, W] int32, default 0: a sliding window's first visible
+    key; blocks before a slot's earliest start are never looked up, so their
+    table entries may be unmapped); limit 0 for every w marks a dead slot.
+    Returns out [S, W, H*hd] in q's dtype (a dead slot's rows are zeros)."""
     S, W, D = q.shape
-    if D != k_arena.shape[-1] or D % n_heads or v_arena.shape != k_arena.shape:
-        raise ValueError(f"q {q.shape} / heads {n_heads} do not match arenas "
-                         f"{k_arena.shape}, {v_arena.shape}")
-    if tables.shape[0] != S or limits.shape != (S, W):
-        raise ValueError(f"tables {tables.shape} / limits {limits.shape} do "
-                         f"not match q {q.shape}")
+    G = n_heads if kv_heads is None else kv_heads
+    if (D % n_heads or n_heads % G or k_arena.shape[-1] != G * (D // n_heads)
+            or v_arena.shape != k_arena.shape):
+        raise ValueError(
+            f"q {q.shape} of {n_heads} heads over kv_heads={G} K/V heads do not "
+            f"match arenas {k_arena.shape}, {v_arena.shape}: an arena holds "
+            f"kv_heads * head_dim lanes, and kv_heads divides n_heads")
+    if tables.shape[0] != S or limits.shape != (S, W) or (
+            starts is not None and starts.shape != (S, W)):
+        raise ValueError(f"tables {tables.shape} / limits {limits.shape} / starts "
+                         f"{None if starts is None else starts.shape} do not "
+                         f"match q {q.shape}")
     # off the TPU the same kernel is emulated, as kernels/attention.py does
     return _paged_call(q, k_arena, v_arena, tables, limits,
-                       jnp.asarray(layer, jnp.int32).reshape(1),
-                       n_heads=n_heads,
+                       jnp.asarray(layer, jnp.int32).reshape(1), starts,
+                       n_heads=n_heads, kv_heads=G,
                        interpret=jax.default_backend() != "tpu")
 
 
 # jitted with the layer as DATA: a model's layers share one trace and one
 # Mosaic lowering of the kernel (traced a layer, 36 of them cost a served
 # model 7 s of set-up before any compile cache is asked)
-@functools.partial(jax.jit, static_argnames=("n_heads", "interpret"))
-def _paged_call(q, k_arena, v_arena, tables, limits, layer, *, n_heads: int,
-                interpret: bool):
-    S, W, D = q.shape
-    bT = k_arena.shape[2]
-    H, hd, MB = n_heads, D // n_heads, tables.shape[1]
+@functools.partial(jax.jit, static_argnames=("n_heads", "kv_heads", "interpret"))
+def _paged_call(q, k_arena, v_arena, tables, limits, layer, starts=None, *,
+                n_heads: int, kv_heads: int, interpret: bool):
+    S, W, _ = q.shape
+    bT, D = k_arena.shape[2], k_arena.shape[3]
+    H, G, MB = n_heads, kv_heads, tables.shape[1]
+    hd = D // G
     C = max(1, min(_CHUNK_T // bT, MB))          # blocks per work item
     Hp = -(-H // 16) * 16                        # whole bf16 sublane tiles
     max_items = S * -(-MB // C)
-    nblk, wslot, wchunk, nwork = _work_list(limits, bT, C, max_items)
+    windowed = starts is not None
+    scalars = _work_list(limits, bT, C, max_items, starts)
+    if windowed:   # (.., nwork, starts, fblk): the kernel's order
+        scalars = (*scalars[:4], starts.reshape(-1).astype(jnp.int32), scalars[4])
 
     kernel = functools.partial(
-        _kernel, W=W, H=H, hd=hd, Hp=Hp, bT=bT, C=C, MB=MB,
-        scale=1.0 / math.sqrt(hd))
-    rows = pl.BlockSpec((S * W, D), lambda i, *_: (0, 0))
+        _kernel, W=W, H=H, G=G, hd=hd, Hp=Hp, bT=bT, C=C, MB=MB,
+        scale=1.0 / math.sqrt(hd), windowed=windowed)
+    if G == H:
+        rows = pl.BlockSpec((S * W, D), lambda i, *_: (0, 0))
+        qrows = q.reshape(S * W, D)
+    else:  # a query's heads as rows, up to whole sublane tiles
+        rows = pl.BlockSpec((S * W * Hp, hd), lambda i, *_: (0, 0))
+        qrows = jnp.pad(q.reshape(S * W, H, hd), ((0, 0), (0, Hp - H), (0, 0))
+                        ).reshape(S * W * Hp, hd)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=7,
+            num_scalar_prefetch=3 + len(scalars),
             grid=(1,),
             in_specs=[rows, hbm, hbm],
             out_specs=rows,
@@ -218,13 +292,15 @@ def _paged_call(q, k_arena, v_arena, tables, limits, layer, *, n_heads: int,
                 pltpu.VMEM((W * Hp, 1), jnp.float32),
                 pltpu.VMEM((W * Hp, D), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((S * W, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(qrows.shape, jnp.float32),
         interpret=interpret,
         name="paged_decode_attn",  # what a device trace calls the kernel
     )(layer, tables.reshape(-1).astype(jnp.int32),
-      limits.reshape(-1).astype(jnp.int32), nblk, wslot, wchunk, nwork,
-      q.reshape(S * W, D).astype(jnp.float32), k_arena, v_arena)
-    return out.reshape(S, W, D).astype(q.dtype)
+      limits.reshape(-1).astype(jnp.int32), *scalars,
+      qrows.astype(jnp.float32), k_arena, v_arena)
+    if G != H:
+        out = out.reshape(S * W, Hp, hd)[:, :H]
+    return out.reshape(S, W, H * hd).astype(q.dtype)
 
 
 # ------------------------------------------------- absorbed latent attention

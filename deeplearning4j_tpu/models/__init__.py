@@ -25,6 +25,7 @@ from .text_lstm import TextGenerationLSTM
 from .zoo_ext import AlexNet, Darknet19, SqueezeNet, UNet, Xception
 from .kimi_k2 import KimiK2Config
 from .keye_vl import KeyeVLConfig  # served only, as kimi_k2: no loss, no backward
+from .trinity import TrinityConfig  # served only: the forward of its windowed kernels
 from .vae import VariationalAutoencoder
 from .yolo import TinyYOLO, Yolo2OutputLayer
 
@@ -32,6 +33,7 @@ __all__ = [
     "AlexNet", "Darknet19", "SqueezeNet", "UNet", "Xception",
     "KimiK2Config",
     "KeyeVLConfig",
+    "TrinityConfig",
     "VariationalAutoencoder", "TinyYOLO", "Yolo2OutputLayer",
     "TransformerConfig",
     "transformer_forward",

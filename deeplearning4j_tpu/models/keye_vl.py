@@ -438,6 +438,7 @@ class SparseGQADecodeFamily:
     The pool hands text positions; the three channels are made equal here."""
 
     speculative = False           # a verify window is not built for this family
+    shares_prefix = True          # every block lives as long as its request
     stat_names = STEP_STATS
     name = "keye_vl"
 

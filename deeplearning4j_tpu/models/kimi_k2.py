@@ -442,6 +442,7 @@ class LatentDecodeFamily:
     counters."""
 
     speculative = False           # a verify window is not built for this family
+    shares_prefix = True          # every block lives as long as its request
     stat_names = MOE_STATS
     name = "kimi_k2"
 

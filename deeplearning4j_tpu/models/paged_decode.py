@@ -37,6 +37,15 @@ share a handful of prefill executables.
   the prefix index already holds, and raises :class:`NoFreeBlocksError`
   (``retry_admission = True``) when the arena cannot hold it NOW — the
   serving executor re-queues instead of failing the request;
+- **cache groups** — layers with a sliding window never read a key more than
+  ``window`` positions back, so their blocks need not outlive it. A windowed
+  group maps at most ``window / block_T + 2`` blocks a slot: prefill stores
+  only the rows a later query can still see (the rest go to the trash
+  block), and every ``step`` hands the blocks that fell behind the window
+  back to the group's free list, on the host, and maps the next one from
+  what the admission reserved. An admission is priced in every group (the
+  whole span, or ``min`` of that and ``window / block_T + 2``) and waits when
+  ANY group is short;
 - **speculative decoding** — with a small draft model from the same zoo, one
   jitted step drafts ``k`` greedy tokens (k+1 chained single-token passes
   over the draft's own paged arena, sharing the block tables) and verifies
@@ -51,18 +60,35 @@ no model and no kernel. The config it is given answers ``decode_family()``
 with an object of its model's file (``transformer.TransformerDecodeFamily``:
 K and V arenas of ``H*hd``; ``kimi_k2.LatentDecodeFamily``: one latent arena;
 ``keye_vl.SparseGQADecodeFamily``: K, V and an index-key arena, and XLA's
-gather of the rows its indexer selected in place of a kernel),
+gather of the rows its indexer selected in place of a kernel;
+``trinity.WindowedGQADecodeFamily``: K and V of its full-attention layers in
+one cache group and of its sliding-window layers in another),
 which writes its rows with :func:`_write_window` and attends through the
 tables with a kernel of ``kernels/paged_attention.py``. The families share the
 allocator, the tables, the prefix index, copy-on-write, admit / step /
 release, the counters and the donated in-place programs below. What a family
-answers, stated here once (no base class: three implementations and this list):
+answers, stated here once (no base class: four implementations and this list):
 
 - ``name``; ``speculative`` (whether ``decode_window`` takes W > 1 tokens a
   slot, which a verify window needs); ``n_layers``; ``cache_widths`` (what one
   token stores in a block: one arena ``[L, n_blocks, block_T, width]`` a
   width) and ``cache_dtype``; ``stat_names`` (the int32 counters a step
   returns, which come back in the one fetch that brings the tokens);
+- ``cache_groups`` and ``arena_groups``: a family whose layers do not all
+  keep a request's whole context answers a tuple of :class:`CacheGroup`
+  (``n_layers`` layers with ONE ``window``; ``None``: a block lives as long
+  as the request) and, an arena, the group it belongs to; an arena of group
+  g is ``[g.n_layers, n_blocks_g, block_T, width]``. The pool keeps, a group,
+  its own allocator and its own table ``[slots, max_blocks]`` (logical block
+  -> physical; a block a windowed group handed back reads 0), and
+  ``prefill`` / ``decode_window`` take and return the arenas in the family's
+  order while ``tables`` is a TUPLE, one a group. A family that does not
+  answer is one group of all its layers with ``window None``, and ``tables``
+  is the one array it always was;
+- ``shares_prefix``: whether the prefix index and copy-on-write apply. A
+  family with a windowed group answers False (a block that is handed back
+  behind the window cannot be a later request's prefix), and the pool then
+  neither looks a prompt up nor registers it;
 - ``resident(params) -> params``: what the family's programs read, made ONCE
   from what the caller holds when the pool is constructed, and kept as
   ``pool.params``: every leaf that a step would cast before use is cast here
@@ -74,7 +100,8 @@ answers, stated here once (no base class: three implementations and this list):
   [D], rows: one [L, Tb, width] an arena)``;
 - ``decode_window(params, tokens [S, W], positions [S, W], arenas, tables)
   -> (logits [S, W, V], arenas written in place, stats or None)``: a slot is
-  live iff its logical block 0 is mapped;
+  live iff its logical block 0 is mapped (in a group whose blocks live as
+  long as the request);
 - ``head(params, h [N, D]) -> logits [N, V]``;
 - ``cumulative_stats(sums, steps) -> dict``: what ``block_stats()`` shows of
   the running sums of ``stat_names``.
@@ -86,7 +113,7 @@ driver) is the only caller — no internal locking.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -172,6 +199,53 @@ class BlockAllocator:
         return self._index.get(key)
 
 
+class CacheGroup(NamedTuple):
+    """Layers of a family that keep the same span of a request's context:
+    ``n_layers`` of them, each reading no key more than ``window`` positions
+    back (``None``: the whole context, so a block lives as long as the
+    request)."""
+
+    n_layers: int
+    window: Optional[int] = None
+
+
+class _Group:
+    """One cache group's share of the pool's host state: its allocator, its
+    table and, a slot, the logical blocks it maps (``lo .. hi - 1``) and the
+    blocks its admission reserved and has not mapped yet (``owed``)."""
+
+    def __init__(self, spec: CacheGroup, *, slots: int, block_T: int,
+                 max_blocks: int, n_blocks: Optional[int]):
+        self.n_layers, self.window = spec.n_layers, spec.window
+        #: most blocks a slot maps at once: a window's span laid over block
+        #: boundaries and the block the next token opens
+        self.cap = (max_blocks if self.window is None
+                    else min(max_blocks, self.window // block_T + 2))
+        self.n_blocks = n_blocks or 1 + slots * self.cap
+        if self.n_blocks < 2:
+            raise ValueError("n_blocks must be >= 2 (1 usable + trash)")
+        self.alloc = BlockAllocator(self.n_blocks)
+        self.tables = np.zeros((slots, max_blocks), np.int32)
+        self.lo = np.zeros(slots, np.int32)
+        self.hi = np.zeros(slots, np.int32)
+        self.owed = np.zeros(slots, np.int32)
+
+    def price(self, nblocks: int) -> int:
+        """Blocks an admission of ``nblocks`` logical blocks holds at most."""
+        return min(nblocks, self.cap)
+
+    def first_needed(self, position: int, block_T: int) -> int:
+        """First logical block a query at ``position`` still sees."""
+        if self.window is None:
+            return 0
+        return max(0, position - self.window + 1) // block_T
+
+    def reset(self) -> None:
+        self.alloc = BlockAllocator(self.n_blocks)
+        self.tables[:] = 0
+        self.lo[:] = self.hi[:] = self.owed[:] = 0
+
+
 def _write_window(arena, layer: int, tables, limits, x):
     """``arena[layer, block, cell] = x[s, w]`` at position ``limits[s, w] - 1``
     of every live slot s, through its table, in place; nothing of a dead slot
@@ -211,13 +285,18 @@ class PagedDecodeSlotPool:
       token per step plain, up to ``spec_tokens + 1`` speculative), each
       list clamped to the slot's remaining ``max_new_tokens`` budget.
 
+    ``n_blocks`` sizes the arena (default: what ``slots`` full-length
+    requests hold at most, + the trash block): one number, or one a cache
+    group for a family that names several, and ``pool.n_blocks`` reads back
+    the same way.
+
     Pass ``draft_params``/``draft_cfg`` (a smaller config from the same
     zoo — same vocab, causal) to enable speculative decoding with
     ``spec_tokens`` drafted per target step.
     """
 
-    def __init__(self, params, cfg, *, slots: int = 8,
-                 block_T: int = 16, n_blocks: Optional[int] = None,
+    def __init__(self, params, cfg, *, slots: int = 8, block_T: int = 16,
+                 n_blocks: Union[int, Sequence[int], None] = None,
                  max_len: Optional[int] = None, eos_id: Optional[int] = None,
                  min_prompt_bucket: int = 16,
                  draft_params=None, draft_cfg=None, spec_tokens: int = 4):
@@ -254,9 +333,38 @@ class PagedDecodeSlotPool:
         self.block_T = block_T
         self.eos_id = eos_id
         self.max_blocks = self.max_len // block_T  # logical blocks per slot
-        self.n_blocks = n_blocks or (1 + slots * self.max_blocks)
-        if self.n_blocks < 2:
-            raise ValueError("n_blocks must be >= 2 (1 usable + trash)")
+        # the family's cache groups (one of all layers, for a family that
+        # names none): an allocator, a table and ``n_blocks`` each, by default
+        # what ``slots`` full-length requests hold at most
+        specs = tuple(getattr(fam, "cache_groups", None)
+                      or (CacheGroup(fam.n_layers),))
+        self._arena_groups = tuple(getattr(fam, "arena_groups", None)
+                                   or (0,) * len(fam.cache_widths))
+        each = (tuple(n_blocks) if isinstance(n_blocks, (tuple, list))
+                else (n_blocks,) * len(specs))
+        if len(each) != len(specs) or len(self._arena_groups) != len(fam.cache_widths):
+            raise ValueError(
+                f"the {fam.name} family has {len(specs)} cache group(s) over "
+                f"{len(fam.cache_widths)} arenas: got n_blocks {n_blocks!r}, "
+                f"arena_groups {self._arena_groups!r}")
+        self._groups = [
+            _Group(spec, slots=slots, block_T=block_T, max_blocks=self.max_blocks,
+                   n_blocks=each[g]) for g, spec in enumerate(specs)]
+        self._windowed = any(g.window is not None for g in self._groups)
+        self._shares_prefix = bool(getattr(fam, "shares_prefix", True))
+        if len(specs) > 1 and (self._shares_prefix or draft_cfg is not None):
+            raise ValueError(
+                f"the {fam.name} family has {len(specs)} cache groups: the "
+                f"prefix index, copy-on-write and a draft model's arenas go by "
+                f"ONE table (shares_prefix must be False, and no draft)")
+        if self._windowed and (self._shares_prefix or specs[0].window is not None):
+            raise ValueError(
+                f"the {fam.name} family has a windowed cache group: a block "
+                f"handed back behind the window can be no prefix of a later "
+                f"request (shares_prefix must be False), and group 0 says "
+                f"which slots are live by its block 0 (its window must be None)")
+        self.n_blocks = (self._groups[0].n_blocks if len(specs) == 1
+                         else tuple(g.n_blocks for g in self._groups))
         # bucket sizes must stay block-aligned so prefill scatter is whole blocks
         self.min_prompt_bucket = max(1, min_prompt_bucket, block_T)
 
@@ -283,18 +391,15 @@ class PagedDecodeSlotPool:
         self._resident_weight_bytes = sum({
             id(x): math.prod(x.shape) * jnp.dtype(x.dtype).itemsize
             for x in jax.tree.leaves((self.params, self.draft_params))}.values())
-        self._alloc = BlockAllocator(self.n_blocks)
         self._arenas = tuple(self._new_arena(cfg))
-        self._draft_arenas = (tuple(self._new_arena(draft_cfg))
+        self._draft_arenas = (tuple(self._new_arena(draft_cfg, draft=True))
                               if draft_cfg is not None else ())
-        self._tables = np.zeros((slots, self.max_blocks), np.int32)
         self._active = np.zeros(slots, bool)
         self._positions = np.zeros(slots, np.int32)
         self._tokens = np.zeros(slots, np.int32)
         self._budget = np.zeros(slots, np.int32)    # max_new_tokens per slot
         self._emitted = np.zeros(slots, np.int32)   # tokens handed to caller
         self._span = np.zeros(slots, np.int32)      # reserved position span
-        self._nblocks = np.zeros(slots, np.int32)   # logical blocks owned
         self._cow_reserve = np.zeros(slots, np.int32)
         self._joined: Dict[int, Dict[int, int]] = {}  # slot -> {logical: phys}
         #: seconds the last ``step()`` blocked reading its result back (the
@@ -307,6 +412,15 @@ class PagedDecodeSlotPool:
         # up to their live length) against the blocks the tables map
         self.kv_blocks_read = 0
         self.kv_blocks_mapped = 0
+        # a pool with a windowed group also counts, cumulatively: the blocks a
+        # step's attention is asked to visit in a windowed layer, the blocks
+        # handed back behind a window, and the cached rows a step's attention
+        # reads summed over ALL layers beside what it would read without a
+        # window
+        self.kv_blocks_read_windowed = 0
+        self.kv_window_blocks_freed = 0
+        self.swa_rows_read = 0
+        self.swa_rows_windowless = 0
         # what the family's steps counted (``stat_names``): the last step's
         # and the running sums; a family that counts nothing has neither
         self.last_step_stats: Dict[str, int] = {}
@@ -322,15 +436,19 @@ class PagedDecodeSlotPool:
         k = self.spec_tokens
         n = len(fam.cache_widths)                        # arenas of the target
         m = len(dfam.cache_widths) if spec else 0        # and of the draft
+        ng = len(self._groups)                           # tables, dest lists
+        of = self._arena_groups
 
         # every program takes its arenas as leading positional arguments
         # (after the parameters), donates them and returns them first
 
         def _decode(params, *args):
             self.decode_traces += 1
-            arenas, (tables, tokens, positions) = args[:n], args[n:]
+            arenas, tables, (tokens, positions) = args[:n], args[n:n + ng], args[n + ng:]
+            # one group: the table itself, as every family before groups
             logits, arenas, stats = fam.decode_window(
-                params, tokens[:, None], positions[:, None], arenas, tables)
+                params, tokens[:, None], positions[:, None], arenas,
+                tables[0] if ng == 1 else tables)
             nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
             # the step's counters ride behind the tokens: one fetch brings both
             out = nxt if stats is None else jnp.concatenate([nxt, stats])
@@ -364,26 +482,30 @@ class PagedDecodeSlotPool:
             n_acc = 1 + jnp.cumprod(acc, axis=1).sum(axis=1)
             return (*arenas, *darenas, ver, n_acc.astype(jnp.int32))
 
-        def _store(family, params, arenas, dest_blocks, tokens, length):
+        def _store(family, params, arenas, dests, tokens, length):
             """Prefill through ``family``: (arenas with the prompt's rows in
-            their blocks, the last live hidden state)."""
+            their blocks, the last live hidden state). ``dests``: a
+            destination list a cache group (a draft's arenas are all of the
+            one group)."""
             last, rows = family.prefill(params, tokens, length)
             arenas = tuple(
-                _write_blocks(a, dest_blocks, r.reshape(
+                _write_blocks(a, dests[of[i] if len(dests) > 1 else 0], r.reshape(
                     r.shape[0], r.shape[1] // bT, bT, r.shape[2]))
-                for a, r in zip(arenas, rows))
+                for i, (a, r) in enumerate(zip(arenas, rows)))
             return arenas, last
 
         def _prefill(params, *args):
             self.prefill_traces += 1
-            arenas, last = _store(fam, params, args[:n], *args[n:])
+            arenas, last = _store(fam, params, args[:n], args[n:n + ng],
+                                  *args[n + ng:])
             logits = fam.head(params, last[None])[0]
             return (*arenas, jnp.argmax(logits, axis=-1).astype(jnp.int32))
 
         def _prefill_spec(params, dparams, *args):
             self.prefill_traces += 1
-            arenas, last = _store(fam, params, args[:n], *args[n + m:])
-            darenas, _ = _store(dfam, dparams, args[n:n + m], *args[n + m:])
+            dest, rest = args[n + m:n + m + 1], args[n + m + 1:]
+            arenas, last = _store(fam, params, args[:n], dest, *rest)
+            darenas, _ = _store(dfam, dparams, args[n:n + m], dest, *rest)
             logits = fam.head(params, last[None])[0]
             return (*arenas, *darenas,
                     jnp.argmax(logits, axis=-1).astype(jnp.int32))
@@ -410,11 +532,28 @@ class PagedDecodeSlotPool:
             self._prefill_fn = jax.jit(_prefill, donate_argnums=donated)
         self._copy_fn = jax.jit(_copy, donate_argnums=tuple(range(n + m)))
 
-    def _new_arena(self, cfg):
-        """Zeroed arenas for ``cfg``'s family: one a cache width."""
+    def _new_arena(self, cfg, draft: bool = False):
+        """Zeroed arenas for ``cfg``'s family: one a cache width, each with
+        its group's layers and blocks (a draft's arenas go by the one table
+        of group 0)."""
         fam = cfg.decode_family()
-        return tuple(jnp.zeros((fam.n_layers, self.n_blocks, self.block_T, w),
-                               fam.cache_dtype) for w in fam.cache_widths)
+        if draft:
+            g = self._groups[0]
+            return tuple(jnp.zeros((fam.n_layers, g.n_blocks, self.block_T, w),
+                                   fam.cache_dtype) for w in fam.cache_widths)
+        return tuple(
+            jnp.zeros((self._groups[g].n_layers, self._groups[g].n_blocks,
+                       self.block_T, w), fam.cache_dtype)
+            for g, w in zip(self._arena_groups, fam.cache_widths))
+
+    # one group's allocator and table under the names they had before groups
+    @property
+    def _alloc(self) -> BlockAllocator:
+        return self._groups[0].alloc
+
+    @property
+    def _tables(self) -> np.ndarray:
+        return self._groups[0].tables
 
     def _set_arenas(self, arenas) -> None:
         n = len(self._arenas)
@@ -446,9 +585,10 @@ class PagedDecodeSlotPool:
 
     @property
     def total_blocks(self) -> int:
-        """Usable arena blocks (trash block excluded) — the capacity an
-        admission's worst-case block price is checked against at the door."""
-        return self.n_blocks - 1
+        """Usable arena blocks (trash blocks excluded), summed over the cache
+        groups — the capacity an admission's worst-case block price
+        (``request_blocks``, summed likewise) is checked against at the door."""
+        return sum(g.n_blocks - 1 for g in self._groups)
 
     @property
     def admit_overhead_tokens(self) -> int:
@@ -458,9 +598,11 @@ class PagedDecodeSlotPool:
         return self.spec_tokens
 
     def request_blocks(self, prompt_len: int, max_new_tokens: int) -> int:
-        """Worst-case (no sharing) block price of a request."""
+        """Worst-case (no sharing) block price of a request: in every cache
+        group, the blocks of its whole span or, windowed, no more than the
+        group ever maps for a slot."""
         span = prompt_len + max_new_tokens + self.spec_tokens
-        return -(-span // self.block_T)
+        return sum(g.price(-(-span // self.block_T)) for g in self._groups)
 
     def prompt_bucket(self, n: int) -> int:
         from ..common.bucketing import bucket_size
@@ -480,17 +622,35 @@ class PagedDecodeSlotPool:
         two views share counts once). A family whose steps count
         (``stat_names``) adds what it makes of the running sums
         (``cumulative_stats``: the latent family's ``moe_*`` counters); for
-        another family they are absent."""
+        another family they are absent. A pool with a windowed cache group
+        adds ``kv_blocks_read_windowed`` (``kv_blocks_read``'s count for ONE
+        windowed layer: the blocks from the first a slot's query still sees),
+        ``kv_window_blocks_freed`` (blocks handed back behind a window),
+        ``swa_rows_read`` / ``swa_rows_windowless`` (cached rows a step's
+        attention reads, summed over all layers, beside what it would read
+        with no window) and ``blocks_total_g<i>`` / ``blocks_free_g<i>`` a
+        group; ``blocks_total`` / ``blocks_free`` are sums over the groups."""
         rc = self._alloc.refcount[1:]  # trash block is bookkeeping, not capacity
         fam = self.family
+        grouped = {}
+        if self._windowed:
+            grouped = {"kv_blocks_read_windowed": self.kv_blocks_read_windowed,
+                       "kv_window_blocks_freed": self.kv_window_blocks_freed,
+                       "swa_rows_read": self.swa_rows_read,
+                       "swa_rows_windowless": self.swa_rows_windowless}
+            for i, g in enumerate(self._groups):
+                grouped[f"blocks_total_g{i}"] = g.n_blocks - 1
+                grouped[f"blocks_free_g{i}"] = g.alloc.free_blocks
         return {
+            **grouped,
             **fam.cumulative_stats(self.family_stats, self.family_steps),
             "kv_cache_bytes_per_token": int(
-                fam.n_layers * sum(fam.cache_widths)
+                sum(self._groups[g].n_layers * w
+                    for g, w in zip(self._arena_groups, fam.cache_widths))
                 * jnp.dtype(fam.cache_dtype).itemsize),
             "resident_weight_bytes": self._resident_weight_bytes,
             "blocks_total": self.total_blocks,
-            "blocks_free": self._alloc.free_blocks,
+            "blocks_free": sum(g.alloc.free_blocks for g in self._groups),
             "cow_shared_blocks": int((rc > 1).sum()),
             "cow_saved_blocks": int(np.maximum(rc - 1, 0).sum()),
             "spec_proposed": self.spec_proposed,
@@ -501,13 +661,23 @@ class PagedDecodeSlotPool:
 
     def cached_rows(self, slot: int, n: int):
         """What the arenas hold of ``slot``'s first ``n`` positions, through
-        its block table: one [L, n, width] array an arena. For checks and
-        tests (a copy; the arenas stay where they are)."""
+        its group's block table: one [L_group, n, width] array an arena (a
+        position whose block a windowed group handed back reads the trash
+        block). For checks and tests (a copy; the arenas stay where they
+        are)."""
         if not self._active[slot]:
             raise ValueError(f"slot {slot} is not active")
         pos = np.arange(n)
-        block = self._tables[slot, pos // self.block_T]
-        return tuple(a[:, block, pos % self.block_T] for a in self._arenas)
+        return tuple(
+            a[:, self._groups[g].tables[slot, pos // self.block_T], pos % self.block_T]
+            for g, a in zip(self._arena_groups, self._arenas))
+
+    def block_tables(self, slot: int):
+        """``slot``'s table rows, one a cache group (logical block -> physical;
+        0: unmapped). For checks and tests (copies)."""
+        if not self._active[slot]:
+            raise ValueError(f"slot {slot} is not active")
+        return tuple(g.tables[slot].copy() for g in self._groups)
 
     # -- admission planning ------------------------------------------------
 
@@ -528,7 +698,7 @@ class PagedDecodeSlotPool:
                 f"exceeds the {self.max_len}-position KV cache")
         bT = self.block_T
         nblocks = -(-span // bT)
-        fb = n // bT
+        fb = n // bT if self._shares_prefix else 0   # no lookup: nothing shared
         shared_full: List[int] = []
         for i in range(fb):
             b = self._alloc.lookup(("full", toks[:(i + 1) * bT].tobytes()))
@@ -536,7 +706,7 @@ class PagedDecodeSlotPool:
                 break
             shared_full.append(b)
         tail = None
-        if len(shared_full) == fb and n % bT:
+        if self._shares_prefix and len(shared_full) == fb and n % bT:
             tail = self._alloc.lookup(("tail", toks.tobytes()))
         new_needed = nblocks - len(shared_full) - (0 if tail is None else 1)
         reserve = 0 if tail is None else 1
@@ -547,10 +717,23 @@ class PagedDecodeSlotPool:
         — the executor's queue-head gate.  False means 'not NOW'; a
         never-fits request raises the same ValueError ``admit`` would."""
         toks = np.asarray(prompt, np.int32).reshape(-1)
-        _, _, _, _, new_needed, reserve = self._plan(toks, max_new_tokens)
+        _, nblocks, _, _, new_needed, reserve = self._plan(toks, max_new_tokens)
         if not (~self._active).any():
             return False
-        return self._alloc.free_blocks >= new_needed + reserve
+        return not self._short_group(nblocks, new_needed + reserve)
+
+    def _short_group(self, nblocks: int, first_needs: int) -> Optional[str]:
+        """What the cache group that cannot hold an admission NOW lacks (None:
+        every group can). The one group of a family that shares prefixes
+        needs ``first_needs`` (sharing counted); a group of another family
+        the price of ``nblocks`` logical blocks."""
+        for i, g in enumerate(self._groups):
+            need = first_needs if self._shares_prefix else g.price(nblocks)
+            if g.alloc.free_blocks < need:
+                return (f"{need} new KV blocks in cache group {i} "
+                        f"(window {g.window}) but only {g.alloc.free_blocks} of "
+                        f"{g.n_blocks - 1} are free")
+        return None
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -567,14 +750,28 @@ class PagedDecodeSlotPool:
         free = np.flatnonzero(~self._active)
         if free.size == 0:
             raise RuntimeError("no free decode slot")
-        if self._alloc.free_blocks < new_needed + reserve:
+        short = self._short_group(nblocks, new_needed + reserve)
+        if short:
             raise NoFreeBlocksError(
-                f"admission needs {new_needed} new KV blocks"
-                f"{f' (+{reserve} CoW reserve)' if reserve else ''} but only "
-                f"{self._alloc.free_blocks} of {self.total_blocks} are free")
+                f"admission needs {short}"
+                f"{f' (+{reserve} of them a CoW reserve)' if reserve else ''}")
         slot = int(free[0])
         bT = self.block_T
         fb = n // bT
+
+        # every group but the first maps the blocks a LATER query still sees
+        # (all of them where there is no window) and the one the first new
+        # token opens; what else its price covers is reserved, and mapped as
+        # decoding gets there
+        rows_stored = [n]
+        for g in self._groups[1:]:
+            lo = g.first_needed(n, bT)
+            hi = nblocks if g.window is None else min(nblocks, n // bT + 1)
+            g.tables[slot, lo:hi] = g.alloc.alloc(hi - lo)  # a free slot's row is zeros
+            g.lo[slot], g.hi[slot] = lo, hi
+            g.owed[slot] = g.price(nblocks) - (hi - lo)
+            g.alloc.reserved += int(g.owed[slot])
+            rows_stored.append(n - lo * bT)
 
         new_blocks = self._alloc.alloc(new_needed)
         for b in shared_full:
@@ -607,10 +804,16 @@ class PagedDecodeSlotPool:
         for j in range(bucket // bT):
             if j < nblocks and row[j] not in shared_set:
                 dest[j] = row[j]
+        # another group's list: its mapped blocks; rows behind its window go
+        # to the trash block with the bucket's padding
+        dests = [dest] + [g.tables[slot, :bucket // bT].copy()
+                          for g in self._groups[1:]]
+        stored = ({f"rows_stored_g{i}": r for i, r in enumerate(rows_stored)}
+                  if len(self._groups) > 1 else {})
         try:
-            with span("kv.prefill", bucket=bucket,
-                      shared_blocks=len(shared_set), new_blocks=len(new_blocks)):
-                first = self._run(self._prefill_fn, dest, padded, np.int32(n))[0]
+            with span("kv.prefill", bucket=bucket, shared_blocks=len(shared_set),
+                      new_blocks=len(new_blocks), **stored):
+                first = self._run(self._prefill_fn, *dests, padded, np.int32(n))[0]
                 with span("kv.prefill.fetch"):
                     first = int(first)  # the host waits for the prefill here
         except Exception as e:
@@ -621,21 +824,21 @@ class PagedDecodeSlotPool:
                 f"sequences lost") from e
 
         # publish this prompt's freshly WRITTEN blocks for future sharers
-        for i in range(fb):
+        for i in range(fb if self._shares_prefix else 0):
             if i >= len(shared_full):
                 self._alloc.register(("full", toks[:(i + 1) * bT].tobytes()),
                                      int(row[i]))
-        if n % bT and tail is None:
+        if self._shares_prefix and n % bT and tail is None:
             self._alloc.register(("tail", toks.tobytes()), int(row[fb]))
 
         self._tables[slot] = row
+        self._groups[0].lo[slot], self._groups[0].hi[slot] = 0, nblocks
         self._active[slot] = True
         self._positions[slot] = n
         self._tokens[slot] = first
         self._budget[slot] = max_new_tokens
         self._emitted[slot] = 1
         self._span[slot] = n_span
-        self._nblocks[slot] = nblocks
         self._joined[slot] = joined
         return slot, first
 
@@ -689,8 +892,9 @@ class PagedDecodeSlotPool:
             s = int(s)
             self._cow_before_write(s, int(self._positions[s]),
                                    int(self._positions[s]) + window - 1)
+        grouped = self._slide_windows(live) if self._windowed else {}
         with span("kv.step.upload"):
-            tables = jnp.asarray(self._tables)
+            tables = [jnp.asarray(g.tables) for g in self._groups]
             toks = jnp.asarray(self._tokens)
             pos = jnp.asarray(self._positions)
         live_blocks = int(
@@ -703,8 +907,9 @@ class PagedDecodeSlotPool:
             # a step's own routing is known when its tokens come back: the
             # span carries the counters of the step fetched last
             with span("kv.step.dispatch", live_blocks=live_blocks,
-                      mapped_blocks=mapped_blocks, **self.last_step_stats):
-                results = self._run(self._decode_fn, tables, toks, pos)
+                      mapped_blocks=mapped_blocks, **grouped,
+                      **self.last_step_stats):
+                results = self._run(self._decode_fn, *tables, toks, pos)
             # the one host round trip a step (S4): the device runs the step
             # while the host waits here
             with span("kv.step.fetch") as fetch:
@@ -748,24 +953,67 @@ class PagedDecodeSlotPool:
             self._emitted[slot] += take
         return out
 
+    def _slide_windows(self, live) -> Dict[str, int]:
+        """Before a step, on the host, for every windowed cache group and live
+        slot: hand the blocks that fell wholly behind the window of the
+        step's query back to the group's free list (the table entry reads 0
+        from now on: the kernel never looks there again) and map the block
+        the step writes into, from what the admission reserved. Counts the
+        step's rows and blocks; returns what ``kv.step.dispatch`` carries of
+        them."""
+        bT = self.block_T
+        at = self._positions[live].astype(np.int64)
+        freed, carried = 0, {}
+        self.swa_rows_windowless += int((at + 1).sum()) * self.family.n_layers
+        for i, g in enumerate(self._groups):
+            if g.window is None:
+                self.swa_rows_read += int((at + 1).sum()) * g.n_layers
+                carried[f"live_blocks_g{i}"] = int((at // bT + 1).sum())
+                continue
+            for s in live:
+                s, p = int(s), int(self._positions[s])
+                first = g.first_needed(p, bT)
+                while g.lo[s] < min(first, g.hi[s]):
+                    g.alloc.unref(int(g.tables[s, g.lo[s]]))
+                    g.tables[s, g.lo[s]] = 0
+                    g.lo[s] += 1
+                    g.owed[s] += 1
+                    g.alloc.reserved += 1
+                    freed += 1
+                while g.hi[s] <= p // bT:
+                    g.owed[s] -= 1
+                    g.alloc.reserved -= 1
+                    g.tables[s, g.hi[s]] = g.alloc.alloc(1)[0]
+                    g.hi[s] += 1
+            seen = int((at // bT - np.maximum(at - g.window + 1, 0) // bT + 1).sum())
+            self.kv_blocks_read_windowed += seen
+            self.swa_rows_read += int(np.minimum(at + 1, g.window).sum()) * g.n_layers
+            carried[f"live_blocks_g{i}"] = seen
+        self.kv_window_blocks_freed += freed
+        carried["window_blocks_freed"] = freed
+        return carried
+
     def release(self, slot: int) -> None:
         """Free a slot: drop its block references (shared blocks survive
         while other sequences or the prefix index's last holder need them),
-        return any unused CoW reserve, and clear the table row."""
+        return any unused CoW reserve and what a windowed group still held
+        reserved for it, and clear the table rows."""
         if not self._active[slot]:
             raise ValueError(f"slot {slot} is not active")
-        for lb in range(int(self._nblocks[slot])):
-            self._alloc.unref(int(self._tables[slot, lb]))
+        for g in self._groups:
+            for lb in range(int(g.lo[slot]), int(g.hi[slot])):
+                g.alloc.unref(int(g.tables[slot, lb]))
+            g.alloc.reserved -= int(g.owed[slot])
+            g.tables[slot] = 0
+            g.lo[slot] = g.hi[slot] = g.owed[slot] = 0
         self._alloc.reserved -= int(self._cow_reserve[slot])
         self._cow_reserve[slot] = 0
-        self._tables[slot] = 0
         self._active[slot] = False
         self._positions[slot] = 0
         self._tokens[slot] = 0
         self._budget[slot] = 0
         self._emitted[slot] = 0
         self._span[slot] = 0
-        self._nblocks[slot] = 0
         self._joined.pop(slot, None)
 
     def _reset_after_failure(self) -> None:
@@ -775,15 +1023,14 @@ class PagedDecodeSlotPool:
         riders); the pool itself keeps serving."""
         self._arenas = tuple(self._new_arena(self.cfg))
         if self.draft_cfg is not None:
-            self._draft_arenas = tuple(self._new_arena(self.draft_cfg))
-        self._alloc = BlockAllocator(self.n_blocks)
-        self._tables[:] = 0
+            self._draft_arenas = tuple(self._new_arena(self.draft_cfg, draft=True))
+        for g in self._groups:
+            g.reset()
         self._active[:] = False
         self._positions[:] = 0
         self._tokens[:] = 0
         self._budget[:] = 0
         self._emitted[:] = 0
         self._span[:] = 0
-        self._nblocks[:] = 0
         self._cow_reserve[:] = 0
         self._joined.clear()

@@ -511,6 +511,7 @@ class TransformerDecodeFamily:
     stores ``H*hd`` values in each of two arenas."""
 
     speculative = True   # ``decode_window`` takes W = spec_tokens + 1 tokens
+    shares_prefix = True  # every block lives as long as its request
     stat_names = ()      # a step counts nothing of its own
     name = "transformer"
 

@@ -434,3 +434,103 @@ def test_flash_kernels_feed_the_mxu_the_dtype_they_are_given(dtype):
         assert not casts
     else:
         assert casts and all(e.invars[0].aval.shape == (128, D) for e in casts)
+
+
+# -- the forward-only kernel: grouped K/V heads, a sliding window --------------
+
+
+@pytest.mark.parametrize("blocks", [(16, 16), (32, 16), (16, 32), (None, None)],
+                         ids=["16x16", "32x16", "16x32", "auto"])
+@pytest.mark.parametrize("window", [None, 1, 16, 40, 1000], ids=["full", "w1", "w16", "w40", "w1000"])
+@pytest.mark.parametrize("heads", [(6, 2), (4, 4), (8, 1)], ids=["H6G2", "H4G4", "H8G1"])
+def test_flash_window_and_grouped_heads_match_reference(heads, window, blocks):
+    """``window`` against ``mha_reference`` with the mask, K/V of fewer heads
+    read through the head index: blocks smaller than the window, larger, and
+    windows that are not whole blocks; T 112 pads up to whole blocks."""
+    H, G = heads   # (equal heads and no window: the differentiable kernels' case)
+    key = jax.random.key(11)
+    q = jax.random.normal(jax.random.fold_in(key, 0), (2, H, 112, 32), jnp.float32)
+    k, v = (jax.random.normal(jax.random.fold_in(key, i), (2, G, 112, 32), jnp.float32)
+            for i in (1, 2))
+    out = flash_attention(q, k, v, causal=True, window=window, block_q=blocks[0],
+                          block_k=blocks[1], interpret=True)
+    ref = mha_reference(q, k, v, causal=True, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+def test_flash_window_rectangular_queries_are_the_last_positions():
+    """Tq < Tk: the queries are the last Tq positions, as ``mha_reference``
+    has it, and the window counts from each query's own position."""
+    key = jax.random.key(5)
+    q = jax.random.normal(jax.random.fold_in(key, 0), (1, 4, 32, 16), jnp.float32)
+    k, v = (jax.random.normal(jax.random.fold_in(key, i), (1, 2, 96, 16), jnp.float32)
+            for i in (1, 2))
+    out = flash_attention(q, k, v, causal=True, window=24, block_q=16, block_k=16,
+                          interpret=True)
+    ref = mha_reference(q, k, v, causal=True, window=24)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+def test_flash_window_visits_only_the_blocks_some_query_sees():
+    """The key axis of the grid is as long as the window's span in blocks,
+    not as the sequence: NaN keys and values wholly behind every window of a
+    q-block are never read."""
+    from deeplearning4j_tpu.kernels.attention import _flash_forward_gqa
+
+    key = jax.random.key(2)
+    q = jax.random.normal(jax.random.fold_in(key, 0), (1, 2, 128, 16), jnp.float32)
+    k, v = (jax.random.normal(jax.random.fold_in(key, i), (1, 1, 128, 16), jnp.float32)
+            for i in (1, 2))
+    jaxpr = str(jax.make_jaxpr(lambda *a: _flash_forward_gqa(
+        *(x.transpose(0, 2, 1, 3) for x in a), window=32, scale=0.25, block_q=16,
+        block_k=16, interpret=False, q_offset=0))(q, k, v))
+    assert "grid=(2, 8, 4)" in jaxpr   # 8 q-blocks x (16 + 32 - 2) // 16 + 2 key blocks
+    # the last q-block alone, against keys whose first half is NaN
+    poisoned = k.at[:, :, :64].set(jnp.nan), v.at[:, :, :64].set(jnp.nan)
+    out = flash_attention(q[:, :, 112:], *poisoned, causal=True, window=32,
+                          block_q=16, block_k=16, interpret=True)
+    ref = mha_reference(q, k, v, causal=True, window=32)[:, :, 112:]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_equal_heads_no_window_is_the_call_as_it_was(causal):
+    """``window=None`` with equal heads takes the differentiable kernels, bit
+    for bit what the call without the argument gives, gradients too."""
+    q, k, v = _qkv((1, 2, 128, 32))
+    plain = flash_attention(q, k, v, causal=causal, interpret=True)
+    named = flash_attention(q, k, v, causal=causal, interpret=True, window=None)
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(named))
+    g = jax.grad(lambda q: flash_attention(q, k, v, causal=causal, interpret=True,
+                                           window=None).sum())(q)
+    assert np.isfinite(np.asarray(g)).all()
+
+
+def test_flash_window_refuses_what_the_forward_only_kernel_lacks():
+    q, k, v = _qkv((1, 4, 64, 16))
+    with pytest.raises(ValueError, match="forward-only"):
+        flash_attention(q, k, v, window=8, interpret=True)            # not causal
+    with pytest.raises(ValueError, match="forward-only"):
+        flash_attention(q, k[:, :2], v[:, :2], causal=True, interpret=True,
+                        mask=jnp.ones((1, 64)))
+    with pytest.raises(ValueError, match="divide"):
+        flash_attention(q, k[:, :3], v[:, :3], causal=True, interpret=True)
+
+
+def test_flash_heads_side_by_side_layout_is_the_same_attention():
+    """``layout="bthd"``: q [B, T, H, D], k / v [B, T, G, D], a head a block
+    of lanes of a row (no transpose on the way in or out); only the
+    forward-only kernel takes it."""
+    key = jax.random.key(4)
+    q = jax.random.normal(jax.random.fold_in(key, 0), (2, 6, 80, 16), jnp.float32)
+    k, v = (jax.random.normal(jax.random.fold_in(key, i), (2, 2, 80, 16), jnp.float32)
+            for i in (1, 2))
+    ref = mha_reference(q, k, v, causal=True, window=24)
+    out = flash_attention(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)), causal=True,
+                          window=24, block_q=16, block_k=16, interpret=True, layout="bthd")
+    np.testing.assert_allclose(np.asarray(out.transpose(0, 2, 1, 3)), np.asarray(ref),
+                               atol=2e-5)
+    with pytest.raises(ValueError, match="forward-only"):
+        flash_attention(q, q, q, layout="bthd", interpret=True)      # not causal
+    with pytest.raises(ValueError, match="layout"):
+        flash_attention(q, k, v, causal=True, layout="hbtd", interpret=True)

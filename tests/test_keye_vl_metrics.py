@@ -171,7 +171,7 @@ def test_the_cell_is_declared_with_the_issues_readers_and_judged_on_p90_and_serv
     readers that find something to read in it, and its new readers move what
     the issue says: four p90, the selection's row share ``serve_tok_s``."""
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    assert [w["name"] for w in bench["workloads"]][-1] == CELL
+    assert CELL in [w["name"] for w in bench["workloads"]]   # later PRs append theirs
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     lists = {m["name"]: m.get("workloads") for g in ("end_to_end", "per_layer")
              for m in bench[g]}

@@ -161,6 +161,138 @@ def test_kernel_refuses_mismatched_shapes():
                                layer=0, n_heads=2)
 
 
+# -- grouped heads and a first visible key --------------------------------------
+
+
+def grouped_reference(q, k_arena, v_arena, tables, limits, starts, layer,
+                      n_heads, kv_heads):
+    """softmax(q K^T / sqrt(hd)) V in float32 over the densely gathered view:
+    query head h against K/V head ``h // (n_heads / kv_heads)``, query w of
+    slot s over keys ``starts[s, w] .. limits[s, w] - 1``."""
+    S, W, _ = q.shape
+    hd = k_arena.shape[-1] // kv_heads
+    tables, limits, starts = (np.asarray(x) for x in (tables, limits, starts))
+    k = np.asarray(k_arena.astype(jnp.float32))[layer][tables].reshape(S, -1, kv_heads, hd)
+    v = np.asarray(v_arena.astype(jnp.float32))[layer][tables].reshape(S, -1, kv_heads, hd)
+    k, v = (np.repeat(x, n_heads // kv_heads, axis=2) for x in (k, v))
+    qh = np.asarray(q, np.float32).reshape(S, W, n_heads, hd)
+    scores = np.einsum("swhd,sthd->swht", qh, k) / math.sqrt(hd)
+    at = np.arange(k.shape[1])[None, None, :]
+    seen = (at < limits[:, :, None]) & (at >= starts[:, :, None])
+    scores = np.where(seen[:, :, None, :], scores, -1e30)
+    p = np.exp(scores - scores.max(-1, keepdims=True))
+    p = p / p.sum(-1, keepdims=True)
+    return np.einsum("swht,sthd->swhd", p, v).reshape(S, W, n_heads * hd)
+
+
+def make_grouped_case(n_heads, kv_heads, W, lengths, window, dtype, seed=0,
+                      free_behind=False):
+    """As ``make_case`` with arenas of ``kv_heads * HEAD_DIM`` lanes; query w
+    starts at ``max(0, position - window + 1)`` (None: at 0).
+    ``free_behind``: every block wholly before a slot's earliest start is
+    unmapped (0: the trash block, filled with NaN), as the pool leaves it
+    after handing the block back."""
+    rs = np.random.RandomState(seed)
+    S = len(lengths)
+    n_blocks = 1 + S * MAX_BLOCKS
+    shape = (LAYERS, n_blocks, BLOCK_T, kv_heads * HEAD_DIM)
+    k_arena, v_arena = (rs.randn(*shape).astype(np.float32) for _ in range(2))
+    if free_behind:
+        k_arena[:, 0] = v_arena[:, 0] = np.nan
+    q = jnp.asarray(rs.randn(S, W, n_heads * HEAD_DIM), dtype)
+    tables = rs.permutation(np.arange(1, n_blocks)).reshape(S, MAX_BLOCKS).astype(np.int32)
+    limits = np.zeros((S, W), np.int32)
+    for s, n in enumerate(lengths):
+        if n is None:
+            tables[s] = 0
+        else:
+            limits[s] = n - np.arange(W)[::-1]
+    starts = (np.zeros_like(limits) if window is None
+              else np.maximum(limits - window, 0))
+    if free_behind:
+        for s, n in enumerate(lengths):
+            if n is not None:
+                tables[s, :starts[s].min() // BLOCK_T] = 0
+    return (q, jnp.asarray(k_arena, dtype), jnp.asarray(v_arena, dtype),
+            jnp.asarray(tables), jnp.asarray(limits), jnp.asarray(starts))
+
+
+@pytest.mark.parametrize("window", [None, 1, BLOCK_T, CHUNK_T + 5, 200],
+                         ids=["full", "w1", "wB", "wC+5", "w200"])
+@pytest.mark.parametrize("W", [1, 3], ids=["W1", "W3"])
+@pytest.mark.parametrize("heads", [(6, 2), (4, 4), (8, 1)],
+                         ids=["H6G2", "H4G4", "H8G1"])
+def test_grouped_heads_and_starts_match_a_dense_softmax(heads, W, window):
+    """Grouped query heads over fewer K/V heads, and a first visible key a
+    query: slots that end inside their first, second and third chunk and one
+    that fills its table, a dead slot; every block behind a slot's window is
+    UNMAPPED (the trash block holds NaN: a kernel that looks there fails)."""
+    H, G = heads
+    lengths = [max(W, 5), CHUNK_T + 3, None, 2 * CHUNK_T + 40, MAX_LEN]
+    q, k_arena, v_arena, tables, limits, starts = make_grouped_case(
+        H, G, W, lengths, window, jnp.float32, seed=H * 7 + W,
+        free_behind=window is not None)
+    out = paged_decode_attention(
+        q, k_arena, v_arena, tables, limits, layer=1, n_heads=H, kv_heads=G,
+        starts=None if window is None else starts)
+    clean = (jnp.nan_to_num(k_arena), jnp.nan_to_num(v_arena))
+    ref = grouped_reference(q, *clean, tables, limits, starts, 1, H, G)
+    live = [s for s, n in enumerate(lengths) if n is not None]
+    np.testing.assert_allclose(np.asarray(out)[live], ref[live],
+                               atol=F32_TOL, rtol=F32_TOL)
+    assert not np.asarray(out)[2].any()
+
+
+def test_grouped_heads_in_bfloat16_at_a_served_shape():
+    """48 query heads over 8 K/V heads of 128 lanes, bf16, a window of 160
+    keys over slots of up to 384: the widths a grouped, windowed model is
+    served at (table width cut to the test's)."""
+    rs = np.random.RandomState(3)
+    H, G, hd, S = 48, 8, 128, 3
+    n_blocks = 1 + S * MAX_BLOCKS
+    k_arena, v_arena = (jnp.asarray(rs.randn(1, n_blocks, BLOCK_T, G * hd), jnp.bfloat16)
+                        for _ in range(2))
+    q = jnp.asarray(rs.randn(S, 1, H * hd), jnp.bfloat16)
+    tables = jnp.asarray(rs.permutation(np.arange(1, n_blocks)).reshape(S, MAX_BLOCKS), jnp.int32)
+    limits = jnp.asarray([[MAX_LEN], [0], [77]], jnp.int32)
+    starts = jnp.maximum(limits - 160, 0)
+    tables = tables.at[1].set(0)
+    out = paged_decode_attention(q, k_arena, v_arena, tables, limits, layer=0,
+                                 n_heads=H, kv_heads=G, starts=starts)
+    ref = grouped_reference(q, k_arena, v_arena, tables, limits, starts, 0, H, G)
+    np.testing.assert_allclose(np.asarray(out, np.float32)[[0, 2]], ref[[0, 2]],
+                               atol=BF16_TOL, rtol=BF16_TOL)
+
+
+@pytest.mark.parametrize("W", [1, 5], ids=["W1", "W5"])
+def test_equal_heads_and_no_starts_are_the_call_as_it_was(W):
+    """``kv_heads == n_heads`` named or not, and ``starts`` of zeros against
+    none at all, give the same BITS: the flags add nothing to the path a
+    model with its own K/V a head decodes through."""
+    lengths = [CHUNK_T + 9, None, MAX_LEN - 7]
+    q, _, _, k_arena, v_arena, tables, limits = make_case(4, W, lengths, jnp.float32, seed=W)
+    plain = paged_decode_attention(q, k_arena, v_arena, tables, limits, layer=1, n_heads=4)
+    named = paged_decode_attention(q, k_arena, v_arena, tables, limits, layer=1,
+                                   n_heads=4, kv_heads=4)
+    zeros = paged_decode_attention(q, k_arena, v_arena, tables, limits, layer=1,
+                                   n_heads=4, starts=jnp.zeros_like(limits))
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(named))
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(zeros))
+
+
+def test_kernel_names_what_it_accepts_when_it_refuses():
+    q, k_arena, v_arena, tables, limits, starts = make_grouped_case(
+        6, 2, 1, [9, 40], 16, jnp.float32)
+    with pytest.raises(ValueError, match="kv_heads"):
+        paged_decode_attention(q, k_arena, v_arena, tables, limits, layer=0, n_heads=6)
+    with pytest.raises(ValueError, match="kv_heads"):
+        paged_decode_attention(q, k_arena, v_arena, tables, limits, layer=0,
+                               n_heads=6, kv_heads=4)
+    with pytest.raises(ValueError, match="starts"):
+        paged_decode_attention(q, k_arena, v_arena, tables, limits, layer=0,
+                               n_heads=6, kv_heads=2, starts=starts[:1])
+
+
 # -- the pool around the kernel ------------------------------------------------
 
 

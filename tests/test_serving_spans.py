@@ -303,10 +303,13 @@ def test_a_steps_routing_counters_ride_the_dispatch_span_and_are_tabled():
     named = {kw.arg for kw in dispatch[0].keywords if kw.arg}
     spread = [kw.value for kw in dispatch[0].keywords if kw.arg is None]
     assert named == {"live_blocks", "mapped_blocks"}
-    assert [ast.unparse(v) for v in spread] == ["self.last_step_stats"]
+    # ``grouped``: what a pool with a windowed cache group counts a step
+    # (ISSUE 37: ``_slide_windows``), empty for every other pool
+    assert [ast.unparse(v) for v in spread] == ["grouped", "self.last_step_stats"]
     doc = open(os.path.join(ROOT, "docs", "OBSERVABILITY.md")).read()
     row = next(line for line in doc.splitlines()
                if line.startswith("| `kv.step.dispatch`"))
+    assert "`live_blocks_g<i>`" in row and "`window_blocks_freed`" in row
     assert {"routed_tokens", "resident_assignments", "experts_touched"} <= set(MOE_STATS)
     missing = [n for n in MOE_STATS if f"`{n}`" not in row]
     assert not missing, f"not in the span's row of docs/OBSERVABILITY.md: {missing}"

@@ -411,3 +411,124 @@ def test_sparse_prefill_program_compiles_for_the_chip_with_three_arenas_in_place
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2 * KV_LAYERS
     assert "dsa_kth_score" in text and "dsa_selected_attn" in text
+
+
+# -- the trinity family at Trinity-Large-Preview's published widths (ISSUE 37) --
+#
+# benchmark/configs/trinity-large-preview.json and benchmark/traffic/mixed-len.json:
+# 16 slots x 33,792 positions in blocks of 32, TWO cache groups (the full layers'
+# K and V for the request's life, the sliding layers' within the 4,096 window:
+# 130 blocks a slot), 48 query heads over 8 K/V heads of 128 lanes, 32 of 256
+# experts stacked, an eighth of the vocabulary. The depth is cut to 2 for the
+# test's time: a dense sliding layer and a full layer with experts.
+
+TR_SLOTS, TR_MAX_LEN, TR_WINDOW = 16, 33792, 4096
+TR_MAX_BLOCKS = TR_MAX_LEN // BLOCK_T
+TR_N_BLOCKS = (1 + TR_SLOTS * TR_MAX_BLOCKS, 1 + TR_SLOTS * (TR_WINDOW // BLOCK_T + 2))
+TR_ARENAS = [(1, n, BLOCK_T, 1024) for n in TR_N_BLOCKS for _ in range(2)]
+
+
+@pytest.fixture(scope="module")
+def windowed_pool_and_params(one_chip):
+    from deeplearning4j_tpu.models import trinity as tr
+
+    cfg = tr.TrinityConfig(
+        vocab_size=25024, num_hidden_layers=2, num_dense_layers=1,
+        layer_types=(tr.SLIDING, tr.FULL), n_resident_experts=32,
+        max_position_embeddings=TR_MAX_LEN)
+    shapes = jax.eval_shape(lambda: tr.init_params(jax.random.key(0), cfg))
+    params = jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), shapes)
+    mp = pytest.MonkeyPatch()
+    mp.setattr(PagedDecodeSlotPool, "_new_arena", lambda self, cfg: (None,) * 4)
+    try:
+        pool = PagedDecodeSlotPool(shapes, cfg, slots=TR_SLOTS, block_T=BLOCK_T,
+                                   max_len=TR_MAX_LEN)
+    finally:
+        mp.undo()
+    assert pool.n_blocks == TR_N_BLOCKS and pool.family.cache_widths == (1024,) * 4
+    assert pool.family.arena_groups == (0, 0, 1, 1)
+    return pool, params
+
+
+def _assert_four_arenas_in_place(lowered, compiled):
+    text = lowered.as_text()
+    tied = []
+    for arena in TR_ARENAS[::2]:   # K and V of a group share a type
+        arena_type = "tensor<" + "x".join(str(d) for d in arena) + "xbf16>"
+        tied += re.findall(re.escape(arena_type) + r" \{[^%]*?tf\.aliasing_output = (\d+)",
+                           text)
+    assert len(tied) == len(set(tied)) == 4, tied
+    arena_bytes = sum(2 * n * BLOCK_T * 1024 for _, n, _, _ in TR_ARENAS)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= arena_bytes
+    entry = compiled.as_text()
+    entry = entry[entry.index("\nENTRY "):]
+    copies = re.findall(r"= bf16\[(?:1,)?(?:%d|%d),%d,1024\]\S* copy\(" % (
+        *TR_N_BLOCKS, BLOCK_T), entry)
+    assert not copies, copies
+    return mem
+
+
+def test_windowed_decode_program_compiles_for_the_chip_with_four_arenas_in_place(
+        one_chip, on_chip_path, windowed_pool_and_params):
+    """Two tables, the grouped decode kernel twice (``starts`` on the sliding
+    layer: nine scalar lists, seven on the full one), the arenas of both
+    cache groups updated in place."""
+    pool, params = windowed_pool_and_params
+    table = _shape(one_chip, (TR_SLOTS, TR_MAX_BLOCKS), jnp.int32)
+    lowered = pool._decode_fn.lower(
+        params, *(_shape(one_chip, a, jnp.bfloat16) for a in TR_ARENAS), table, table,
+        _shape(one_chip, (TR_SLOTS,), jnp.int32), _shape(one_chip, (TR_SLOTS,), jnp.int32))
+    compiled = lowered.compile()  # a Mosaic error would be raised here
+    mem = _assert_four_arenas_in_place(lowered, compiled)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "paged_decode_attn" in text
+    # nothing the size of a full layer's arena is made beside it
+    assert mem.temp_size_in_bytes < 2 * TR_N_BLOCKS[0] * BLOCK_T * 1024
+
+
+@pytest.mark.parametrize("bucket", [2048, 32768])
+def test_windowed_prefill_program_compiles_for_the_chip_with_four_arenas_in_place(
+        one_chip, on_chip_path, windowed_pool_and_params, bucket):
+    """A bucket under the window and the longest: the sliding layer through
+    ``flash_fwd_swa``, the full one through ``flash_fwd_gqa``, rows in passes
+    of ``prefill_chunk``; a 32k bucket's temporaries stay near 2 GB."""
+    pool, params = windowed_pool_and_params
+    dest = _shape(one_chip, (bucket // BLOCK_T,), jnp.int32)
+    lowered = pool._prefill_fn.lower(
+        params, *(_shape(one_chip, a, jnp.bfloat16) for a in TR_ARENAS), dest, dest,
+        _shape(one_chip, (1, bucket), jnp.int32), _shape(one_chip, (), jnp.int32))
+    compiled = lowered.compile()  # a Mosaic error would be raised here
+    mem = _assert_four_arenas_in_place(lowered, compiled)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "flash_fwd_swa" in text and "flash_fwd_gqa" in text
+    # the kernels write what benchmark/work_trinity.py:call_bucket reads
+    assert f"bf16[1,{bucket},6144]" in text   # [batch, T, heads x head_dim]
+    assert mem.temp_size_in_bytes < 2.5e9
+
+
+def test_equal_heads_decode_kernel_lowers_as_it_did(one_chip, on_chip_path):
+    """``kv_heads == n_heads`` and no ``starts``: seven scalar lists and the
+    [slots, H * hd] query block, as gpt2-large.chat's step has run them since
+    ISSUE 29; the grouped, windowed call has nine and a head a row."""
+    def text(**kw):
+        H, G = kw.pop("heads")
+        q = _shape(one_chip, (SLOTS, 1, H * 128), jnp.bfloat16)
+        arena = _shape(one_chip, (2, N_BLOCKS, BLOCK_T, G * 128), jnp.bfloat16)
+        ints = _shape(one_chip, (SLOTS, 1), jnp.int32)
+        tables = _shape(one_chip, (SLOTS, MAX_BLOCKS), jnp.int32)
+        if kw.pop("windowed"):
+            fn = lambda q, k, v, t, l, s: paged_decode_attention(  # noqa: E731
+                q, k, v, t, l, layer=1, n_heads=H, kv_heads=G, starts=s)
+            return jax.jit(fn).lower(q, arena, arena, tables, ints, ints).compile().as_text()
+        fn = lambda q, k, v, t, l: paged_decode_attention(  # noqa: E731
+            q, k, v, t, l, layer=1, n_heads=H)
+        return jax.jit(fn).lower(q, arena, arena, tables, ints).compile().as_text()
+
+    plain = text(heads=(10, 10), windowed=False)
+    grouped = text(heads=(48, 8), windowed=True)
+    for t in (plain, grouped):
+        assert t.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"f32[{SLOTS},1280]" in plain and f"f32[{SLOTS * 48},128]" in grouped
