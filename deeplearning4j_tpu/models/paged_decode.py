@@ -46,6 +46,16 @@ share a handful of prefill executables.
   what the admission reserved. An admission is priced in every group (the
   whole span, or ``min`` of that and ``window / block_T + 2``) and waits when
   ANY group is short;
+- **one step ahead** — a decode step is two calls: ``dispatch()`` launches
+  it and returns at once, ``collect()`` reads the oldest uncollected step's
+  tokens back (``step()`` is the one after the other). Greedy decoding needs
+  nothing from the host between two steps: the next step's tokens ARE the
+  last step's output, which stays on the device and is an operand of the one
+  decode program beside the host's own tokens for the slots it knows better
+  (just admitted, or already collected); positions advance by one and budgets
+  are the host's. So a caller may dispatch step n+1 BEFORE it collects step n
+  and do its host work (retirement, tables, uploads) while the device
+  computes; a slot released meanwhile has its token in flight dropped;
 - **speculative decoding** — with a small draft model from the same zoo, one
   jitted step drafts ``k`` greedy tokens (k+1 chained single-token passes
   over the draft's own paged arena, sharing the block tables) and verifies
@@ -113,7 +123,8 @@ driver) is the only caller — no internal locking.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Union
+from collections import deque
+from typing import Any, Deque, Dict, List, NamedTuple, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -246,6 +257,19 @@ class _Group:
         self.lo[:] = self.hi[:] = self.owed[:] = 0
 
 
+class _Flight:
+    """A dispatched decode step: the slots it steps (``riders``, one flag a
+    slot: a slot released before the step is collected is struck out, and its
+    token dropped), the program's results while they are on the device, and
+    the step's ``{slot: [tokens]}`` answer once they were read back."""
+
+    __slots__ = ("riders", "results", "answer")
+
+    def __init__(self, riders: np.ndarray, results):
+        self.riders, self.results = riders, results
+        self.answer: Optional[Dict[int, List[int]]] = None
+
+
 def _write_window(arena, layer: int, tables, limits, x):
     """``arena[layer, block, cell] = x[s, w]`` at position ``limits[s, w] - 1``
     of every live slot s, through its table, in place; nothing of a dead slot
@@ -271,7 +295,9 @@ def _write_blocks(arena, dest_blocks, x):
 class PagedDecodeSlotPool:
     """``slots`` concurrent sequences over one paged arena: ``admit``
     prefills a prompt into a free slot, ``step`` advances every live
-    sequence, ``release`` frees a slot; ``free_slots``, ``prompt_bucket``,
+    sequence (``dispatch`` launches the step, ``collect`` reads the oldest
+    uncollected one back: the serving loop keeps one step ahead with them),
+    ``release`` frees a slot; ``free_slots``, ``prompt_bucket``,
     the trace counters and the :class:`KvCacheLostError` reset are what any
     session of the serving executor has. It discovers three more by
     ``getattr``:
@@ -399,12 +425,24 @@ class PagedDecodeSlotPool:
         self._tokens = np.zeros(slots, np.int32)
         self._budget = np.zeros(slots, np.int32)    # max_new_tokens per slot
         self._emitted = np.zeros(slots, np.int32)   # tokens handed to caller
+        # tokens handed out once every dispatched step is collected: a slot
+        # whose budget is dispatched is stepped no more, collected or not
+        self._dispatched = np.zeros(slots, np.int32)
         self._span = np.zeros(slots, np.int32)      # reserved position span
         self._cow_reserve = np.zeros(slots, np.int32)
         self._joined: Dict[int, Dict[int, int]] = {}  # slot -> {logical: phys}
-        #: seconds the last ``step()`` blocked reading its result back (the
-        #: ``kv.step.fetch`` span): the step's time less this is the host's
+        # dispatched steps whose answers the caller has not collected, oldest
+        # first, and the last dispatched step's output (tokens, then the
+        # family's counters): the next step's tokens, where they stay
+        self._flying: Deque[_Flight] = deque()
+        self._carry = jnp.zeros(slots + len(fam.stat_names), jnp.int32)
+        #: seconds the last collected step blocked reading its result back (the
+        #: ``kv.step.fetch`` span): the loop's period less this is the host's
         self.last_fetch_s = 0.0
+        # cumulative: steps dispatched, and those dispatched while the step
+        # before them was still uncollected (the host's work rode under it)
+        self.kv_steps = 0
+        self.kv_steps_overlapped = 0
         # cumulative speculative counters (0 forever on a plain pool)
         self.spec_proposed = 0
         self.spec_accepted = 0
@@ -444,7 +482,11 @@ class PagedDecodeSlotPool:
 
         def _decode(params, *args):
             self.decode_traces += 1
-            arenas, tables, (tokens, positions) = args[:n], args[n:n + ng], args[n + ng:]
+            arenas, tables = args[:n], args[n:n + ng]
+            carry, fresh, positions = args[n + ng:]
+            # a slot's token is the step before's output, where it lies, unless
+            # the host knows it (``fresh`` >= 0: just admitted, or collected)
+            tokens = jnp.where(fresh >= 0, fresh, carry[:slots])
             # one group: the table itself, as every family before groups
             logits, arenas, stats = fam.decode_window(
                 params, tokens[:, None], positions[:, None], arenas,
@@ -616,6 +658,9 @@ class PagedDecodeSlotPool:
         kernel is asked to visit: over live slots, the blocks up to the
         window's last position) and ``kv_blocks_mapped`` (``slots x
         max_blocks``: what a dense gather through the tables would visit).
+        ``kv_steps`` counts the steps dispatched and ``kv_steps_overlapped``
+        those dispatched while the step before them was still uncollected
+        (the caller's host work ran under the device's).
         ``kv_cache_bytes_per_token`` is what one token stores over all layers
         and arenas; ``resident_weight_bytes`` the bytes of every leaf of the
         resident tree(s) the decode program is handed (the draft's too; a leaf
@@ -657,6 +702,8 @@ class PagedDecodeSlotPool:
             "spec_accepted": self.spec_accepted,
             "kv_blocks_read": self.kv_blocks_read,
             "kv_blocks_mapped": self.kv_blocks_mapped,
+            "kv_steps": self.kv_steps,
+            "kv_steps_overlapped": self.kv_steps_overlapped,
         }
 
     def cached_rows(self, slot: int, n: int):
@@ -817,11 +864,7 @@ class PagedDecodeSlotPool:
                 with span("kv.prefill.fetch"):
                     first = int(first)  # the host waits for the prefill here
         except Exception as e:
-            self._reset_after_failure()
-            raise KvCacheLostError(
-                f"prefill failed after its KV buffers were donated "
-                f"({type(e).__name__}: {e}); cache reset, in-flight "
-                f"sequences lost") from e
+            raise self._lost("prefill", e) from e
 
         # publish this prompt's freshly WRITTEN blocks for future sharers
         for i in range(fb if self._shares_prefix else 0):
@@ -837,7 +880,7 @@ class PagedDecodeSlotPool:
         self._positions[slot] = n
         self._tokens[slot] = first
         self._budget[slot] = max_new_tokens
-        self._emitted[slot] = 1
+        self._emitted[slot] = self._dispatched[slot] = 1
         self._span[slot] = n_span
         self._joined[slot] = joined
         return slot, first
@@ -865,25 +908,42 @@ class PagedDecodeSlotPool:
                     *self._arenas, *self._draft_arenas, np.int32(old),
                     np.int32(new)))
             except Exception as e:
-                self._reset_after_failure()
-                raise KvCacheLostError(
-                    f"copy-on-write failed after the arena was donated "
-                    f"({type(e).__name__}: {e}); cache reset, in-flight "
-                    f"sequences lost") from e
+                raise self._lost("copy-on-write", e) from e
             self._tables[slot, lb] = new
             self._alloc.unref(old)
 
     def step(self) -> Dict[int, List[int]]:
-        """Advance EVERY live slot through ONE fixed-signature XLA call.
+        """Advance EVERY live slot through ONE fixed-signature XLA call and
+        wait for it: ``dispatch()`` then ``collect()``.
 
         Returns ``{slot: [tokens...]}`` — one token plain, up to
         ``spec_tokens + 1`` speculative, clamped to the slot's remaining
         ``max_new_tokens`` budget.  The caller decides retirement (EOS /
-        budget / deadline) and calls :meth:`release`."""
-        live = np.flatnonzero(self._active)
+        budget / deadline) and calls :meth:`release`.  (A caller that left a
+        step uncollected gets THAT step's answer: answers come oldest first.)"""
+        self.dispatch()
+        return self.collect() or {}
+
+    def dispatch(self) -> bool:
+        """Launch one decode step of every slot with budget left and return
+        without waiting for it: its tokens stay on the device, where the next
+        step reads them, so a caller may ``dispatch()`` step n+1 BEFORE it
+        ``collect()``s step n and do its host work under the device's.
+
+        The host's side of a step is settled here: copy-on-write, the windows
+        slid, positions advanced. A slot whose budget is dispatched is sent as
+        a dead slot from then on (zero table row, position 0), released or
+        not, so no slot is stepped past its budget. Returns True when a step
+        is left RUNNING; False when there is nothing to wait for: no slot had
+        budget left or, in a pool with a draft, the step was read back here
+        (its next positions wait for the accepted count) and ``collect()``
+        hands the answer over."""
+        stepping = self._active & (self._dispatched < self._budget)
+        live = np.flatnonzero(stepping)
         if live.size == 0:
-            return {}
-        window = self.spec_tokens + 1 if self.draft_cfg is not None else 1
+            return False
+        spec = self.draft_cfg is not None
+        window = self.spec_tokens + 1 if spec else 1
         if (self._positions[live] + window > self._span[live]).any():
             raise RuntimeError(
                 "a live slot is at the end of its reserved block span — the "
@@ -893,38 +953,75 @@ class PagedDecodeSlotPool:
             self._cow_before_write(s, int(self._positions[s]),
                                    int(self._positions[s]) + window - 1)
         grouped = self._slide_windows(live) if self._windowed else {}
+        # the step before, where it is still running: its riders' tokens are
+        # its output on the device; every other token the host knows
+        ahead = self._flying[-1] if self._flying else None
+        running = ahead is not None and ahead.answer is None
+        fresh = np.where(stepping, self._tokens, 0)
+        if running:
+            fresh[ahead.riders & stepping] = -1
         with span("kv.step.upload"):
-            tables = [jnp.asarray(g.tables) for g in self._groups]
-            toks = jnp.asarray(self._tokens)
-            pos = jnp.asarray(self._positions)
+            # private copies: the host edits its tables while the step runs
+            tables = [jnp.asarray(np.where(stepping[:, None], g.tables, 0))
+                      for g in self._groups]
+            toks = jnp.asarray(fresh)
+            pos = jnp.asarray(np.where(stepping, self._positions, 0))
         live_blocks = int(
             (-(-(self._positions[live] + window) // self.block_T)).sum())
         mapped_blocks = self.slots * self.max_blocks
         self.kv_blocks_read += live_blocks
         self.kv_blocks_mapped += mapped_blocks
-        out: Dict[int, List[int]] = {}
+        self.kv_steps += 1
+        self.kv_steps_overlapped += running
         try:
             # a step's own routing is known when its tokens come back: the
             # span carries the counters of the step fetched last
             with span("kv.step.dispatch", live_blocks=live_blocks,
                       mapped_blocks=mapped_blocks, **grouped,
                       **self.last_step_stats):
-                results = self._run(self._decode_fn, *tables, toks, pos)
-            # the one host round trip a step (S4): the device runs the step
-            # while the host waits here
-            with span("kv.step.fetch") as fetch:
-                if self.draft_cfg is None:
-                    nxt = np.asarray(results[0])
-                else:
-                    ver, n_acc = (np.asarray(r) for r in results)
+                operands = (toks, pos) if spec else (self._carry, toks, pos)
+                results = self._run(self._decode_fn, *tables, *operands)
         except Exception as e:
-            self._reset_after_failure()
-            raise KvCacheLostError(
-                f"decode step failed after its KV buffers were donated "
-                f"({type(e).__name__}: {e}); cache reset, in-flight "
-                f"sequences lost") from e
+            raise self._lost("decode step", e) from e
+        flight = _Flight(stepping, results)
+        self._flying.append(flight)
+        if spec:
+            self._land(flight)
+            return False
+        self._carry = results[0]
+        self._positions[live] += 1
+        self._dispatched[live] += 1
+        return True
+
+    def collect(self) -> Optional[Dict[int, List[int]]]:
+        """The answer of the OLDEST uncollected step, ``{slot: [tokens...]}``,
+        read back now if it was not yet (``kv.step.fetch``: the host waits
+        here for the device); None when no step is uncollected. A slot
+        released since its step was dispatched (EOS, deadline) has that token
+        dropped: it is never credited to the slot's next tenant."""
+        if not self._flying:
+            return None
+        flight = self._flying[0]
+        if flight.answer is None:
+            self._land(flight)  # a failure resets the pool: nothing is left
+        self._flying.popleft()
+        return flight.answer
+
+    def _land(self, flight: _Flight) -> None:
+        """Read ``flight``'s results back (the one host round trip a step,
+        S4) and credit them: the family's counters, and for every rider still
+        in its slot its token(s), ``_tokens`` and ``_emitted``."""
+        try:
+            with span("kv.step.fetch") as fetch:
+                fetched = [np.asarray(r) for r in flight.results]
+        except Exception as e:
+            raise self._lost("decode step", e) from e
         self.last_fetch_s = fetch.duration_s
+        flight.results = None
+        out: Dict[int, List[int]] = {}
+        riders = [int(s) for s in np.flatnonzero(flight.riders)]
         if self.draft_cfg is None:
+            nxt = fetched[0]
             if self.family.stat_names:
                 nxt, counted = nxt[:self.slots], nxt[self.slots:]
                 self.last_step_stats = {
@@ -933,25 +1030,24 @@ class PagedDecodeSlotPool:
                 for name, v in self.last_step_stats.items():
                     self.family_stats[name] += v
                 self.family_steps += 1
-            for slot in live:
-                slot = int(slot)
+            for slot in riders:
                 out[slot] = [int(nxt[slot])]
-                self._positions[slot] += 1
                 self._tokens[slot] = nxt[slot]
                 self._emitted[slot] += 1
-            return out
-        for slot in live:
-            slot = int(slot)
-            na = int(n_acc[slot])
-            self.spec_proposed += self.spec_tokens
-            self.spec_accepted += na - 1
-            remaining = int(self._budget[slot] - self._emitted[slot])
-            take = min(na, max(remaining, 0))
-            out[slot] = [int(t) for t in ver[slot, :take]]
-            self._positions[slot] += na
-            self._tokens[slot] = int(ver[slot, na - 1])
-            self._emitted[slot] += take
-        return out
+        else:
+            ver, n_acc = fetched
+            for slot in riders:
+                na = int(n_acc[slot])
+                self.spec_proposed += self.spec_tokens
+                self.spec_accepted += na - 1
+                remaining = int(self._budget[slot] - self._emitted[slot])
+                take = min(na, max(remaining, 0))
+                out[slot] = [int(t) for t in ver[slot, :take]]
+                self._positions[slot] += na
+                self._tokens[slot] = int(ver[slot, na - 1])
+                self._emitted[slot] += take
+                self._dispatched[slot] = self._emitted[slot]
+        flight.answer = out
 
     def _slide_windows(self, live) -> Dict[str, int]:
         """Before a step, on the host, for every windowed cache group and live
@@ -997,7 +1093,8 @@ class PagedDecodeSlotPool:
         """Free a slot: drop its block references (shared blocks survive
         while other sequences or the prefix index's last holder need them),
         return any unused CoW reserve and what a windowed group still held
-        reserved for it, and clear the table rows."""
+        reserved for it, and clear the table rows. A token of the slot that
+        is still in flight is dropped when its step is collected."""
         if not self._active[slot]:
             raise ValueError(f"slot {slot} is not active")
         for g in self._groups:
@@ -1013,8 +1110,18 @@ class PagedDecodeSlotPool:
         self._tokens[slot] = 0
         self._budget[slot] = 0
         self._emitted[slot] = 0
+        self._dispatched[slot] = 0
         self._span[slot] = 0
         self._joined.pop(slot, None)
+        for flight in self._flying:  # its token in flight belongs to nobody
+            flight.riders[slot] = False
+
+    def _lost(self, what: str, e: BaseException) -> KvCacheLostError:
+        """Reset after a donated call failed; the error to raise from it."""
+        self._reset_after_failure()
+        return KvCacheLostError(
+            f"{what} failed after its KV buffers were donated "
+            f"({type(e).__name__}: {e}); cache reset, in-flight sequences lost")
 
     def _reset_after_failure(self) -> None:
         """Recover from a failed donated call: fresh zero arenas, fresh
@@ -1031,6 +1138,9 @@ class PagedDecodeSlotPool:
         self._tokens[:] = 0
         self._budget[:] = 0
         self._emitted[:] = 0
+        self._dispatched[:] = 0
         self._span[:] = 0
         self._cow_reserve[:] = 0
         self._joined.clear()
+        self._flying.clear()
+        self._carry = jnp.zeros_like(self._carry)

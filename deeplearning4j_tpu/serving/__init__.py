@@ -14,7 +14,7 @@ executable). See docs/PARITY.md "Serving" for the DL4J mapping.
 from .executor import (BatchingInferenceExecutor, DeadlineExceededError,
                        ExecutorClosedError, GenerationFuture,
                        GenerativeInferenceExecutor, InferenceFuture,
-                       QueueFullError)
+                       QueueFullError, StepAtDispatch)
 from .json_server import JsonModelServer, JsonModelClient
 from .loadgen import Burst, LoadGenerator, TraceSpec, replay
 from .pool import PoolAutoscaler, ServingPool
@@ -25,6 +25,7 @@ __all__ = [
     "BatchingInferenceExecutor",
     "GenerativeInferenceExecutor",
     "GenerationFuture",
+    "StepAtDispatch",
     "InferenceFuture",
     "QueueFullError",
     "DeadlineExceededError",

@@ -530,6 +530,22 @@ class GenerationFuture(InferenceFuture):
 _SPAN_STEP_CAP = 64
 
 
+class StepAtDispatch:
+    """``dispatch`` / ``collect`` for a session that has only ``step()``: the
+    step runs whole inside ``dispatch``, which leaves nothing running (it
+    answers False), and ``collect`` hands its answer over."""
+
+    _stepped = None
+
+    def dispatch(self) -> bool:
+        self._stepped = self.step()
+        return False
+
+    def collect(self):
+        out, self._stepped = self._stepped, None
+        return out
+
+
 class GenerativeInferenceExecutor(BatchingInferenceExecutor):
     """Iteration-level (Orca-style) continuous batching over a decode slot
     pool — the autoregressive counterpart of the micro-batching executor.
@@ -541,11 +557,32 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
     traffic. Deadlines shed mid-decode through the existing 504 path
     (the sequence is EVICTED, its slot freed the same step).
 
+    The loop decodes ONE STEP AHEAD: a turn dispatches step n+1 and THEN
+    collects and retires step n, so retirement, the gauges and the next
+    dispatch's host work run while the device computes. Two rules keep what
+    a step boundary meant. A prefill never stands between a finished step
+    and its retirement: with an admission waiting, the step in flight is
+    collected and retired FIRST (one unpipelined step an admission), so no
+    finished request waits out another's prefill and ``admit`` meets no step
+    in flight. Retirement is by what was collected (budget, EOS, deadline):
+    a request whose last token is in flight finishes at that token's
+    collect, with nothing dispatched for it after; a step dispatched for a
+    slot that EOS or a deadline then frees has that token dropped by the
+    session.
+
     ``session`` is duck-typed (the block-paged
     ``models.paged_decode.PagedDecodeSlotPool`` is the real one): ``slots``,
     ``free_slots``, ``admit(prompt, max_new_tokens) -> (slot,
-    first_token)``, ``step() -> {slot: token | [tokens...]}``,
-    ``release(slot)``, plus optional ``eos_id`` / ``max_len`` attributes.
+    first_token)``, ``step() -> {slot: token | [tokens...]}`` (warm-up),
+    ``dispatch() -> bool`` (launch a step of every slot with budget left;
+    True when it is left RUNNING, False when there is nothing to wait for:
+    nothing was launched, or the step was read back already),
+    ``collect() -> {slot: token | [tokens...]} | None`` (the oldest
+    uncollected step's answer, waiting for it if need be, less the slots
+    released since its dispatch; None when there is none),
+    ``release(slot)``, plus optional ``eos_id`` / ``max_len`` attributes. A
+    session that has only ``step()`` takes the other two from
+    :class:`StepAtDispatch`: its depth is 0, as a pool's with a draft.
     The paged pool additionally exposes ``can_admit``/``request_blocks``/
     ``total_blocks`` (block-priced admission control), ``block_stats()``
     (occupancy/CoW/speculation telemetry), ``admit_overhead_tokens``
@@ -588,6 +625,11 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
         # still) and its `decode` (every step of its slot life is its own)
         self._prefill_s = 0.0
         self._step_s = 0.0
+        # a dispatched step is still running (its answer uncollected), and the
+        # instant the current step's period began: the collect before it, or
+        # its own dispatch where nothing was running
+        self._ahead = False
+        self._t_period = 0.0
         self._occupancy_sum = 0
         self._tokens_out = 0
         self._admitted = 0
@@ -718,7 +760,8 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
         active: Dict[int, GenerationFuture] = {}
         while True:
             with self._cv:
-                while not self._q and not active and not self._stopping:
+                while (not self._q and not active and not self._ahead
+                       and not self._stopping):
                     with span("sched.idle"):
                         self._cv.wait()
                 stopping, drain = self._stopping, self._drain_on_stop
@@ -733,8 +776,9 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
                             "executor stopped mid-decode"))
                     active.clear()
                     self._md.slot_occupancy.set(0)
+                    self._discard_ahead()
                     return
-                if stopping and not self._q and not active:
+                if stopping and not self._q and not active and not self._ahead:
                     return
                 candidates: List[GenerationFuture] = []
                 blocked_head = False
@@ -763,17 +807,17 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
                     # requests — submit() 400s anything an EMPTY arena
                     # cannot hold — but a duck-typed session could get here)
                     self._cv.wait(0.01)
+            if candidates and self._ahead:
+                # a prefill never stands between a finished step and its
+                # retirement: nobody's last token waits out another's
+                # prefill, and an admission meets no step in flight
+                self._decode_step(active, launch=False)
             for fut in candidates:
                 with span("sched.admit", request_id=fut.request_id,
                           prompt_len=int(fut.x.shape[0])):
                     self._admit_into_slot(fut, active)
-            if not active:
-                continue
-            # `step` is the number first_step/last_step of a request_span
-            # name: a request joins its steps in a device trace by number
-            with span("sched.decode_step", step=self._steps + 1,
-                      live=len(active)):
-                self._decode_step(active)
+            if active or self._ahead:
+                self._decode_step(active, launch=bool(active))
 
     def _admit_into_slot(self, fut: GenerationFuture,
                          active: Dict[int, GenerationFuture]) -> None:
@@ -848,44 +892,95 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
             active[slot] = fut
         self._sync_session_metrics()
 
-    def _decode_step(self, active: Dict[int, GenerationFuture]) -> None:
-        t0 = time.monotonic()
-        try:
-            fault_point("infer")
-            out = self.session.step()
-        except Exception as e:  # decode failure → every live rider sees it
-            log.warning("decode step failed for requests [%s]: %s: %s",
-                        ", ".join(str(f.request_id) for f in active.values()),
-                        type(e).__name__, e)
-            reason = ("cache_lost" if getattr(e, "all_sequences_lost", False)
-                      else "step_error")
-            for slot, fut in list(active.items()):
+    def _decode_step(self, active: Dict[int, GenerationFuture],
+                     launch: bool) -> None:
+        """One turn of the decode loop, one step AHEAD: dispatch step n+1
+        (``launch``), THEN collect and retire step n, so that retirement, the
+        gauges and the next dispatch's host work run while the device
+        computes. A session that leaves nothing running (``dispatch()``
+        answers False: :class:`StepAtDispatch`, a pool with a draft, no slot
+        with budget left) is collected in the same turn."""
+        # `step` is the number first_step/last_step of a request_span name:
+        # the turn that COLLECTS step n carries n (and dispatches n+1); a
+        # request joins its steps in a device trace by number
+        with span("sched.decode_step", step=self._steps + 1, live=len(active)):
+            behind = self._ahead
+            if not behind:
+                self._t_period = time.monotonic()
+            if launch:
                 try:
-                    self.session.release(slot)
-                except Exception:
-                    log.debug("slot %d release failed after step error", slot)
-                self._md.evicted.labels(reason=reason).inc()
-                self._evicted += 1
-                self._finish(fut, error=e)
-            active.clear()
-            self._md.slot_occupancy.set(0)
+                    fault_point("infer")
+                    self._ahead = bool(self.session.dispatch())
+                except Exception as e:
+                    self._fail_riders(active, e)
+                    return
+            else:
+                self._ahead = False
+            if behind:
+                self._collect_step(active)
+            if not self._ahead:
+                self._collect_step(active)
+
+    def _collect_step(self, active: Dict[int, GenerationFuture]) -> None:
+        """Collect the oldest uncollected step and retire by what it brought
+        (nothing to do where the session has none)."""
+        try:
+            out = self.session.collect()
+        except Exception as e:
+            self._fail_riders(active, e)
             return
-        dt = time.monotonic() - t0
+        if out is None:
+            return
+        now = time.monotonic()
+        # the loop's period a step: what a token costs its client
+        dt, self._t_period = now - self._t_period, now
         self._step_s += dt
         self._steps += 1
         with span("sched.retire"):
             # the paged pool says how long it blocked on the step's result;
-            # the rest of the step was the host's (S4's cost a step)
+            # the rest of the period was the host's own work
             fetch_s = getattr(self.session, "last_fetch_s", None)
             self._retire(active, out, dt,
                          None if fetch_s is None else dt - fetch_s)
             self._sync_session_metrics()
             aggregate.maybe_spool()  # replica's aggregated-/metrics spool
 
+    def _fail_riders(self, active: Dict[int, GenerationFuture],
+                     e: BaseException) -> None:
+        """A decode step failed: every live rider sees it."""
+        log.warning("decode step failed for requests [%s]: %s: %s",
+                    ", ".join(str(f.request_id) for f in active.values()),
+                    type(e).__name__, e)
+        reason = ("cache_lost" if getattr(e, "all_sequences_lost", False)
+                  else "step_error")
+        for slot, fut in list(active.items()):
+            try:
+                self.session.release(slot)
+            except Exception:
+                log.debug("slot %d release failed after step error", slot)
+            self._md.evicted.labels(reason=reason).inc()
+            self._evicted += 1
+            self._finish(fut, error=e)
+        active.clear()
+        self._md.slot_occupancy.set(0)
+        self._discard_ahead()
+
+    def _discard_ahead(self) -> None:
+        """Collect and drop a step left running when its riders were all
+        released (a failure, shutdown): the session hands answers out oldest
+        first, and this one is nobody's."""
+        if self._ahead:
+            self._ahead = False
+            try:
+                self.session.collect()
+            except Exception:
+                log.debug("discarding the step in flight failed", exc_info=True)
+
     def _retire(self, active: Dict[int, GenerationFuture], out: dict,
                 dt: float, host_s: Optional[float]) -> None:
         """Hand one step's tokens to their requests; finish or evict the
-        ones that are done."""
+        ones that are done. A live slot the step did not ride (``out`` lacks
+        it) gets nothing and keeps its place."""
         self._md.steps.inc()
         self._occupancy_sum += len(active)
         now = time.monotonic()
@@ -895,10 +990,11 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
             # a minimal session emits one int per slot; the paged pool a list
             # (1 token plain, up to spec_tokens+1 speculative) — accept
             # both, clamped to the request's budget and truncated at EOS
-            step_out = out[slot]
+            rode = slot in out
+            step_out = out[slot] if rode else ()
             if not isinstance(step_out, (list, tuple)):
                 step_out = (step_out,)
-            fut.steps += 1
+            fut.steps += rode
             chunk = 0
             hit_eos = False
             for tok in step_out:
@@ -910,7 +1006,7 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
                     hit_eos = True
                     break
             emitted_total += chunk
-            if fut.sampled and fut.span is not None \
+            if rode and fut.sampled and fut.span is not None \
                     and len(fut.span["step_ms"]) < _SPAN_STEP_CAP:
                 fut.span["step_ms"].append(round(dt * 1e3, 3))
                 fut.span["step_tokens"].append(chunk)

@@ -20,6 +20,8 @@ import time
 
 import numpy as np
 
+from deeplearning4j_tpu.serving import StepAtDispatch
+
 
 class DoubleModel:
     """output(x) = 2x — deterministic, numpy-only."""
@@ -28,7 +30,7 @@ class DoubleModel:
         return np.asarray(x, np.float32) * 2.0
 
 
-class StubSession:
+class StubSession(StepAtDispatch):
     """FakeSession twin (see tests/test_serving_generative.py): emits
     ``prompt[-1]+1, +2, ...`` with a configurable per-step delay."""
 

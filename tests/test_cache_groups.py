@@ -240,7 +240,8 @@ def test_the_pool_refuses_groups_it_cannot_keep(groups, arena_groups, shares, ma
 
 POOL_KEYS = {"kv_cache_bytes_per_token", "resident_weight_bytes", "blocks_total",
              "blocks_free", "cow_shared_blocks", "cow_saved_blocks", "spec_proposed",
-             "spec_accepted", "kv_blocks_read", "kv_blocks_mapped"}
+             "spec_accepted", "kv_blocks_read", "kv_blocks_mapped",
+             "kv_steps", "kv_steps_overlapped"}  # the last two: ISSUE 38
 MOE_KEYS = {"moe_routed_tokens", "moe_resident_assignments", "moe_experts_touched",
             "moe_experts_resident", "moe_load_max", "moe_load_sum"}
 

@@ -180,6 +180,7 @@ def test_the_cell_is_declared_with_the_issues_readers_and_judged_on_p90_and_serv
         "serve_tok_s", "serve_lat_per_tok_p90_ms",
         "gen.lateness_p99_ms", "sched.queue_wait_p50_ms", "sched.ttft_p50_ms",
         "kv.block_occupancy", "kv.step_host_ms", "kv.cache_bytes_per_token",
+        "kv.step_overlap_share",  # ISSUE 38: the four serving cells
         "device.peak_mem_frac.serve", "moe.experts_touched_share", "moe.load_max_over_mean",
         "step.mfu.decode.dsa", "dsa.select_roofline.decode",
         "dsa.attend_roofline.decode", "dsa.selected_row_share",

@@ -23,10 +23,10 @@ from deeplearning4j_tpu.serving import (DeadlineExceededError,
                                         ExecutorClosedError,
                                         GenerativeInferenceExecutor,
                                         JsonModelClient, JsonModelServer,
-                                        QueueFullError)
+                                        QueueFullError, StepAtDispatch)
 
 
-class FakeSession:
+class FakeSession(StepAtDispatch):
     """Deterministic slot-pool stand-in: every sequence emits
     ``prompt[-1] + 1, +2, ...``; ``step_delay`` simulates decode-step cost."""
 

@@ -24,7 +24,7 @@ import pytest
 
 from deeplearning4j_tpu.monitoring import MetricsRegistry, flight
 from deeplearning4j_tpu.monitoring.trace import SERVING_SPANS, span
-from deeplearning4j_tpu.serving import JsonModelServer
+from deeplearning4j_tpu.serving import JsonModelServer, StepAtDispatch
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -32,7 +32,7 @@ GENERATIVE_PHASES = ["read", "parse", "queue", "prefill", "decode",
                      "interleave", "loop", "handoff", "serialize", "write"]
 
 
-class SleepySession:
+class SleepySession(StepAtDispatch):
     """A slot pool whose ``admit`` and ``step`` only sleep: the loop
     thread's time is then known, whatever the host is doing."""
 
@@ -229,7 +229,7 @@ def test_profiler_trace_joins_requests_to_steps_and_prefills(tmp_path):
                                 attn_impl="xla")
     pool = PagedDecodeSlotPool(tfm.init_params(jax.random.key(0), cfg), cfg,
                                slots=2, block_T=8)
-    (spans, _), events = _profile(tmp_path, lambda: _serve(
+    (spans, totals), events = _profile(tmp_path, lambda: _serve(
         pool, budgets=[4, 3, 5], warmup_input=[1, 2]))
 
     by_name = {}
@@ -251,11 +251,20 @@ def test_profiler_trace_joins_requests_to_steps_and_prefills(tmp_path):
         assert len(inside) == 1 and int(inside[0]["bucket"]) == 16
         assert {"shared_blocks", "new_blocks"} <= set(inside[0])
     assert len(by_name["kv.prefill.fetch"]) == len(prefills)
-    for _, start, end in by_name["sched.decode_step"]:  # each step holds one of each
-        for name in ("kv.step.upload", "kv.step.dispatch", "kv.step.fetch",
-                     "sched.retire"):
-            assert sum(1 for _, a, b in by_name[name]
-                       if start <= a and b <= end) == 1, name
+    # a turn of the loop dispatches step n+1, then collects and retires step
+    # n (ISSUE 38): it holds at most one of each, an upload with its dispatch
+    # and a fetch with its retire; the turn that starts a run only dispatches,
+    # the one that ends it only collects, and every step is in some turn
+    held = {name: [sum(1 for _, a, b in by_name[name] if start <= a and b <= end)
+                   for _, start, end in by_name["sched.decode_step"]]
+            for name in ("kv.step.upload", "kv.step.dispatch", "kv.step.fetch",
+                         "sched.retire")}
+    for name, counts in held.items():
+        assert max(counts) == 1 and sum(counts) == totals["steps"], name
+    assert held["kv.step.upload"] == held["kv.step.dispatch"]
+    assert held["kv.step.fetch"] == held["sched.retire"]
+    assert any(d and f for d, f in zip(held["kv.step.dispatch"],
+                                       held["kv.step.fetch"]))  # one step ahead
     assert {s["request_id"] for s, _, _ in by_name["door.request"]} >= set(spans)
     for name in ("door.read", "door.parse", "door.wait", "door.serialize",
                  "door.write"):

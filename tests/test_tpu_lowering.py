@@ -131,13 +131,22 @@ def _assert_in_place(lowered, compiled, n_arenas):
     return ops
 
 
+def _step_operands(one_chip, pool):
+    """What ``dispatch()`` hands the decode program behind the arenas and the
+    tables (ISSUE 38): the step before's output, where a slot's next token
+    stays (tokens, then the family's counters), the host's tokens for the
+    slots it knows better, and the positions."""
+    ints = _shape(one_chip, (pool.slots,), jnp.int32)
+    return _shape(one_chip, pool._carry.shape, jnp.int32), ints, ints
+
+
 def test_decode_program_compiles_for_the_chip_with_arenas_in_place(
         one_chip, on_chip_path, pool_and_params):
     pool, params = pool_and_params
     arena = _shape(one_chip, ARENA, jnp.bfloat16)
     lowered = pool._decode_fn.lower(
         params, arena, arena, _shape(one_chip, (SLOTS, MAX_BLOCKS), jnp.int32),
-        _shape(one_chip, (SLOTS,), jnp.int32), _shape(one_chip, (SLOTS,), jnp.int32))
+        *_step_operands(one_chip, pool))
     compiled = lowered.compile()  # a Mosaic error would be raised here
     ops = _assert_in_place(lowered, compiled, n_arenas=2)
     # what XLA does to an arena is one in-place scatter of the window's rows
@@ -252,8 +261,7 @@ def test_latent_decode_program_compiles_for_the_chip_with_the_arena_in_place(
     lowered = pool._decode_fn.lower(
         params, _shape(one_chip, K2_ARENA, jnp.bfloat16),
         _shape(one_chip, (K2_SLOTS, K2_MAX_BLOCKS), jnp.int32),
-        _shape(one_chip, (K2_SLOTS,), jnp.int32),
-        _shape(one_chip, (K2_SLOTS,), jnp.int32))
+        *_step_operands(one_chip, pool))
     compiled = lowered.compile()  # a Mosaic error would be raised here
     _assert_latent_arena_in_place(lowered, compiled)
     text = compiled.as_text()
@@ -384,8 +392,7 @@ def test_sparse_decode_program_compiles_for_the_chip_with_three_arenas_in_place(
     lowered = pool._decode_fn.lower(
         params, *(_shape(one_chip, a, jnp.bfloat16) for a in KV_ARENAS),
         _shape(one_chip, (KV_SLOTS, KV_MAX_BLOCKS), jnp.int32),
-        _shape(one_chip, (KV_SLOTS,), jnp.int32),
-        _shape(one_chip, (KV_SLOTS,), jnp.int32))
+        *_step_operands(one_chip, pool))
     compiled = lowered.compile()
     mem = _assert_three_arenas_in_place(lowered, compiled)
     # nothing of a K arena's layer is made beside it: a step gathers the
@@ -478,7 +485,7 @@ def test_windowed_decode_program_compiles_for_the_chip_with_four_arenas_in_place
     table = _shape(one_chip, (TR_SLOTS, TR_MAX_BLOCKS), jnp.int32)
     lowered = pool._decode_fn.lower(
         params, *(_shape(one_chip, a, jnp.bfloat16) for a in TR_ARENAS), table, table,
-        _shape(one_chip, (TR_SLOTS,), jnp.int32), _shape(one_chip, (TR_SLOTS,), jnp.int32))
+        *_step_operands(one_chip, pool))
     compiled = lowered.compile()  # a Mosaic error would be raised here
     mem = _assert_four_arenas_in_place(lowered, compiled)
     text = compiled.as_text()
