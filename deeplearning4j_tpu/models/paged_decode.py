@@ -436,9 +436,6 @@ class PagedDecodeSlotPool:
         # family's counters): the next step's tokens, where they stay
         self._flying: Deque[_Flight] = deque()
         self._carry = jnp.zeros(slots + len(fam.stat_names), jnp.int32)
-        #: seconds the last collected step blocked reading its result back (the
-        #: ``kv.step.fetch`` span): the loop's period less this is the host's
-        self.last_fetch_s = 0.0
         # cumulative: steps dispatched, and those dispatched while the step
         # before them was still uncollected (the host's work rode under it)
         self.kv_steps = 0
@@ -930,49 +927,51 @@ class PagedDecodeSlotPool:
         step reads them, so a caller may ``dispatch()`` step n+1 BEFORE it
         ``collect()``s step n and do its host work under the device's.
 
-        The host's side of a step is settled here: copy-on-write, the windows
-        slid, positions advanced. A slot whose budget is dispatched is sent as
-        a dead slot from then on (zero table row, position 0), released or
-        not, so no slot is stepped past its budget. Returns True when a step
-        is left RUNNING; False when there is nothing to wait for: no slot had
-        budget left or, in a pool with a draft, the step was read back here
-        (its next positions wait for the accepted count) and ``collect()``
-        hands the answer over."""
-        stepping = self._active & (self._dispatched < self._budget)
-        live = np.flatnonzero(stepping)
-        if live.size == 0:
-            return False
+        The host's side of a step is settled here (``kv.step.prepare``:
+        copy-on-write, the windows slid, the tokens the host knows, the step's
+        counters), then the uploads and the launch; positions advance. A slot
+        whose budget is dispatched is sent as a dead slot from then on (zero
+        table row, position 0), released or not, so no slot is stepped past
+        its budget. Returns True when a step is left RUNNING; False when there
+        is nothing to wait for: no slot had budget left or, in a pool with a
+        draft, the step was read back here (its next positions wait for the
+        accepted count) and ``collect()`` hands the answer over."""
         spec = self.draft_cfg is not None
         window = self.spec_tokens + 1 if spec else 1
-        if (self._positions[live] + window > self._span[live]).any():
-            raise RuntimeError(
-                "a live slot is at the end of its reserved block span — the "
-                "caller must retire sequences at their token budget")
-        for s in live:
-            s = int(s)
-            self._cow_before_write(s, int(self._positions[s]),
-                                   int(self._positions[s]) + window - 1)
-        grouped = self._slide_windows(live) if self._windowed else {}
-        # the step before, where it is still running: its riders' tokens are
-        # its output on the device; every other token the host knows
-        ahead = self._flying[-1] if self._flying else None
-        running = ahead is not None and ahead.answer is None
-        fresh = np.where(stepping, self._tokens, 0)
-        if running:
-            fresh[ahead.riders & stepping] = -1
+        with span("kv.step.prepare"):
+            stepping = self._active & (self._dispatched < self._budget)
+            live = np.flatnonzero(stepping)
+            if live.size == 0:
+                return False
+            if (self._positions[live] + window > self._span[live]).any():
+                raise RuntimeError(
+                    "a live slot is at the end of its reserved block span — "
+                    "the caller must retire sequences at their token budget")
+            for s in live:
+                s = int(s)
+                self._cow_before_write(s, int(self._positions[s]),
+                                       int(self._positions[s]) + window - 1)
+            grouped = self._slide_windows(live) if self._windowed else {}
+            # the step before, where it is still running: its riders' tokens
+            # are its output on the device; every other token the host knows
+            ahead = self._flying[-1] if self._flying else None
+            running = ahead is not None and ahead.answer is None
+            fresh = np.where(stepping, self._tokens, 0)
+            if running:
+                fresh[ahead.riders & stepping] = -1
+            live_blocks = int(
+                (-(-(self._positions[live] + window) // self.block_T)).sum())
+            mapped_blocks = self.slots * self.max_blocks
+            self.kv_blocks_read += live_blocks
+            self.kv_blocks_mapped += mapped_blocks
+            self.kv_steps += 1
+            self.kv_steps_overlapped += running
         with span("kv.step.upload"):
             # private copies: the host edits its tables while the step runs
             tables = [jnp.asarray(np.where(stepping[:, None], g.tables, 0))
                       for g in self._groups]
             toks = jnp.asarray(fresh)
             pos = jnp.asarray(np.where(stepping, self._positions, 0))
-        live_blocks = int(
-            (-(-(self._positions[live] + window) // self.block_T)).sum())
-        mapped_blocks = self.slots * self.max_blocks
-        self.kv_blocks_read += live_blocks
-        self.kv_blocks_mapped += mapped_blocks
-        self.kv_steps += 1
-        self.kv_steps_overlapped += running
         try:
             # a step's own routing is known when its tokens come back: the
             # span carries the counters of the step fetched last
@@ -1009,44 +1008,50 @@ class PagedDecodeSlotPool:
 
     def _land(self, flight: _Flight) -> None:
         """Read ``flight``'s results back (the one host round trip a step,
-        S4) and credit them: the family's counters, and for every rider still
-        in its slot its token(s), ``_tokens`` and ``_emitted``."""
+        S4: ``kv.step.fetch``, which carries ``ready``, whether the result was
+        there before the read) and credit them (``kv.step.land``): the
+        family's counters, and for every rider still in its slot its
+        token(s), ``_tokens`` and ``_emitted``."""
+        # ``ready`` is asked BEFORE the blocking read: a result that is not
+        # there yet means the device was still running when the host came
+        # back for it (the device paced this step); one that is, the host did
         try:
-            with span("kv.step.fetch") as fetch:
+            ready = int(flight.results[0].is_ready())
+            with span("kv.step.fetch", ready=ready):
                 fetched = [np.asarray(r) for r in flight.results]
         except Exception as e:
             raise self._lost("decode step", e) from e
-        self.last_fetch_s = fetch.duration_s
-        flight.results = None
-        out: Dict[int, List[int]] = {}
-        riders = [int(s) for s in np.flatnonzero(flight.riders)]
-        if self.draft_cfg is None:
-            nxt = fetched[0]
-            if self.family.stat_names:
-                nxt, counted = nxt[:self.slots], nxt[self.slots:]
-                self.last_step_stats = {
-                    name: int(v) for name, v in zip(self.family.stat_names,
-                                                    counted)}
-                for name, v in self.last_step_stats.items():
-                    self.family_stats[name] += v
-                self.family_steps += 1
-            for slot in riders:
-                out[slot] = [int(nxt[slot])]
-                self._tokens[slot] = nxt[slot]
-                self._emitted[slot] += 1
-        else:
-            ver, n_acc = fetched
-            for slot in riders:
-                na = int(n_acc[slot])
-                self.spec_proposed += self.spec_tokens
-                self.spec_accepted += na - 1
-                remaining = int(self._budget[slot] - self._emitted[slot])
-                take = min(na, max(remaining, 0))
-                out[slot] = [int(t) for t in ver[slot, :take]]
-                self._positions[slot] += na
-                self._tokens[slot] = int(ver[slot, na - 1])
-                self._emitted[slot] += take
-                self._dispatched[slot] = self._emitted[slot]
+        with span("kv.step.land"):
+            flight.results = None   # the device's buffers go here
+            out: Dict[int, List[int]] = {}
+            riders = [int(s) for s in np.flatnonzero(flight.riders)]
+            if self.draft_cfg is None:
+                nxt = fetched[0]
+                if self.family.stat_names:
+                    nxt, counted = nxt[:self.slots], nxt[self.slots:]
+                    self.last_step_stats = {
+                        name: int(v) for name, v in zip(self.family.stat_names,
+                                                        counted)}
+                    for name, v in self.last_step_stats.items():
+                        self.family_stats[name] += v
+                    self.family_steps += 1
+                for slot in riders:
+                    out[slot] = [int(nxt[slot])]
+                    self._tokens[slot] = nxt[slot]
+                    self._emitted[slot] += 1
+            else:
+                ver, n_acc = fetched
+                for slot in riders:
+                    na = int(n_acc[slot])
+                    self.spec_proposed += self.spec_tokens
+                    self.spec_accepted += na - 1
+                    remaining = int(self._budget[slot] - self._emitted[slot])
+                    take = min(na, max(remaining, 0))
+                    out[slot] = [int(t) for t in ver[slot, :take]]
+                    self._positions[slot] += na
+                    self._tokens[slot] = int(ver[slot, na - 1])
+                    self._emitted[slot] += take
+                    self._dispatched[slot] = self._emitted[slot]
         flight.answer = out
 
     def _slide_windows(self, live) -> Dict[str, int]:

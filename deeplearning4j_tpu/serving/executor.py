@@ -50,7 +50,8 @@ import numpy as np
 from ..common.faults import fault_point
 from ..monitoring import aggregate, flight
 from ..monitoring.serving import serving_metrics
-from ..monitoring.trace import span
+from ..monitoring.trace import (LOOP_SPANS, SEGMENTS, StepPhaseRecorder,
+                                feed_spans_to, profiler_listening, span)
 
 log = logging.getLogger(__name__)
 
@@ -529,6 +530,17 @@ class GenerationFuture(InferenceFuture):
 #: to see stalls without letting a 2k-token generation bloat the flight ring
 _SPAN_STEP_CAP = 64
 
+#: rows of the loop's step account: the newest 32,768 steps, 93 s of steps at
+#: the fastest cell's 350 a second (a row is 19 int64: 4.75 MiB in all)
+_STEP_RING = 1 << 15
+#: what a row holds beside its segment, period, phases and ``other``
+_STEP_FIELDS = ("step", "live", "overlapped", "ready")
+#: an idle loop waits in slices, one ``sched.idle`` span each: a span that is
+#: open when a profiler session starts or stops is never written, so a trace
+#: loses at most one slice at either end (and an idle server wakes 20 times
+#: a second for a predicate check)
+_IDLE_SLICE_S = 0.05
+
 
 class StepAtDispatch:
     """``dispatch`` / ``collect`` for a session that has only ``step()``: the
@@ -625,11 +637,17 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
         # still) and its `decode` (every step of its slot life is its own)
         self._prefill_s = 0.0
         self._step_s = 0.0
-        # a dispatched step is still running (its answer uncollected), and the
+        # a dispatched step is still running (its answer uncollected; and
+        # whether IT was dispatched while the one before it was), and the
         # instant the current step's period began: the collect before it, or
         # its own dispatch where nothing was running
         self._ahead = False
-        self._t_period = 0.0
+        self._ahead_overlapped = False
+        self._t_period_ns = 0
+        # where a token's period goes: the loop thread's spans feed it for
+        # the thread's life, a row a collected step (``stats()["step_account"]``)
+        self._account = StepPhaseRecorder(ring=_STEP_RING, columns=LOOP_SPANS,
+                                          fields=_STEP_FIELDS)
         self._occupancy_sum = 0
         self._tokens_out = 0
         self._admitted = 0
@@ -757,13 +775,22 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
                 log.exception("generative warmup failed — the first request "
                               "will pay the XLA compiles instead")
         self._warm.set()
+        feed_spans_to(self._account)
+        try:
+            self._serve()
+        finally:
+            feed_spans_to(None)
+
+    def _serve(self) -> None:
         active: Dict[int, GenerationFuture] = {}
+        account = self._account
         while True:
             with self._cv:
                 while (not self._q and not active and not self._ahead
                        and not self._stopping):
                     with span("sched.idle"):
-                        self._cv.wait()
+                        self._cv.wait(_IDLE_SLICE_S)
+                account.profiler_seen(profiler_listening())  # once a turn
                 stopping, drain = self._stopping, self._drain_on_stop
                 if stopping and not drain:
                     # queued requests were already cancelled by stop();
@@ -904,9 +931,12 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
         # the turn that COLLECTS step n carries n (and dispatches n+1); a
         # request joins its steps in a device trace by number
         with span("sched.decode_step", step=self._steps + 1, live=len(active)):
-            behind = self._ahead
+            behind, overlapped = self._ahead, self._ahead_overlapped
             if not behind:
-                self._t_period = time.monotonic()
+                # a run of steps starts here: what the loop did since the
+                # last collect (idle, admissions) was no step's
+                self._account.discard()
+                self._t_period_ns = time.perf_counter_ns()
             if launch:
                 try:
                     fault_point("infer")
@@ -914,16 +944,20 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
                 except Exception as e:
                     self._fail_riders(active, e)
                     return
+                self._ahead_overlapped = behind
             else:
                 self._ahead = False
             if behind:
-                self._collect_step(active)
+                self._collect_step(active, overlapped)
             if not self._ahead:
-                self._collect_step(active)
+                self._collect_step(active, behind)
 
-    def _collect_step(self, active: Dict[int, GenerationFuture]) -> None:
-        """Collect the oldest uncollected step and retire by what it brought
-        (nothing to do where the session has none)."""
+    def _collect_step(self, active: Dict[int, GenerationFuture],
+                      overlapped: bool) -> None:
+        """Collect the oldest uncollected step, write its row of the step
+        account and retire by what it brought (nothing to do where the
+        session has none). ``overlapped``: it was dispatched while the step
+        before it was uncollected."""
         try:
             out = self.session.collect()
         except Exception as e:
@@ -931,17 +965,25 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
             return
         if out is None:
             return
-        now = time.monotonic()
+        now_ns = time.perf_counter_ns()
         # the loop's period a step: what a token costs its client
-        dt, self._t_period = now - self._t_period, now
+        period_ns, self._t_period_ns = now_ns - self._t_period_ns, now_ns
+        dt = period_ns / 1e9
         self._step_s += dt
         self._steps += 1
+        # the pool's ``kv.step.fetch`` says how long it blocked on the step's
+        # result and whether the result was there before it asked; the rest
+        # of the period was the host's own work
+        account = self._account
+        fetch_ns = account.pending_ns("kv.step.fetch")
+        ready = -1 if fetch_ns is None else account.last_stats.get(
+            "kv.step.fetch", {}).get("ready", -1)
+        account.step_done(period_ns,
+                          (self._steps, len(active), overlapped, ready))
         with span("sched.retire"):
-            # the paged pool says how long it blocked on the step's result;
-            # the rest of the period was the host's own work
-            fetch_s = getattr(self.session, "last_fetch_s", None)
             self._retire(active, out, dt,
-                         None if fetch_s is None else dt - fetch_s)
+                         None if fetch_ns is None else (period_ns - fetch_ns) / 1e9)
+        with span("sched.gauges"):
             self._sync_session_metrics()
             aggregate.maybe_spool()  # replica's aggregated-/metrics spool
 
@@ -1092,12 +1134,61 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
 
     # -- introspection -----------------------------------------------------
 
+    def step_account(self) -> dict:
+        """Where a token's period goes, by :data:`~..monitoring.trace.SEGMENTS`
+        (whether a profiler session was listening), from the rows the loop
+        thread wrote, a collected step each; the quantiles are taken here, on
+        the asker's thread. A segment holds ``steps`` (all it ever counted)
+        and ``rows`` (those the ring still has: what the rest is taken
+        over), ``live_mean``, ``overlapped_share`` (steps dispatched while
+        the step before them was uncollected), ``ready_share`` (of THOSE,
+        the ones whose result was there before the host asked for it: the
+        host set that step's pace, not the device; None where the session
+        does not say), ``period_ms`` (collect to collect), ``host_ms`` (a
+        row's period less its ``kv.step.fetch``), ``phases_ms`` (exclusive
+        time of every loop-thread span that closed in the period),
+        ``other_ms`` (a row's period less its phases), each ``{p50, p90}``,
+        and ``loop_s``: the exclusive seconds of the spans that closed
+        between two runs of steps (``sched.idle``, ``sched.admit`` >
+        ``kv.prefill``, the retirement of a run's last step)."""
+        snap = self._account.snapshot()
+        col = {name: i for i, name in enumerate(snap["columns"])}
+        rows = snap["rows"]
+
+        def quantiles(ns) -> dict:
+            p50, p90 = np.percentile(ns, (50, 90))
+            return {"p50": float(p50) / 1e6, "p90": float(p90) / 1e6}
+
+        out = {}
+        for code, segment in enumerate(SEGMENTS):
+            steps, outside = snap["steps"][code], snap["outside_s"][code]
+            if not steps and not outside:
+                continue
+            r = rows[rows[:, col["segment"]] == code]
+            entry = {"steps": steps, "rows": len(r), "loop_s": outside}
+            if len(r):
+                period = r[:, col["period"]]
+                overlapped = r[:, col["overlapped"]] == 1
+                said = r[overlapped & (r[:, col["ready"]] >= 0), col["ready"]]
+                entry.update(
+                    live_mean=float(r[:, col["live"]].mean()),
+                    overlapped_share=float(overlapped.mean()),
+                    ready_share=float(said.mean()) if len(said) else None,
+                    period_ms=quantiles(period),
+                    host_ms=quantiles(period - r[:, col["kv.step.fetch"]]),
+                    phases_ms={name: quantiles(r[:, col[name]])
+                               for name in LOOP_SPANS if r[:, col[name]].any()},
+                    other_ms=quantiles(r[:, col["other"]]))
+            out[segment] = entry
+        return out
+
     def stats(self) -> dict:
         """This executor's continuous-batching aggregates (bench evidence):
-        decode steps, emitted tokens, admissions/evictions, and MEAN slot
+        decode steps, emitted tokens, admissions/evictions, MEAN slot
         occupancy per step — the measured batching-efficiency number the
-        continuous-vs-static comparison reports. Paged sessions add block
-        occupancy, CoW savings and the speculative acceptance rate."""
+        continuous-vs-static comparison reports — and ``step_account``
+        (:meth:`step_account`). Paged sessions add block occupancy, CoW
+        savings and the speculative acceptance rate."""
         s = {
             "steps": self._steps,
             "tokens": self._tokens_out,
@@ -1105,6 +1196,7 @@ class GenerativeInferenceExecutor(BatchingInferenceExecutor):
             "evicted": self._evicted,
             "mean_slot_occupancy": (round(self._occupancy_sum / self._steps, 3)
                                     if self._steps else 0.0),
+            "step_account": self.step_account(),
         }
         block_stats = getattr(self.session, "block_stats", None)
         if block_stats is not None:

@@ -371,7 +371,7 @@ def test_step_phase_recorder_survives_raising_phase_body():
             time.sleep(0.01)
             with rec.phase("h2d"):
                 raise RuntimeError("h2d blew up")
-    assert rec._frames == []  # both frames unwound despite the raise
+    assert rec._depth == 0  # both phases unwound despite the raise
     rec.discard()  # failed step: drop its partial accumulation
 
     # the NEXT step accounts cleanly — nesting and exclusive time intact

@@ -41,9 +41,8 @@ class SleepySession(StepAtDispatch):
 
     def __init__(self, slots=3, admit_s=0.03, step_s=0.01, fetch_s=None):
         self.slots, self.admit_s, self.step_s = slots, admit_s, step_s
+        self.fetch_s = fetch_s  # the part of a step spent under ``kv.step.fetch``
         self.active = set()
-        if fetch_s is not None:
-            self.last_fetch_s = fetch_s  # what the paged pool reports
 
     @property
     def free_slots(self):
@@ -56,7 +55,12 @@ class SleepySession(StepAtDispatch):
         return slot, 1
 
     def step(self):
-        time.sleep(self.step_s)
+        if self.fetch_s is None:
+            time.sleep(self.step_s)
+        else:   # as the paged pool: the wait for the device is a span
+            time.sleep(self.step_s - self.fetch_s)
+            with span("kv.step.fetch"):
+                time.sleep(self.fetch_s)
         return {s: [2] for s in self.active}
 
     def release(self, slot):
@@ -156,14 +160,36 @@ def test_decode_is_the_requests_own_steps_and_joins_them_by_number(served):
 
 
 def test_step_host_ms_is_the_step_less_the_pools_fetch():
-    spans, _ = _serve(SleepySession(slots=2, admit_s=0.0, step_s=0.02,
-                                    fetch_s=0.015), budgets=[3, 3])
-    for ev in spans.values():
-        assert len(ev["step_host_ms"]) == len(ev["step_ms"]) == 2
-        for host, whole in zip(ev["step_host_ms"], ev["step_ms"]):
-            assert host == pytest.approx(whole - 15.0, abs=1e-3)
+    """A step's ``step_host_ms`` is its period less the ``kv.step.fetch``
+    that closed inside it: the number the loop's step account keeps in the
+    step's row (by ``first_step..last_step``), not an attribute of the pool."""
+    session = SleepySession(slots=2, admit_s=0.0, step_s=0.02, fetch_s=0.015)
+    rec = flight.FlightRecorder(proc="span-test", capacity=4096)
+    flight.set_flight_recorder(rec)
+    server = JsonModelServer(None, generative_session=session,
+                             default_max_new_tokens=4,
+                             registry=MetricsRegistry()).start()
+    try:
+        assert server.wait_ready(60.0)
+        for rid in ("r0", "r1"):
+            assert len(_post(server.port, rid, max_new=3)) == 3
+        snap = server._executor._account.snapshot()
+    finally:
+        server.stop()
+        flight.set_flight_recorder(None)
+    col = {name: i for i, name in enumerate(snap["columns"])}
+    rows = {int(r[col["step"]]): r for r in snap["rows"]}
+    spans = [e for e in rec.events() if e["kind"] == "request_span"]
+    assert len(spans) == 2
+    for ev in spans:
+        steps = range(ev["first_step"], ev["last_step"] + 1)
+        assert len(ev["step_host_ms"]) == len(ev["step_ms"]) == len(steps) == 2
+        for n, host, whole in zip(steps, ev["step_host_ms"], ev["step_ms"]):
+            period, fetch = rows[n][col["period"]], rows[n][col["kv.step.fetch"]]
+            assert fetch > 0 and whole == round(period / 1e6, 3)
+            assert host == round((period - fetch) / 1e6, 3)
     plain, _ = _serve(SleepySession(slots=1), budgets=[2])
-    assert "step_host_ms" not in plain["r0"]  # a pool that reports no fetch
+    assert "step_host_ms" not in plain["r0"]  # a pool that opens no fetch span
 
 
 def test_a_request_done_at_prefill_has_no_steps_to_join():
@@ -359,6 +385,37 @@ OLD_SPAN = {"code": 200, "steps": 4, "step_ms": [10.0] * 4,
             "phases": {"queue": 0.1, "prefill": 0.05, "decode": 0.04,
                        "serialize": 0.001}}
 
+def _account(rows=900, ready_share=0.25, **segments):
+    """An observation whose ``/stats`` holds a step account: the ``untraced``
+    segment as a 3 ms period of which the fetch is 0.8."""
+    def q(p50):
+        return {"p50": p50, "p90": 2 * p50}
+
+    untraced = {
+        "steps": rows, "rows": rows, "live_mean": 1.3, "overlapped_share": 0.95,
+        "ready_share": ready_share, "period_ms": q(3.0), "host_ms": q(2.2),
+        "other_ms": q(0.06), "loop_s": {"sched.idle": 9.0},
+        "phases_ms": {"kv.step.prepare": q(0.05), "kv.step.upload": q(0.8),
+                      "kv.step.dispatch": q(0.75), "kv.step.fetch": q(0.8),
+                      "sched.retire": q(0.04), "sched.gauges": q(0.07),
+                      "sched.decode_step": q(0.1)}}
+    return {"serve": {"executor_stats": {"step_account": {
+        "untraced": untraced, **segments}}}}
+
+
+# ten readers of the ``untraced`` segment (ISSUE 39), each with its value on
+# the account above; all of them None where the program keeps no account
+# (every commit before PR 39), where the segment is absent or short
+STEP_ACCOUNT_READERS = {
+    "kv.step_period_ms.untraced": 3.0, "kv.step_host_ms.untraced": 2.2,
+    "kv.step_prepare_ms.untraced": 0.05, "kv.step_upload_ms.untraced": 0.8,
+    "kv.step_launch_ms.untraced": 0.75, "kv.step_fetch_wait_ms.untraced": 0.8,
+    "sched.retire_ms.untraced": 0.04, "sched.gauges_ms.untraced": 0.07,
+    "sched.step_unattributed_share": 2.0, "kv.device_paced_step_share": 75.0}
+NO_ACCOUNT = {"serve": {"executor_stats": {"steps": 12, "blocks": {}}}}
+TRACED_ONLY = {"serve": {"executor_stats": {"step_account": {
+    "traced": _account()["serve"]["executor_stats"]["step_account"]["untraced"]}}}}
+
 CLOSED = {"a": _closed(0.08, 0.02, 5, step_host_ms=[2.0, 4.0]),
           "b": _closed(0.30, 0.00, 10, step_host_ms=[3.0]),
           "c": _closed(0.00, 0.06, 2, door=0.003, step_host_ms=[9.0, 1.0])}
@@ -379,6 +436,14 @@ CLOSED = {"a": _closed(0.08, 0.02, 5, step_host_ms=[2.0, 4.0]),
     ("sched.interleave_time_share", _obs(CLOSED), 100.0 * 0.38 / 2.25),
     ("sched.interleave_time_share", _obs({"a": OLD_SPAN}), None),
     ("sched.interleave_time_share", {"serve": None}, None),
+    *[(m, _account(), want) for m, want in STEP_ACCOUNT_READERS.items()],
+    *[(m, NO_ACCOUNT, None) for m in STEP_ACCOUNT_READERS],
+    *[(m, _account(rows=199), None) for m in STEP_ACCOUNT_READERS],
+    ("kv.step_period_ms.untraced", TRACED_ONLY, None),   # no untraced segment
+    ("kv.step_host_ms.untraced", {"serve": None}, None),
+    ("kv.device_paced_step_share", _account(ready_share=None), None),  # none said
+    ("kv.device_paced_step_share", _account(ready_share=0.0), 100.0),
+    ("sched.gauges_ms.untraced", _account(rows=200), 0.07),   # 200 rows do
 ])
 def test_new_reader_on_a_hand_built_observation(metric, obs, expected):
     got = _read(metric, obs)
@@ -407,6 +472,17 @@ def test_every_new_metric_of_benchmark_json_has_its_reader():
                                            m["name"] + ".py")), m["name"]
         # a cell a metric lists reports the end-to-end metric it moves
         assert set(m.get("workloads", [])) <= reports[m["moves"]], m["name"]
+    # ISSUE 39's ten: appended together, read from the program's spans with
+    # no profiler listening, in the four serving cells, for their p90
+    names = [m["name"] for m in bench["per_layer"]]
+    mine = bench["per_layer"][names.index("kv.step_period_ms.untraced"):][:10]
+    assert {m["name"] for m in mine} == set(STEP_ACCOUNT_READERS)
+    serving = [w["name"] for w in bench["workloads"]
+               if w["name"] in reports["serve_lat_per_tok_p90_ms"]]
+    for m in mine:
+        assert (m["source"], m["moves"], m["workloads"]) == (
+            "program_span", "serve_lat_per_tok_p90_ms", serving), m["name"]
+        assert m["layer"] == m["name"].split(".")[0] and m["unit"] in ("ms", "%")
 
 
 def test_leak_audit_counts_only_this_workers_shared_memory(monkeypatch, tmp_path):

@@ -17,7 +17,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import work_trinity as wt  # noqa: E402
+from benchmark import stepaccount, work_trinity as wt  # noqa: E402
 
 V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 CELL = "trinity-large-preview.mixed-len"
@@ -227,6 +227,7 @@ def test_the_cell_is_declared_with_the_issues_readers_and_judged_on_p90_and_serv
         "gen.lateness_p99_ms", "sched.queue_wait_p50_ms", "sched.ttft_p50_ms",
         "kv.block_occupancy", "kv.step_host_ms", "kv.cache_bytes_per_token",
         "kv.step_overlap_share",  # ISSUE 38: the four serving cells
+        *stepaccount.READERS,     # ISSUE 39: the step account's ten, likewise
         "device.peak_mem_frac.serve", "moe.experts_touched_share",
         "moe.resident_assignment_share", "moe.load_max_over_mean", *new}
     mine = [m for m in bench["per_layer"] if m["name"] in new]
