@@ -1,5 +1,5 @@
-"""Learned sparse attention over whole sequences (prefill): two Pallas kernels
-for the two steps that XLA leaves in HBM.
+"""Learned sparse attention: two Pallas kernels for the two steps of prefill
+that XLA leaves in HBM, and one for a decode step's selection.
 
 A model with a learned selection (``models/keye_vl.py``) scores every cached
 row for every query, keeps the ``k`` best and attends to those only. For a
@@ -17,6 +17,16 @@ HBM with ``[C, T]`` float32 arrays:
   ``G`` query heads that share the K/V head, the mask tile is an int8
   ``[C, block_k]`` block shared by them, key blocks past the chunk's causal
   frontier are neither copied nor computed, and the online softmax is float32.
+- :func:`decode_select` (``dsa_decode_select``) — a decode step's top-``k``
+  of every slot's cached rows, exact and COMPACTED: the cells of the chosen
+  rows in row order, which the step's row gather reads. A slot is one grid
+  step (its scores in ``[R / 128, 128]`` vregs, its live rows a prefix): the
+  k-th score by the same bisection, the rows tied at it admitted in row
+  order while there is room, then each 128-row chunk packs its chosen cells
+  to the left (seven lane rolls) and lands at its offset in the output (one
+  more). A dead slot's step copies and computes nothing. It replaced a
+  stable sort of ``[slots, max_len]`` with the cells as payload: a third of
+  a step's device time (PERF.md, PR 40).
 
 Off the TPU both run in interpret mode, as ``kernels/attention.py`` does, so
 the CPU tests exercise the path the chip runs.
@@ -43,22 +53,33 @@ def _interpret() -> bool:
 # ------------------------------------------------------------ the k-th score
 
 
-def _kth_kernel(keys_ref, out_ref, *, k: int):
-    """One block of rows: the largest ``u`` with ``count(keys >= u) >= k``,
-    built bit by bit from the top. ``keys`` are int32 whose SIGNED order is
-    the scores' order; the answer is built in the unsigned domain (the keys
-    with the sign bit flipped), where setting a bit only ever raises it."""
-    keys = keys_ref[...]
+def _order_keys(scores):
+    """float32 -> int32 whose SIGNED order is the scores' IEEE order (the
+    magnitude bits of negatives flipped)."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    return bits ^ (jnp.right_shift(bits, 31) & jnp.int32(0x7FFFFFFF))
+
+
+def _kth_key(keys, k: int, count):
+    """The largest key ``u`` with ``count(keys >= u) >= k``, built bit by bit
+    from the top: ``keys`` int32 in the scores' order (``_order_keys``),
+    ``count(bool array)`` the float32 count of each group that shares a
+    threshold (keepdims). The answer is built in the unsigned domain (the
+    keys with the sign bit flipped), where setting a bit only ever raises it."""
 
     def bit(i, found):
         trial = found | jnp.left_shift(jnp.int32(1), 31 - i)
-        # counted in float32: exact up to 2^24 keys a row
-        enough = jnp.sum((keys >= (trial ^ _SIGN)).astype(jnp.float32),
-                         axis=-1, keepdims=True) >= k
-        return jnp.where(enough, trial, found)
+        # counted in float32: exact up to 2^24 keys a group
+        return jnp.where(count(keys >= (trial ^ _SIGN)) >= k, trial, found)
 
-    found = jax.lax.fori_loop(0, 32, bit, jnp.zeros(out_ref.shape, jnp.int32))
-    out_ref[...] = found ^ _SIGN
+    shape = jax.eval_shape(count, keys).shape
+    return jax.lax.fori_loop(0, 32, bit, jnp.zeros(shape, jnp.int32)) ^ _SIGN
+
+
+def _kth_kernel(keys_ref, out_ref, *, k: int):
+    """One block of rows, a threshold a row."""
+    out_ref[...] = _kth_key(keys_ref[...], k, lambda m: jnp.sum(
+        m.astype(jnp.float32), axis=-1, keepdims=True))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_rows", "interpret"))
@@ -82,9 +103,7 @@ def kth_largest(scores, k: int):
     [..., T], exactly (``-inf`` where a row holds fewer than ``k`` values
     above it, as ``lax.top_k(scores, k)[0][..., -1]`` gives) -> [..., 1]."""
     lead, T = scores.shape[:-1], scores.shape[-1]
-    bits = jax.lax.bitcast_convert_type(scores.reshape(-1, T), jnp.int32)
-    # IEEE order as signed integer order: flip the magnitude of negatives
-    keys = bits ^ (jnp.right_shift(bits, 31) & jnp.int32(0x7FFFFFFF))
+    keys = _order_keys(scores.reshape(-1, T))
     rows = keys.shape[0]
     # a block of rows and its double buffer stay within a few MiB of VMEM
     block = max(8, min(rows, (2 ** 22 // (4 * T)) // 8 * 8))
@@ -93,6 +112,182 @@ def kth_largest(scores, k: int):
                       interpret=_interpret())[:rows]
     back = found ^ (jnp.right_shift(found, 31) & jnp.int32(0x7FFFFFFF))
     return jax.lax.bitcast_convert_type(back, jnp.float32).reshape(*lead, 1)
+
+
+# ----------------------------------------------- a decode step's selection
+
+
+def _before(n: int):
+    """bf16 [n, n]: 1 where the row's index is below the column's."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+            < jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)).astype(jnp.bfloat16)
+
+
+def _rank_in_chunk(ones):
+    """bf16 0/1 [NC, 128] -> float32 [NC, 128]: how many ones come before a
+    lane in its chunk. Every count here is a matmul of 0/1 bf16 (or of
+    counts up to 128, exact in bf16) accumulated in float32: exact."""
+    return jnp.dot(ones, _before(128), preferred_element_type=jnp.float32)
+
+
+def _chunk_counts(ones):
+    """bf16 0/1 [NC, 128] -> float32 [8, NC]: each chunk's count as a row
+    (every sublane alike): a matmul, where a sum would need a relayout."""
+    return jax.lax.dot_general(jnp.ones((8, 128), jnp.bfloat16), ones,
+                               (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _rank(ones):
+    """bf16 0/1 [NC, 128] -> float32 [NC, 128]: how many ones come before a
+    row, in row order."""
+    counts = jnp.sum(ones.astype(jnp.float32), axis=1, keepdims=True)
+    return _rank_in_chunk(ones) + jnp.dot(
+        _before(ones.shape[0]).T, jnp.broadcast_to(counts, ones.shape).astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32)
+
+
+def _select_kernel(limits_ref, visit_ref, scores_ref, cells_ref, out_ref, ties_ref,
+                   acc_ref, packed_ref, offsets_ref, offsets_smem, *, k: int):
+    """One slot: ``out`` [KB, 128] the cells of its selected rows in row
+    order from place 0, zeros behind them; ``ties`` [1, 128] 1 where rows
+    tied at the threshold outnumbered the room left for them. A slot's rows
+    are ``[NC, 128]`` (row ``r`` at ``[r // 128, r % 128]``, a 128-row
+    CHUNK a sublane); its live rows are the prefix below ``limits[slot]``."""
+    del visit_ref                         # read by the index maps only
+    limit = limits_ref[pl.program_id(0)]
+    n_chunks, kb = scores_ref.shape[0], out_ref.shape[0]
+    ties_ref[...] = jnp.zeros_like(ties_ref)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (n_chunks, 128), 1)
+
+    def row_at(shape):
+        return (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * 128
+                + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+
+    @pl.when(limit <= k)
+    def _whole_prefix():                  # a dead slot too: nothing below 0
+        out_ref[...] = jnp.where(row_at(out_ref.shape) < limit, cells_ref[:kb], 0)
+
+    @pl.when(limit > k)
+    def _threshold():
+        keys = jnp.where(row_at(scores_ref.shape) < limit,
+                         _order_keys(scores_ref[...]), _SIGN)
+
+        def count(m):
+            return jnp.sum(jnp.sum(m.astype(jnp.float32), axis=0, keepdims=True),
+                           axis=1, keepdims=True)
+
+        t = _kth_key(keys, k, count)                                # [1, 1]
+        above, tied = keys > t, keys == t
+        room = k - count(above)
+        ties_ref[...] = jnp.broadcast_to(count(tied) > room, ties_ref.shape).astype(
+            jnp.int32)
+        # rows tied at t enter in row order while there is room
+        chosen = above | (tied & (_rank(tied.astype(jnp.bfloat16)) < room))
+        ones = chosen.astype(jnp.bfloat16)
+        # within each chunk its chosen cells go to lanes 0.. in row order: a
+        # chosen row moves left by the rows not chosen before it, one bit of
+        # that distance a pass (lowest first: no two ever meet)
+        gap = jnp.where(chosen, lane - _rank_in_chunk(ones).astype(jnp.int32), 128)
+        cell = cells_ref[...]
+        for bit in (1, 2, 4, 8, 16, 32, 64):
+            come_gap, come_cell = (pltpu.roll(x, 128 - bit, 1) for x in (gap, cell))
+            come = (come_gap & bit) != 0          # 128 (not chosen) has no such bit
+            stay = (gap & bit) == 0
+            cell = jnp.where(come, come_cell, cell)
+            gap = jnp.where(come, come_gap, jnp.where(stay, gap, 128))
+        packed_ref[...] = jnp.where(gap < 128, cell, 0)
+        # where each chunk's cells start in the output: scalars, so moved to
+        # SMEM in one copy (reading a vector's element costs ~0.2 us a time)
+        offsets_ref[...] = jnp.dot(_chunk_counts(ones).astype(jnp.bfloat16),
+                                   _before(n_chunks),
+                                   preferred_element_type=jnp.float32).astype(jnp.int32)
+        pltpu.sync_copy(offsets_ref, offsets_smem)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        row_lane = lane[:1]
+
+        def chunk(c, carry):
+            # lanes [start, start + count) of the output from block ``first``
+            # on: the chunk's cells rolled by ``start``, the lanes that wrap
+            # go to the next block (a chunk past the last chosen row adds
+            # zeros to the last block)
+            at = offsets_smem[0, c]
+            first = jnp.minimum(at // 128, kb - 1)
+            start = at % 128
+            rolled = pltpu.roll(packed_ref[pl.ds(c, 1), :], start, 1)
+            acc_ref[pl.ds(first, 1), :] += jnp.where(row_lane >= start, rolled, 0)
+            acc_ref[pl.ds(first + 1, 1), :] += jnp.where(row_lane < start, rolled, 0)
+            return carry
+
+        # only the chunks that hold live rows
+        jax.lax.fori_loop(0, jnp.minimum((limit + 127) // 128, n_chunks), chunk, 0)
+        out_ref[...] = acc_ref[:kb]
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def _select_call(scores, cells, limits, *, k: int, interpret: bool):
+    S, n_chunks, _ = scores.shape
+    kb = -(-k // 128)
+    live = limits > 0
+    # the slot whose rows a grid step holds: its own where it is live, else
+    # the last live one before it (the first live one before any): a dead
+    # slot's step starts no copy
+    before = jax.lax.cummax(jnp.where(live, jnp.arange(S), -1))
+    visit = jnp.where(before >= 0, before, jnp.argmax(live)).astype(jnp.int32)
+    rows = pl.BlockSpec((None, n_chunks, 128), lambda i, lim, vis: (vis[i], 0, 0))
+    return pl.pallas_call(
+        functools.partial(_select_kernel, k=k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[rows, rows],
+            out_specs=[pl.BlockSpec((None, kb, 128), lambda i, lim, vis: (i, 0, 0)),
+                       pl.BlockSpec((None, 1, 128), lambda i, lim, vis: (i, 0, 0))],
+            scratch_shapes=[
+                pltpu.VMEM((kb + 1, 128), jnp.int32),      # the output + 1 block
+                pltpu.VMEM((n_chunks, 128), jnp.int32),    # the chunks, packed
+                pltpu.VMEM((8, n_chunks), jnp.int32),      # where they start
+                pltpu.SMEM((8, n_chunks), jnp.int32),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((S, kb, 128), jnp.int32),
+                   jax.ShapeDtypeStruct((S, 1, 128), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="dsa_decode_select",  # what a device trace calls the kernel
+    )(limits, visit, scores, cells)
+
+
+def decode_select_counted(scores, cell_of_row, limits, k: int):
+    """:func:`decode_select`, and bool [S]: the slots whose selection the
+    threshold decided with more rows tied at it than room for them."""
+    S, R = scores.shape
+    limits = limits.astype(jnp.int32)
+    chosen = jnp.arange(k)[None, :] < jnp.minimum(limits, k)[:, None]
+    if k >= R:                           # every live row is selected
+        cells = jnp.pad(cell_of_row, ((0, 0), (0, k - R)))
+        return chosen, jnp.where(chosen, cells, 0), jnp.zeros((S,), bool)
+    pad = -R % 128
+    scores = jnp.pad(scores, ((0, 0), (0, pad)), constant_values=-jnp.inf)
+    cells = jnp.pad(cell_of_row, ((0, 0), (0, pad)))
+    out, ties = _select_call(scores.reshape(S, -1, 128), cells.reshape(S, -1, 128),
+                             limits, k=k, interpret=_interpret())
+    return chosen, out.reshape(S, -1)[:, :k], ties[:, 0, 0] > 0
+
+
+def decode_select(scores, cell_of_row, limits, k: int):
+    """One decode step's exact top-``k`` of every slot, compacted, with no
+    sort: float32 ``scores`` [S, R], ``cell_of_row`` int32 [S, R] (where row
+    ``r`` of slot ``s`` lies), ``limits`` [S] (slot ``s``'s live
+    rows are ``0 .. limits[s] - 1``; 0 for a dead slot) -> (``chosen`` bool
+    [S, k], ``cells`` int32 [S, k]). Slot ``s`` has ``min(limits[s], k)``
+    places chosen, the first ones: the cells of the ``k`` rows with the
+    largest scores (ties to the lower row; all live rows where there are no
+    more than ``k``), in ROW order, read from ``cell_of_row``; cell 0 where
+    nothing is chosen. The threshold is :func:`kth_largest`'s bisection; a
+    slot is one grid step of ``dsa_decode_select``, and a dead slot's step
+    copies and computes nothing."""
+    return decode_select_counted(scores, cell_of_row, limits, k)[:2]
 
 
 # ------------------------------------------- attention under a selection mask

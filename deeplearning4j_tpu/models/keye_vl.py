@@ -46,7 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.sparse_attention import kth_largest, selected_attention
+from ..kernels.sparse_attention import (decode_select_counted, kth_largest,
+                                        selected_attention)
 from .kimi_k2 import MOE_STATS, _mm, _rms, resident_experts, route
 from .paged_decode import _write_window
 
@@ -54,8 +55,10 @@ _NEG = -jnp.inf
 
 #: what one decode step counts, in this order: its routing (the expert
 #: layer's counters) and, summed over layers and live slots, the cached rows
-#: its indexer scored and the K/V rows its attention then read
-STEP_STATS = MOE_STATS + ("live_rows", "selected_rows")
+#: its indexer scored, the K/V rows its attention then read, the slots whose
+#: selection a threshold decided (more live rows than ``index_topk``) and, of
+#: those, the ones with more rows tied at the threshold than room for them
+STEP_STATS = MOE_STATS + ("live_rows", "selected_rows", "thresholded", "tie_breaks")
 
 
 @dataclasses.dataclass
@@ -449,6 +452,8 @@ class SparseGQADecodeFamily:
         self.cache_dtype = cfg.param_dtype
         self.n_sparse_layers = cfg.num_hidden_layers
         self.n_resident_experts = cfg.n_resident_experts
+        # while ``decode_window`` traces: every ``_attend``'s tie breaks
+        self._tie_breaks = None
 
     def resident(self, params):
         """Served in the dtype the weights come in (``param_dtype``, no
@@ -488,6 +493,10 @@ class SparseGQADecodeFamily:
             # read: the selection (a dead slot reads none)
             "dsa_live_rows": sums["live_rows"],
             "dsa_selected_rows": sums["selected_rows"],
+            # live slot-layers whose selection the threshold decided, and of
+            # those the ones where rows tied at it outnumbered the room left
+            "dsa_thresholded": sums["thresholded"],
+            "dsa_tie_breaks": sums["tie_breaks"],
         }
 
     def _attend(self, q, qi, wi, arenas, layer: int, tables, limits, cell_of_row,
@@ -499,7 +508,8 @@ class SparseGQADecodeFamily:
         puts the live ones first -> (o [S, H * hd], and the selection the
         step made: ``chosen`` bool [S, topk], True where the place holds a
         selected row, and ``cells`` int32 [S, topk], where that row lies in
-        the arena's layer)."""
+        the arena's layer: the selected rows in ROW order from place 0, read
+        from ``cell_of_row``; attention over a set reads no order)."""
         cfg = self.cfg
         k_arena, v_arena, i_arena = arenas
         S, H, hd = q.shape
@@ -513,13 +523,13 @@ class SparseGQADecodeFamily:
             scores = jnp.where(jnp.arange(max_len)[None, :] < limits[:, None],
                                scores, _NEG)
         with jax.named_scope("dsa.select"):
-            # exact: one stable sort, the largest score first and equal
-            # scores by the lower row; a row's place in the arena rides along
-            # as the sort's payload (looking 32,768 places up in the tables
-            # afterwards costs more than the sort: PERF.md, PR 35)
-            falling, cells = jax.lax.sort((-scores, cell_of_row), num_keys=1,
-                                          is_stable=True)
-            chosen, cells = falling[:, :topk] < jnp.inf, cells[:, :topk]
+            # exact, with no sort: the k-th score by bisection, ties to the
+            # lower row, the chosen rows' cells compacted in row order, one
+            # live slot a grid step (the stable sort of [16, 17408] it
+            # replaced was a third of a step: PERF.md, PR 40)
+            chosen, cells, ties = decode_select_counted(scores, cell_of_row, limits, topk)
+            if self._tie_breaks is not None:
+                self._tie_breaks.append(jnp.sum(ties).astype(jnp.int32))
         with jax.named_scope("dsa.attend"):
             # one live slot a trip: a dead slot gathers nothing (XLA's gather
             # costs by the row, 17 ns for 1 KB on a v5e, and batched over the
@@ -555,7 +565,7 @@ class SparseGQADecodeFamily:
 
     def decode_window(self, params, tokens, positions, arenas, tables):
         """One decode step of every slot: tokens / positions [S, 1]. Returns
-        (logits [S, 1, V], arenas, stats int32 [6] in ``STEP_STATS`` order)."""
+        (logits [S, 1, V], arenas, stats int32 [8] in ``STEP_STATS`` order)."""
         cfg = self.cfg
         if tokens.shape[1] != 1:
             raise ValueError("the keye_vl family decodes one token a step")
@@ -568,6 +578,7 @@ class SparseGQADecodeFamily:
         h = params["embed"][tokens].astype(jnp.float32)            # [S, 1, D]
         moe = jnp.zeros((len(MOE_STATS),), jnp.int32)
         picked = jnp.zeros((), jnp.int32)
+        self._tie_breaks = []
         for l, p in enumerate(params["layers"]):
             q, k, v, qi, ki, wi = attention_rows(
                 cfg, p, _rms(h, p["attn_norm"], cfg.rms_norm_eps), pos3)
@@ -584,6 +595,10 @@ class SparseGQADecodeFamily:
             h = h + f[:, None]
             moe = moe + s
             picked = picked + jnp.sum(chosen).astype(jnp.int32)
+        # an ``_attend`` that another family ran (a check's control) added none
+        ties, self._tie_breaks = sum(self._tie_breaks, jnp.zeros((), jnp.int32)), None
         rows = (jnp.sum(limits) * self.n_layers).astype(jnp.int32)
+        topk = min(cfg.index_topk, cell_of_row.shape[1])
+        thresholded = (jnp.sum(limits > topk) * self.n_layers).astype(jnp.int32)
         return (_head(cfg, params, h), (k_arena, v_arena, i_arena),
-                jnp.concatenate([moe, jnp.stack([rows, picked])]))
+                jnp.concatenate([moe, jnp.stack([rows, picked, thresholded, ties])]))

@@ -271,7 +271,8 @@ def _keye():
         index_n_heads=2, index_head_dim=8, index_topk=4, index_q_chunk=8,
         max_position_embeddings=64, param_dtype=jnp.float32, moe_tile=8)
     return cfg, kv.init_params(jax.random.key(0), cfg), (
-        POOL_KEYS | MOE_KEYS | {"dsa_live_rows", "dsa_selected_rows"})
+        POOL_KEYS | MOE_KEYS | {"dsa_live_rows", "dsa_selected_rows",
+                                "dsa_thresholded", "dsa_tie_breaks"})
 
 
 @pytest.mark.parametrize("make", [_transformer, _kimi, _keye],

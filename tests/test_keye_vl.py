@@ -165,6 +165,117 @@ def test_decode_selection_is_the_prefill_selection(small):
     assert set(np.argsort(-scores, kind="stable")[:8]) == set(np.flatnonzero(mask[-1]))
 
 
+def sorted_select(scores, cell_of_row, limits, k):
+    """What a decode step ran before ``decode_select``: one stable sort of
+    the masked scores, the largest first, the rows' cells riding along."""
+    R = scores.shape[1]
+    scores = jnp.where(jnp.arange(R)[None, :] < limits[:, None], scores, -jnp.inf)
+    falling, cells = jax.lax.sort((-scores, cell_of_row), num_keys=1, is_stable=True)
+    chosen = jnp.pad(falling[:, :k] < jnp.inf, ((0, 0), (0, max(0, k - R))))
+    return chosen, jnp.pad(cells[:, :k], ((0, 0), (0, max(0, k - R))))
+
+
+def as_mask(chosen, cells, n):
+    """A selection as the set of cells it names, whatever their order."""
+    chosen, cells = np.asarray(chosen), np.asarray(cells)
+    mask = np.zeros((chosen.shape[0], n), bool)
+    for s in range(chosen.shape[0]):
+        mask[s, cells[s][chosen[s]]] = True
+        assert mask[s].sum() == chosen[s].sum()   # no cell named twice
+    return mask
+
+
+@pytest.mark.parametrize("S,R,k,tied,limits", [
+    # integer scores: many more rows tie at the threshold than there is room;
+    # a dead slot, limits = k, > k, the whole row, rows ending mid-chunk
+    (5, 300, 40, True, [0, 40, 103, 267, 300]),
+    (3, 1024, 200, True, [1024, 0, 777]),
+    (6, 640, 128, False, [0, 127, 128, 129, 513, 640]),     # below, at, above k
+    (2, 256, 256, False, [0, 200]),                         # k = R: no selection
+    (3, 64, 100, False, [64, 0, 9]),                        # k > R
+    (11, 200, 8, False, [0, 1, 7, 8, 9, 50, 199, 200, 3, 0, 129]),
+], ids=["ties_past_the_room", "ties_long", "around_k", "k_is_R", "k_over_R", "eleven_slots"])
+def test_decode_select_is_the_stable_sort_it_replaces(S, R, k, tied, limits):
+    """The same SET of rows as the sort (the cells scattered into a mask, a
+    row's count included), ``min(limit, k)`` places chosen from place 0 in
+    row order, cell 0 in every other place."""
+    from deeplearning4j_tpu.kernels.sparse_attention import decode_select
+
+    rs = np.random.RandomState(S * R + k)
+    scores = (rs.randint(0, 4, (S, R)) if tied else rs.randn(S, R)).astype(np.float32)
+    cells = rs.permutation(2 ** 24)[:S * R].reshape(S, R).astype(np.int32)
+    args = (jnp.asarray(scores), jnp.asarray(cells), jnp.asarray(limits, jnp.int32))
+    chosen, got = map(np.asarray, decode_select(*args, k))
+    assert chosen.shape == got.shape == (S, k)
+    limits = np.asarray(limits)
+    n = np.minimum(limits, k)
+    assert np.array_equal(chosen, np.arange(k)[None, :] < n[:, None])
+    assert not got[~chosen].any()
+    want = brute_selection(np.where(np.arange(R)[None] < limits[:, None], scores, -np.inf), k)
+    for s in range(S):   # in row order
+        assert got[s, :n[s]].tolist() == cells[s][want[s]].tolist()
+    # as sets, against the sort
+    index = {int(c): i for i, c in enumerate(cells.reshape(-1))}
+    def rows(chosen, cells):
+        flat = np.vectorize(lambda c: index.get(int(c), 0))(np.asarray(cells))
+        return as_mask(chosen, flat, S * R)
+    assert np.array_equal(rows(chosen, got), rows(*sorted_select(*args, k)))
+
+
+@pytest.fixture(scope="module")
+def attending(small):
+    """A pool with two live slots (29 and 5 rows cached) and a dead one, and
+    random queries: what ``SparseGQADecodeFamily._attend`` takes."""
+    cfg, params = small
+    pool = PagedDecodeSlotPool(params, cfg, slots=3, block_T=8, max_len=64)
+    limits = np.zeros(3, np.int32)
+    for seed, n in ((5, 29), (6, 5)):
+        slot, _ = pool.admit(tokens_of(seed, n), 4)
+        limits[slot] = n
+    tables, limits = jnp.asarray(pool._tables), jnp.asarray(limits)
+    rs = np.random.RandomState(3)
+    H, hd, HI, dI = (cfg.num_attention_heads, cfg.head_dim, cfg.index_n_heads,
+                     cfg.index_head_dim)
+    q, qi, wi = (jnp.asarray(rs.randn(3, *shape).astype(np.float32))
+                 for shape in ((H, hd), (HI, dI), (HI,)))
+    live = limits > 0
+    args = (q, qi, wi, pool._arenas, 0, tables, limits)
+    rest = (jnp.argsort(~live, stable=True).astype(jnp.int32),)
+    return pool, args, kv._cell_of_row(tables, 8), rest
+
+
+def test_attend_over_the_selected_set_is_the_sorted_paths(attending, monkeypatch):
+    """The attention's output with the selection in row order matches the
+    sort's order to float tolerance, over the same set of cells."""
+    pool, args, cell_of_row, rest = attending
+    o, chosen, cells = pool.family._attend(*args, cell_of_row, *rest)
+    assert np.asarray(chosen).sum(-1).tolist() == np.minimum(
+        np.asarray(args[-1]), 8).tolist()
+
+    def by_sort(scores, cell_of_row, limits, k):
+        return (*sorted_select(scores, cell_of_row, limits, k),
+                jnp.zeros(scores.shape[0], bool))
+
+    monkeypatch.setattr(kv, "decode_select_counted", by_sort)
+    o_sorted, chosen_sorted, cells_sorted = pool.family._attend(*args, cell_of_row, *rest)
+    n_cells = pool.n_blocks * 8
+    assert np.array_equal(as_mask(chosen, cells, n_cells),
+                          as_mask(chosen_sorted, cells_sorted, n_cells))
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_sorted), rtol=1e-5, atol=1e-6)
+
+
+def test_attend_reads_the_cells_it_is_given(attending):
+    """``cells`` come from the ``cell_of_row`` operand, never from the
+    tables: every cell given one row later comes back one later (the check's
+    ``decode_wrong_rows`` control relies on it)."""
+    pool, args, cell_of_row, rest = attending
+    _, chosen, cells = pool.family._attend(*args, cell_of_row, *rest)
+    _, chosen_off, cells_off = pool.family._attend(*args, cell_of_row + 1, *rest)
+    assert np.array_equal(np.asarray(chosen), np.asarray(chosen_off))
+    assert np.array_equal(np.asarray(cells_off)[np.asarray(chosen)],
+                          np.asarray(cells)[np.asarray(chosen)] + 1)
+
+
 # -- multimodal rotary ---------------------------------------------------------
 
 
@@ -241,7 +352,8 @@ def test_one_decode_step_matches_the_full_forward_and_the_reference(small):
     close(got[slot, 0], np.asarray(kv.forward(params, seq[None], cfg)[0, -1]))
     close(got[slot, 0], ref_logits(params, seq[None], cfg)[0, -1])
     # one live token through two layers: 30 rows scored, 8 read, a layer
-    assert stats.tolist()[:1] == [2] and stats.tolist()[4:] == [2 * 30, 2 * 8]
+    # ... its selection decided by the threshold in both, with no tie to break
+    assert stats.tolist()[:1] == [2] and stats.tolist()[4:] == [2 * 30, 2 * 8, 2, 0]
 
 
 def test_pool_serves_ragged_prompts_token_for_token_with_one_decode_program(small):
@@ -339,11 +451,31 @@ def test_block_stats_count_rows_routing_and_the_bytes_a_token_stores(small, monk
     assert b["kv_cache_bytes_per_token"] == 2 * (32 + 32 + 128) * 4
     assert b["dsa_live_rows"] == 2 * (25 + 27 + 29)
     assert b["dsa_selected_rows"] == 2 * (12 + 13 + 14)
+    # the long slot's selection is a threshold's in every layer of every step
+    assert b["dsa_thresholded"] == 3 * 2 and b["dsa_tie_breaks"] == 0
     assert b["moe_routed_tokens"] == 3 * 2 * 2                 # steps x live x layers
     assert b["moe_experts_resident"] == 3 * 2 * 16
     assert 0 < b["moe_experts_touched"] <= b["moe_resident_assignments"]
     assert b["moe_resident_assignments"] == b["moe_load_sum"] == 4 * b["moe_routed_tokens"]
     assert set(pool.last_step_stats) == set(kv.STEP_STATS)
+
+
+def test_block_stats_count_the_tie_breaks_of_a_selection_of_equal_scores(small):
+    """Index head weights of zero score every cached row 0: a context longer
+    than the selection ties all of its rows at the threshold, and the first
+    8 enter."""
+    cfg, params = small
+    flat = {**params, "layers": [{**p, "wwi": jnp.zeros_like(p["wwi"])}
+                                 for p in params["layers"]]}
+    pool = PagedDecodeSlotPool(flat, cfg, slots=3, block_T=8, max_len=64)
+    pool.admit(tokens_of(1, 20), 4)     # 21, 22 rows: tied past the room
+    pool.admit(tokens_of(2, 8), 4)      # 9, 10 rows: tied past the room too
+    pool.admit(tokens_of(3, 3), 4)      # within the selection: no threshold
+    for _ in range(2):
+        pool.step()
+    b = pool.block_stats()
+    assert b["dsa_thresholded"] == b["dsa_tie_breaks"] == 2 * 2 * 2   # steps x slots x layers
+    assert b["dsa_selected_rows"] == 2 * ((8 + 8 + 4) + (8 + 8 + 5))   # layers x steps
 
 
 def test_pool_refuses_speculation_for_this_family_by_name(small):

@@ -398,9 +398,16 @@ def test_sparse_decode_program_compiles_for_the_chip_with_three_arenas_in_place(
     # nothing of a K arena's layer is made beside it: a step gathers the
     # index keys of the mapped blocks (71 MB a layer) and 2,048 rows a slot
     assert mem.temp_size_in_bytes < 2 * KV_N_BLOCKS * BLOCK_T * 512
+    text = compiled.as_text()
     # ONE loop a layer runs the trips of all 128 experts
     assert len(re.findall(r"\n\s*\S+ = \([^\n]*f32\[16,2048\][^\n]* while\(",
-                          compiled.as_text())) == KV_LAYERS
+                          text)) == KV_LAYERS
+    # the top-2048 is no sort of [slots, max_len] (ISSUE 40) but ONE Mosaic
+    # kernel a layer, over the live slots
+    assert not re.findall(r"\[%d,%d\][^\n]* sort\(" % (KV_SLOTS, KV_MAX_LEN), text)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("dsa_decode_select" in line for line in calls) == KV_LAYERS, len(calls)
 
 
 def test_sparse_prefill_program_compiles_for_the_chip_with_three_arenas_in_place(
