@@ -846,6 +846,26 @@ _BATCH_AXES = ("data", "dp")
 _HEAD_AXES = ("tp", "model")
 
 
+def _free_axes(mesh):
+    """The mesh's axes a ``shard_map`` may still split over: not manual, > 1."""
+    return [a for a in mesh.axis_names
+            if a not in mesh.manual_axes and mesh.shape[a] > 1]
+
+
+def _pick_axis(mesh, names, dim):
+    """The first of ``names`` that is free in ``mesh`` and divides ``dim``."""
+    free = _free_axes(mesh)
+    return next((a for a in names if a in free and dim % mesh.shape[a] == 0),
+                None)
+
+
+def head_axis(n_heads: int) -> Optional[str]:
+    """The axis of the ambient mesh that flash attention splits ``n_heads``
+    heads over (:func:`_flash_per_shard`); None where every device holds all
+    of them (no mesh, one device, no free head axis that divides them)."""
+    return _pick_axis(jax.sharding.get_abstract_mesh(), _HEAD_AXES, n_heads)
+
+
 def _flash_per_shard(q, k, v, mask, *, causal, scale):
     """``flash_attention`` under whatever mesh is ambient.
 
@@ -859,16 +879,9 @@ def _flash_per_shard(q, k, v, mask, *, causal, scale):
     this is a plain call."""
     fn = functools.partial(flash_attention, causal=causal, scale=scale)
     mesh = jax.sharding.get_abstract_mesh()
-    free = [a for a in mesh.axis_names
-            if a not in mesh.manual_axes and mesh.shape[a] > 1]
-    if not free:
+    if not _free_axes(mesh):
         return fn(q, k, v, mask)
-
-    def pick(names, dim):
-        return next((a for a in names
-                     if a in free and dim % mesh.shape[a] == 0), None)
-
-    b_ax, h_ax = pick(_BATCH_AXES, q.shape[0]), pick(_HEAD_AXES, q.shape[1])
+    b_ax, h_ax = _pick_axis(mesh, _BATCH_AXES, q.shape[0]), head_axis(q.shape[1])
     spec = P(b_ax, h_ax, None, None)
     mspec = None if mask is None else P(b_ax, *([None] * (mask.ndim - 1)))
     return jax.shard_map(fn, in_specs=(spec, spec, spec, mspec),

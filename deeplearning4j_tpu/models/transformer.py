@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..kernels.attention import dot_product_attention, ring_attention, ulysses_attention
+from ..kernels.attention import (dot_product_attention, head_axis, ring_attention,
+                                 ulysses_attention)
 from ..kernels.paged_attention import paged_decode_attention
 from .paged_decode import (  # the pool's names are this module's too
     BlockAllocator,
@@ -196,8 +197,7 @@ def _layer(cfg: TransformerConfig, p, h, attend, rng=None, train=False):
     cd = cfg.compute_dtype
 
     def attn_sub(x):
-        qkv = x @ p["qkv_w"].astype(cd) + p["qkv_b"].astype(cd)
-        o, kept = attend(*jnp.split(qkv, 3, axis=-1))
+        o, kept = attend(*_project_qkv(cfg, p, x))
         o = o @ p["out_w"].astype(cd) + p["out_b"].astype(cd)
         return _dropout(o, cfg, rng, 0, train), kept
 
@@ -218,6 +218,37 @@ def _layer(cfg: TransformerConfig, p, h, attend, rng=None, train=False):
     h = _layer_norm(h + ffn_sub(h.astype(cd)).astype(h.dtype),
                     p["ln2_scale"], p["ln2_bias"]).astype(h.dtype)
     return h, kept
+
+
+#: blocks traced with q, k and v split by heads in the weight (``_project_qkv``)
+head_major_blocks = 0
+
+
+def _project_qkv(cfg: TransformerConfig, p, x):
+    """x [..., D] -> q, k, v, each [..., D].
+
+    Where the ambient mesh splits heads over an axis (``head_axis``: the one
+    flash attention's ``shard_map`` puts them on), ``qkv_w`` is viewed
+    head-major, [D, 3, H, hd], and pinned split by heads over that axis, so
+    the projection hands every device its own heads of q, k and v. The
+    stored columns (q, then k, then v, split contiguously over ``tp``) then
+    move as a weight, not as activations, and that weight is known before
+    the block's input is. Elsewhere: one matmul and a split."""
+    global head_major_blocks
+    cd = cfg.compute_dtype
+    ax = head_axis(cfg.n_heads)
+    if ax is None:
+        qkv = x @ p["qkv_w"].astype(cd) + p["qkv_b"].astype(cd)
+        return jnp.split(qkv, 3, axis=-1)
+    head_major_blocks += 1
+    H, hd = cfg.n_heads, cfg.head_dim
+    # cast first, so what crosses chips is the compute dtype
+    w = jax.lax.with_sharding_constraint(p["qkv_w"].astype(cd).reshape(-1, 3, H, hd),
+                                         P(P.UNCONSTRAINED, None, ax, None))
+    b = jax.lax.with_sharding_constraint(p["qkv_b"].astype(cd).reshape(3, H, hd),
+                                         P(None, ax, None))
+    qkv = jnp.einsum("...d,dshe->...she", x, w) + b
+    return [qkv[..., i, :, :].reshape(*x.shape[:-1], H * hd) for i in range(3)]
 
 
 def _whole_sequences(cfg: TransformerConfig, pad_mask):

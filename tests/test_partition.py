@@ -217,8 +217,8 @@ def dp2tp2_step():
     """A 2-layer causal transformer with an odd vocabulary under data 2 x
     tp 2, placed by the four-chip cell's own sequence (``spec_tree`` ->
     ``state_spec_tree`` -> ``out_shardings``: benchmark/runners/train.py):
-    the compiled step's collectives, its first loss, and the loss of the
-    same step on one device."""
+    the compiled step's collectives, its first loss, the loss of the same
+    step on one device, and the blocks its trace viewed ``qkv_w`` head-major."""
     import jax.numpy as jnp
 
     from deeplearning4j_tpu.models import transformer as tfm
@@ -249,11 +249,13 @@ def dp2tp2_step():
         step = jax.jit(tfm.make_train_step(cfg, updater), donate_argnums=(0, 1),
                        out_shardings=(*keep, None))
         placed = jax.device_put(batch, batch_sharding(part.mesh))
+        traced = tfm.head_major_blocks
         compiled = step.lower(params, opt, placed, *args).compile()
+        head_major = tfm.head_major_blocks - traced
         loss = float(compiled(params, opt, placed, *args)[2])
     one = jax.jit(tfm.make_train_step(cfg, updater), donate_argnums=(0, 1))
     loss_one = float(one(whole, updater.init(whole), batch, *args)[2])
-    return _collectives(compiled.as_text()), loss, loss_one
+    return _collectives(compiled.as_text()), loss, loss_one, head_major
 
 
 def _dims_end(dims, *tail):
@@ -271,7 +273,7 @@ def test_dp2tp2_step_census(dp2tp2_step, case):
     two matrices of a pair is never gathered, the head sums no logits, the
     tied table's gradient is never gathered back, and a block's only
     ``[B,T,D]`` sums are its two pairs', forward and backward."""
-    found, loss, loss_one = dp2tp2_step
+    found, loss, loss_one, _ = dp2tp2_step
     c = _CENSUS
     assert found, "the census parsed no collective at all"
     of = lambda op, *tail: [d for o, d in found  # noqa: E731
@@ -286,6 +288,74 @@ def test_dp2tp2_step_census(dp2tp2_step, case):
         assert len(of("all-reduce", c["T"], c["D"])) <= 4 * c["L"]
     else:
         assert abs(loss - loss_one) <= 1e-5 * abs(loss_one), (loss, loss_one)
+
+
+@pytest.mark.parametrize("case", ["no_exchange_of_the_activations",
+                                  "qkv_w_moves_once_a_block_and_direction",
+                                  "every_block_viewed_head_major"])
+def test_dp2tp2_step_splits_qkv_by_heads_in_the_weight(dp2tp2_step, case):
+    """``qkv_w``'s columns are stored q, then k, then v and split
+    contiguously over tp, while attention wants each device's own heads of
+    all three. The projection contracts with a head-major view of the weight
+    pinned over tp, so no activation crosses chips for q, k or v, forward or
+    backward: what crosses is ``qkv_w`` itself (its bf16 cast going forward,
+    its gradient coming back), once a block and direction."""
+    found, _, _, head_major = dp2tp2_step
+    c = _CENSUS
+    if case == "no_exchange_of_the_activations":
+        assert [(o, d) for o, d in found
+                if o in ("all-to-all", "collective-permute") and c["T"] in d] == []
+    elif case == "qkv_w_moves_once_a_block_and_direction":
+        moved = [d for o, d in found
+                 if o != "all-reduce" and int(np.prod(d)) == 3 * c["D"] ** 2]
+        assert len(moved) == 2 * c["L"], moved
+    else:
+        assert head_major == c["L"]
+
+
+def _traced_block(mesh, *, d_model=64, n_heads=4):
+    """One block over whole sequences traced under ``mesh`` (None: no mesh):
+    its jaxpr, the head-major view's shape, and the blocks viewed so."""
+    import contextlib
+
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models import transformer as tfm
+
+    cfg = tfm.TransformerConfig(vocab_size=97, max_len=16, d_model=d_model,
+                                n_heads=n_heads, n_layers=1, d_ff=128,
+                                causal=True, dropout=0.0, attn_impl="xla")
+    p = tfm.init_params(jax.random.key(0), cfg)["blocks"][0]
+    h = jnp.zeros((4, 16, d_model), cfg.compute_dtype)
+    traced = tfm.head_major_blocks
+    with (jax.sharding.set_mesh(mesh) if mesh is not None
+          else contextlib.nullcontext()):
+        jaxpr = jax.make_jaxpr(
+            lambda p, h: tfm._block(cfg, p, h, None, None, False))(p, h)
+    view = f"[{d_model},3,{n_heads},{cfg.head_dim}]"
+    return str(jaxpr), view, tfm.head_major_blocks - traced
+
+
+@pytest.mark.parametrize("where", ["no_mesh", "tp_1", "tp_2_over_3_heads"])
+def test_without_a_head_axis_the_block_traces_as_one_matmul_and_a_split(where):
+    """No mesh, a tp of 1, or a tp that divides no head count: flash keeps
+    every head on every device there, so the projection is today's form (the
+    one-chip cells' programs do not change) and nothing counts."""
+    mesh, heads = {
+        "no_mesh": (None, 4),
+        "tp_1": (SpecLayout(data=4, fsdp=1, tp=1).build_mesh(jax.devices()[:4]), 4),
+        "tp_2_over_3_heads": (_dp2tp2().mesh, 3),
+    }[where]
+    jaxpr, view, head_major = _traced_block(mesh, d_model=16 * heads,
+                                            n_heads=heads)
+    assert head_major == 0
+    assert view not in jaxpr
+
+
+def test_under_a_head_axis_the_block_contracts_with_the_head_major_view():
+    jaxpr, view, head_major = _traced_block(_dp2tp2().mesh)
+    assert head_major == 1
+    assert view in jaxpr
 
 
 # ------------------------------------------------- acceptance: loss parity

@@ -33,13 +33,17 @@ ARENA = (LAYERS, N_BLOCKS, BLOCK_T, D_MODEL)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler here, or another process holds it
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -546,3 +550,98 @@ def test_equal_heads_decode_kernel_lowers_as_it_did(one_chip, on_chip_path):
     for t in (plain, grouped):
         assert t.count('custom_call_target="tpu_custom_call"') == 1
     assert f"f32[{SLOTS},1280]" in plain and f"f32[{SLOTS * 48},128]" in grouped
+
+
+# -- the qkv projection: split by heads in the weight where a mesh splits
+# heads (the four-chip cell), today's one matmul and split where none does
+
+_EXCHANGE = re.compile(r"= (\(?[a-z]\w*\[.*?) "
+                       r"(all-reduce|all-gather|reduce-scatter|all-to-all|"
+                       r"collective-permute)(?:-start)?\(.*?channel_id=(\d+)")
+
+
+def _exchanges(hlo_text):
+    """{channel_id: (opcode, result shapes)}: a collective once, however many
+    fusion clones the text repeats it in."""
+    found = {}
+    for line in hlo_text.splitlines():
+        m = _EXCHANGE.search(line)
+        if m:
+            found.setdefault(m.group(3), (m.group(2),
+                                          re.findall(r"\w+\[[\d,]*\]", m.group(1))))
+    return found
+
+
+def test_the_four_chip_step_moves_qkv_w_and_not_its_activations(topo, on_chip_path):
+    """gpt2-large.train-dp2tp2's step (benchmark/runners/train.py's placement:
+    data 2 x tp 2, B 4 a replica, T 1024, the published widths, depth cut to
+    1) for a described v5e:2x2. Flash runs per shard with heads over tp; the
+    projection hands it those heads, so no all-to-all and no
+    collective-permute is left, and what crosses for q, k and v is ``qkv_w``
+    (bf16 forward, its gradient back) and its bias."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deeplearning4j_tpu.nn.updaters import Adam
+    from deeplearning4j_tpu.parallel.partition import Partitioner, SpecLayout
+
+    cfg = tfm.TransformerConfig(
+        vocab_size=50257, max_len=1024, d_model=1280, n_heads=20, n_layers=1,
+        d_ff=5120, causal=True, dropout=0.0, norm_position="pre")
+    updater = Adam(1e-4)
+    layout = SpecLayout(data=2, fsdp=1, tp=2)
+    part = Partitioner(layout, mesh=layout.build_mesh(list(topo.devices)))
+    p_shapes = jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg))
+    s_shapes = jax.eval_shape(updater.init, p_shapes)
+    p_specs = part.spec_tree(p_shapes)
+    s_specs = Partitioner.state_spec_tree(s_shapes, p_specs)
+    keep = jax.tree.map(part.sharding_for, (p_specs, s_specs),
+                        is_leaf=lambda x: isinstance(x, P))
+    placed = lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh)  # noqa: E731
+    rows = NamedSharding(part.mesh, P("data"))
+    whole = NamedSharding(part.mesh, P())
+    batch = {k: jax.ShapeDtypeStruct((8, 1024), dt, sharding=rows) for k, dt in
+             (("tokens", jnp.int32), ("labels", jnp.int32), ("weights", jnp.float32))}
+    args = (jax.tree.map(placed, p_shapes, keep[0]),
+            jax.tree.map(placed, s_shapes, keep[1]), batch,
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=whole),
+            jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=whole))
+    traced = tfm.head_major_blocks
+    with jax.sharding.set_mesh(part.mesh):
+        step = jax.jit(tfm.make_train_step(cfg, updater), donate_argnums=(0, 1),
+                       out_shardings=(*keep, None))
+        text = step.lower(*args).compile().as_text()
+    assert tfm.head_major_blocks - traced == 1
+    found = _exchanges(text)
+    assert [f for f in found.values()
+            if f[0] in ("all-to-all", "collective-permute")] == []
+    moved = sorted(sh[0] for op, sh in found.values() if op == "all-gather"
+                   and "1024" not in sh[0])
+    assert moved == ["bf16[1280,3,20,64]", "bf16[1280,3840]",
+                     "bf16[2,1,1920]", "bf16[3,20,64]"], moved
+    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert name in text
+
+
+def test_a_bert_block_compiles_for_one_chip_as_before(one_chip, on_chip_path):
+    """bert-large.mlm-t512's block, forward and backward, on one described
+    chip: no mesh, so the projection is one matmul and a split, with no
+    head-major view of ``qkv_w`` and no collective, and flash is the kernel."""
+    cfg = tfm.TransformerConfig.bert_large(
+        max_len=512, n_layers=1, dropout=0.0, norm_position="post",
+        gelu_approximate=False)
+    block = jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg))["blocks"][0]
+    block = jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), block)
+    h = _shape(one_chip, (16, 512, 1024), jnp.bfloat16)
+    mask = _shape(one_chip, (16, 512), jnp.float32)
+
+    def loss(p, h, mask):
+        return jnp.sum(tfm._block(cfg, p, h, mask, None, False).astype(jnp.float32))
+
+    traced = tfm.head_major_blocks
+    lowered = jax.jit(jax.grad(loss)).lower(block, h, mask)
+    assert tfm.head_major_blocks == traced
+    assert "[1024,3,16,64]" not in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert _exchanges(text) == {}
+    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert name in text
